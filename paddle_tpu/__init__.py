@@ -21,9 +21,6 @@ from __future__ import annotations
 # parity target ~v2.0), so utils.require_version gates pass
 __version__ = "2.0.0"
 
-from .framework import jax_compat as _jax_compat  # noqa: F401  (installs
-# the jax.shard_map alias on jax versions that predate it — must run
-# before any module dereferences jax.shard_map)
 from .framework import (  # noqa: F401
     CPUPlace, CUDAPinnedPlace, CUDAPlace, Place, TPUPlace, XPUPlace,
     Tensor, device_count, enable_grad, get_device, grad,

@@ -63,7 +63,7 @@ COLLECTIVE_PRIMS = {
 }
 
 # host-callback primitives: anything here inside a step program is a
-# per-step host round trip through the PJRT tunnel
+# per-step host round trip
 CALLBACK_PRIMS = {"pure_callback", "io_callback", "callback",
                   "outside_call", "host_callback_call"}
 DEBUG_PRIMS = {"debug_callback", "debug_print"}

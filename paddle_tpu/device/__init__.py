@@ -14,7 +14,7 @@ def synchronize(device=None):
     platform device_context Wait). JAX: handled per-array; this flushes by
     touching a trivial computation."""
     import jax
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
 
 
 class cuda:

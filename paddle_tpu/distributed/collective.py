@@ -19,22 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
 
 
 def shard_map(f, mesh, in_specs, out_specs, **kw):
-    """shard_map with replication-checking off across jax versions
-    (check_vma in >=0.8, check_rep before)."""
-    for flag in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **flag, **kw)
-        except TypeError:
-            continue
-    raise TypeError("incompatible shard_map signature")
+    """``jax.shard_map`` with the replication (vma) checker off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 from ..framework.core import Tensor
 from . import mesh as mesh_mod

@@ -21,7 +21,7 @@ ProbabilityEntry) are evaluated inside the same directory probe — no
 per-id Python dict walk and no np.isin snapshot on the hot path
 (reference anchor: framework/fleet/fleet_wrapper.h:111-185).  The pure
 Python implementation is kept, bit-compatible, as the reference
-implementation and the no-toolchain fallback (``use_native=False``).
+implementation (``use_native=False`` / ``backend="python"``).
 """
 from __future__ import annotations
 
@@ -67,18 +67,15 @@ def dequantize_rows_q8(codes: np.ndarray, scales: np.ndarray):
 
 def sendv_addrs(fd: int, addrs: np.ndarray, row_bytes: int,
                 hdr: bytes, inv: np.ndarray,
-                timeout_ms: int = -1) -> Optional[int]:
+                timeout_ms: int = -1) -> int:
     """Native scatter-gather send of a zc pull reply: ``hdr`` + ``inv``
     bytes, then one iovec per contiguous run of the address-sorted
     rows (address 0 = a zeros row), looping ``sendmsg`` with IOV_MAX
     batching, EINTR retry, partial-send advance and poll-on-EAGAIN.
-    Returns bytes sent (negative = -errno), or None when the native
-    core is unavailable."""
+    Returns bytes sent (negative = -errno)."""
     import ctypes
     from paddle_tpu.native import ps_core
     lib = ps_core()
-    if lib is None:
-        return None
     addrs = np.ascontiguousarray(addrs, np.uint64)
     inv = np.ascontiguousarray(inv, np.int32)
     return int(lib.pts_sendv_addrs(
@@ -95,13 +92,15 @@ class SparseTable:
     common_sparse_table.cc).  Rows materialise on first touch.
 
     Backed by the native C++ sharded core (paddle_tpu/native/ps_core.cc)
-    when ``use_native`` (default) and a toolchain is present and no
-    custom Python initializer is given; the native core gives
+    when ``use_native`` (default) and no custom Python initializer is
+    given (a missing toolchain is then an error, not a downgrade); the
+    native core gives
     lock-sharded concurrent pull/push, a FUSED push (dedup + segment-sum
     + optimizer apply in one C pass), native admission filtering for the
     stock entry policies, and deterministic per-id row init (model
     independent of insertion order and shard count).  Pure-Python dict
-    fallback otherwise (``use_native=False`` or ``backend="python"``).
+    backend when asked for (``use_native=False`` or
+    ``backend="python"``).
 
     Push semantics (both backends): duplicate ids' gradients are summed
     first and the optimizer applies ONCE per unique id — the reference's
@@ -143,27 +142,26 @@ class SparseTable:
             use_native = backend != "python"
         if use_native and initializer is None and optimizer in _OPT_CODES:
             from ...native import ps_core
-            try:
-                lib = ps_core()
-            except Exception:
-                lib = None
-            if lib is not None:
-                self._lib = lib
-                self._native = lib.pts_create(
-                    dim, _OPT_CODES[optimizer], lr, beta1, beta2, epsilon,
-                    init_std, seed, n_shards)
-                if entry is not None:
-                    # only the two stock policies have C twins; a custom
-                    # entry object keeps Python admission over native rows
-                    from ..entry import CountFilterEntry, ProbabilityEntry
-                    if type(entry) is CountFilterEntry:
-                        lib.pts_set_entry(self._native, _ENTRY_COUNT,
-                                          float(entry.count_filter))
-                        self._native_entry = True
-                    elif type(entry) is ProbabilityEntry:
-                        lib.pts_set_entry(self._native, _ENTRY_PROB,
-                                          float(entry.probability))
-                        self._native_entry = True
+            # no toolchain -> NativeBuildError: the Python backend is
+            # used when asked for (backend="python"), never because
+            # g++ happened to be missing
+            lib = ps_core()
+            self._lib = lib
+            self._native = lib.pts_create(
+                dim, _OPT_CODES[optimizer], lr, beta1, beta2, epsilon,
+                init_std, seed, n_shards)
+            if entry is not None:
+                # only the two stock policies have C twins; a custom
+                # entry object keeps Python admission over native rows
+                from ..entry import CountFilterEntry, ProbabilityEntry
+                if type(entry) is CountFilterEntry:
+                    lib.pts_set_entry(self._native, _ENTRY_COUNT,
+                                      float(entry.count_filter))
+                    self._native_entry = True
+                elif type(entry) is ProbabilityEntry:
+                    lib.pts_set_entry(self._native, _ENTRY_PROB,
+                                      float(entry.probability))
+                    self._native_entry = True
         # python fallback state
         self._version = 0   # applied mutating batches (native: in C)
         self._rows: Dict[int, np.ndarray] = {}
@@ -658,11 +656,7 @@ class SparseTable:
     def simd_available() -> bool:
         """True when the native core compiled with AVX2 on this host."""
         from ...native import ps_core
-        try:
-            lib = ps_core()
-        except Exception:
-            return False
-        return lib is not None and int(lib.pts_simd_available()) == 1
+        return int(ps_core().pts_simd_available()) == 1
 
     @staticmethod
     def set_simd(on: bool):
@@ -670,9 +664,7 @@ class SparseTable:
         paths — bit-exact by construction (same evaluation order, FP
         contraction disabled), which the parity suite asserts."""
         from ...native import ps_core
-        lib = ps_core()
-        if lib is not None:
-            lib.pts_set_simd(1 if on else 0)
+        ps_core().pts_set_simd(1 if on else 0)
 
     # -- int8 wire rows (ISSUE 16) --------------------------------------
     def pull_q8(self, ids: np.ndarray):

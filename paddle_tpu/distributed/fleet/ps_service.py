@@ -1022,7 +1022,7 @@ class PSServer:
                 conn.fileno(), addrs, dim * 4,
                 pre, inv2,
                 -1 if to is None else int(to * 1000))
-            if sent is not None and sent < 0:
+            if sent < 0:
                 if -sent in (errno.EAGAIN, errno.EWOULDBLOCK):
                     raise socket.timeout("pull2 sendv timed out")
                 raise OSError(-sent, os.strerror(-sent))

@@ -26,11 +26,7 @@ def recompute(function, *args, **kwargs):
     call is transparent — eager XLA keeps no residual graph to begin with.
     """
     kwargs.pop("preserve_rng_state", None)  # reference-API parity arg
-    try:
-        tracing = not jax.core.trace_state_clean()
-    except AttributeError:  # older jax
-        tracing = True
-    if not tracing:
+    if jax.core.trace_ctx.is_top_level():      # eager: nothing to remat
         return function(*args, **kwargs)
 
     vals = [a._value if isinstance(a, Tensor) else a for a in args]

@@ -43,7 +43,7 @@ from .planner.spec_layout import AXES, get_layout as _layout
 __all__ = [
     "AXES", "init_mesh", "get_mesh", "set_mesh", "mesh_axis_size",
     "data_axes", "batch_spec", "named_sharding", "maybe_constrain",
-    "reform_mesh", "on_reform",
+    "reform_mesh", "on_reform", "target_platform",
 ]
 
 _global_mesh: Optional[Mesh] = None
@@ -153,6 +153,20 @@ def get_mesh(create: bool = True) -> Optional[Mesh]:
     if _global_mesh is None and create:
         init_mesh({"dp": -1})
     return _global_mesh
+
+
+def target_platform() -> str:
+    """Platform of the devices programs are being compiled FOR: the
+    installed mesh's devices when there is one, else the process
+    default backend.  Every backend-sniffing dispatch site (kernel
+    registry, flash eligibility, RNG impl, pool donation) asks this
+    instead of ``jax.default_backend()``, so an AOT compile against a
+    TPU topology from a CPU host takes the TPU code paths
+    (tests/test_tpu_lowering.py)."""
+    mesh = get_mesh(create=False)
+    if mesh is not None:
+        return mesh.devices.flat[0].platform
+    return jax.default_backend()
 
 
 def mesh_axis_size(axis: str) -> int:

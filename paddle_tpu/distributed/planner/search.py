@@ -19,9 +19,7 @@ follow-up), then lower predicted peak, then the degree tuple.
 
 Verification failures are *kept* (``Plan.verify_error``) but excluded
 from the returned list, so every returned verified plan is proven
-lowerable — on this container that honestly drops pp>1 candidates
-(jaxlib 0.4.37's partial-manual shard_map limit, the same 12
-environmental tier-1 failures ROADMAP records).
+lowerable.
 
 Every ``auto`` decision lands in the flight recorder as a
 ``plan.choose`` event, so a postmortem shows which config a run

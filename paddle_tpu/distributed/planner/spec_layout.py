@@ -164,6 +164,15 @@ class SpecLayout:
         programs stay bit-identical."""
         return P(tuple(data_axes), *([None] * (ndim - 1)))
 
+    def attention_bhsd(self, batch_axes: Sequence[str],
+                       head_axis: Optional[str]) -> P:
+        """Spec of a ``(B, H, S, D)`` attention operand run per shard
+        (the flash-attention ``shard_map``): batch over the caller-
+        filtered live data axes, heads over the 'attn_heads' axis when
+        it divides them, sequence and head-dim whole.  ``head_axis``
+        None is also the ``[B, 1, 1, S_k]`` bias-row form."""
+        return P(tuple(batch_axes) or None, head_axis, None, None)
+
     # -- per-dim constraint specs (mesh.constrain_dim building blocks)
     def dim_spec(self, ndim: int, dim: int, axis,
                  unconstrained_rest: bool = False) -> P:

@@ -33,15 +33,23 @@ class Place:
         self._device_id = int(device_id)
 
     # -- jax binding -------------------------------------------------------
-    def jax_device(self) -> Optional[jax.Device]:
-        devs = [d for d in jax.devices() if self._matches(d)]
-        if not devs:
-            # fall back to host platform (tests run on CPU-simulated meshes)
-            devs = jax.devices("cpu")
-        return devs[min(self._device_id, len(devs) - 1)]
+    def jax_device(self) -> jax.Device:
+        """The bound device.  A place whose platform is absent, or whose
+        index is past the device count, is an error — never another
+        device (a ``TPUPlace`` quietly meaning the host CPU, or
+        ``TPUPlace(3)`` meaning chip 0, hides exactly the failures a
+        bring-up has to see)."""
+        devs = self._devices()
+        if not 0 <= self._device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: device index {self._device_id} out of range "
+                f"— this process sees {len(devs)} {self._kind} "
+                f"device(s) (jax.devices() = {jax.devices()})")
+        return devs[self._device_id]
 
-    def _matches(self, d: jax.Device) -> bool:
-        return True
+    def _devices(self):
+        """The devices this kind of place indexes into."""
+        return jax.devices()
 
     def get_device_id(self) -> int:
         return self._device_id
@@ -71,8 +79,8 @@ class CPUPlace(Place):
     def __init__(self):
         super().__init__(0)
 
-    def _matches(self, d):
-        return d.platform == "cpu"
+    def _devices(self):
+        return jax.devices("cpu")   # present beside any accelerator
 
     def __repr__(self):
         return "CPUPlace"
@@ -83,8 +91,8 @@ class TPUPlace(Place):
 
     _kind = "tpu"
 
-    def _matches(self, d):
-        return d.platform != "cpu"
+    def _devices(self):
+        return [d for d in jax.devices() if d.platform != "cpu"]
 
 
 class XPUPlace(TPUPlace):
@@ -108,10 +116,7 @@ _expected_place: Optional[Place] = None
 def _default_place() -> Place:
     global _expected_place
     if _expected_place is None:
-        try:
-            accel = [d for d in jax.devices() if d.platform != "cpu"]
-        except RuntimeError:
-            accel = []
+        accel = [d for d in jax.devices() if d.platform != "cpu"]
         _expected_place = TPUPlace(0) if accel else CPUPlace()
     return _expected_place
 
@@ -169,7 +174,4 @@ def is_compiled_with_xpu() -> bool:
 
 
 def is_compiled_with_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
+    return any(d.platform == "tpu" for d in jax.devices())

@@ -28,6 +28,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..framework.compile_cache import ensure_compile_cache
 from ..observability import flight_recorder as _flight
 
 __all__ = ["Config", "Predictor", "Tensor", "create_predictor",
@@ -37,8 +38,7 @@ __all__ = ["Config", "Predictor", "Tensor", "create_predictor",
            "UpstreamUnavailable", "ServerClosed", "RequestTimeout",
            "ServerDraining", "GatewayRouter", "LocalReplica",
            "RemoteReplica", "GenerationRpcServer", "ReplicaLost",
-           "MigrationUnsupported",
-           "enable_compile_cache"]
+           "MigrationUnsupported"]
 
 
 def get_version() -> str:
@@ -89,13 +89,13 @@ class Config:
         self._cpu_math_threads = 1
         self._enable_profile = False
         self._donate_inputs = False
-        # persistent XLA compile cache (reference API name:
+        # persistent XLA compile cache location (reference API name:
         # AnalysisConfig::SetOptimCacheDir — there it caches optimized
-        # IR programs, here serialized XLA executables): "auto" resolves
-        # to $PADDLE_INFER_CACHE_DIR or ~/.cache/paddle_tpu/xla_cache;
-        # None/"" disables.  A second process cold-loads its compiled
-        # program from this cache instead of re-running XLA.
-        self._optim_cache_dir = "auto"
+        # IR programs, here serialized XLA executables).  None: the
+        # framework's fixed default (framework/compile_cache.py).  A
+        # second process cold-loads its compiled program from the cache
+        # instead of re-running XLA.
+        self._optim_cache_dir = None
         self._load_batch = 1              # batch the load-time AOT uses
 
     # -- model paths -------------------------------------------------
@@ -199,11 +199,12 @@ class Config:
 
     def set_optim_cache_dir(self, path: Optional[str]):
         """Directory for the persistent compile cache (reference:
-        AnalysisConfig::SetOptimCacheDir).  ``"auto"`` (the default)
-        resolves to ``$PADDLE_INFER_CACHE_DIR`` or
-        ``~/.cache/paddle_tpu/xla_cache``; ``None`` or ``""`` disables
-        cross-process caching for predictors built from this config."""
-        self._optim_cache_dir = path
+        AnalysisConfig::SetOptimCacheDir).  Honoured only when
+        ``JAX_COMPILATION_CACHE_DIR`` is unset and no owner of compiled
+        programs enabled the cache earlier in the process (first caller
+        wins — see ``framework.compile_cache``); ``None`` keeps the
+        fixed in-checkout default."""
+        self._optim_cache_dir = path or None
 
     def set_load_batch(self, batch: int):
         """Batch size the load-time AOT compile specializes symbolic
@@ -294,51 +295,6 @@ class Tensor:
         return self.copy_to_cpu()
 
 
-def _resolve_cache_dir(config: Config) -> Optional[str]:
-    d = getattr(config, "_optim_cache_dir", None)
-    if d == "auto":
-        d = os.environ.get("PADDLE_INFER_CACHE_DIR") or os.path.join(
-            os.path.expanduser("~"), ".cache", "paddle_tpu", "xla_cache")
-    return d or None
-
-
-_cache_dir_enabled: Optional[str] = None
-
-
-def enable_compile_cache(path: str):
-    """Point JAX's persistent compilation cache at ``path`` (idempotent;
-    first caller wins for the process).  Every XLA executable the
-    Predictor AOT-compiles is then serialized to disk, so a SECOND
-    process loading the same artifact skips XLA entirely — this is what
-    makes cold-load-to-first-inference a disk read instead of a compile
-    (reference analog: AnalysisConfig::SetOptimCacheDir persisting the
-    optimized program)."""
-    global _cache_dir_enabled
-    if _cache_dir_enabled is not None:
-        return _cache_dir_enabled
-    import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # serving programs are small and compile fast — cache them anyway
-    # (the defaults skip sub-second compiles, which is every smoke model)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:      # older jax: knob absent, cache still works
-        pass
-    # any compile BEFORE the dir was set froze the lazily-initialized
-    # cache in its disabled state for the whole process (jax memoizes
-    # the init); reset so the predictor's compiles actually persist
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:      # pragma: no cover - internal API moved
-        pass
-    _cache_dir_enabled = path
-    return path
-
-
 class Predictor:
     """Compile-once AOT predictor over a deserialized StableHLO artifact
     (parity: AnalysisPredictor, reference
@@ -362,9 +318,7 @@ class Predictor:
         from jax import export as jexport
 
         self._config = config
-        cache_dir = _resolve_cache_dir(config)
-        if cache_dir:
-            enable_compile_cache(cache_dir)
+        ensure_compile_cache(config._optim_cache_dir)
         prefix = config._path_prefix()
         with open(prefix + ".pdmodel", "rb") as f:
             self._exported = jexport.deserialize(bytearray(f.read()))
@@ -377,10 +331,7 @@ class Predictor:
         self._meta = meta
 
         if config._use_accelerator:
-            try:
-                dev = jax.devices()[config._device_id]
-            except Exception:
-                dev = jax.devices("cpu")[0]
+            dev = jax.devices()[config._device_id]
         else:
             dev = jax.devices("cpu")[0]
         self._device = dev

@@ -599,12 +599,14 @@ class GenerationServer:
         # donate the pools: each step consumes the previous pool
         # buffers in place (the CPU backend can't donate — skip the
         # unusable-donation warning there)
-        donate = () if jax.default_backend() == "cpu" else (1,)
+        from ..distributed.mesh import target_platform
+        on_cpu = target_platform() == "cpu"
+        donate = () if on_cpu else (1,)
         self._decode_fn = jax.jit(decode_fn, donate_argnums=donate)
         self._prefill_fn = jax.jit(make_prefill(call_model, "prefill"),
                                    donate_argnums=donate)
         if self._prefix_on:
-            dfork = () if jax.default_backend() == "cpu" else (0, 1)
+            dfork = () if on_cpu else (0, 1)
             self._fork_fn = jax.jit(fork_fn, donate_argnums=dfork)
         if self._spec:
             self._draft_prefill_fn = jax.jit(
@@ -642,6 +644,8 @@ class GenerationServer:
         if self._running:
             return self
         if self._decode_fn is None:
+            from ..framework.compile_cache import ensure_compile_cache
+            ensure_compile_cache()
             self._build_programs()
         if prewarm:
             self._prewarm()

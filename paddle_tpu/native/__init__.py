@@ -3,9 +3,10 @@
 The reference ships ~500k LoC of C++ for kernels + runtime; under XLA the
 kernel side collapses, but the host runtime around the TPU (sparse
 parameter server tables, high-QPS data ingest) stays genuinely native.
-These are compiled on first use with the host toolchain (g++) into a
-per-host cache — never committed, so there is no binary-arch skew between
-the build machine and the bench machine.
+These are compiled on first use with the host toolchain (g++) into
+``_build/`` — never committed, and keyed on source + build flags + host
+CPU, so a tree copied to another machine rebuilds instead of loading a
+foreign ``-march=native`` binary.
 
 pybind11 is not available in this image; the ABI is plain C loaded via
 ctypes (see each .cc file's ``extern "C"`` block).
@@ -15,10 +16,9 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
-import sys
 import threading
-from typing import Optional
 
 __all__ = ["load_library", "NativeBuildError"]
 
@@ -39,55 +39,83 @@ def _build_dir() -> str:
     return d
 
 
-def load_library(name: str) -> Optional[ctypes.CDLL]:
-    """Compile ``<name>.cc`` (if stale) and dlopen it. Returns None when
-    no C++ toolchain is available — callers fall back to pure Python."""
+# -march=native makes the artifact host-specific, and -ffp-contract=off
+# is a numerics contract (the SIMD fused-push path, ISSUE 16, is
+# bit-exact with the scalar path only if neither may contract a*b+c
+# into an FMA) — both therefore belong in the artifact key
+_CXXFLAGS = ("-O3", "-march=native", "-ffp-contract=off", "-std=c++17",
+             "-shared", "-fPIC", "-pthread")
+
+
+def _host_cpu() -> str:
+    """What ``-march=native`` resolved against: ISA + CPU model + its
+    feature flags.  Part of the artifact key, so a ``_build/`` directory
+    carried to a machine with another CPU (the chip tool copies the
+    tree as it stands) is rebuilt there, never dlopen'ed."""
+    ident = {"machine": platform.machine()}
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:      # first processor's entries
+                key, _, val = line.partition(":")
+                key = key.strip()
+                if key in ("model name", "flags", "Features"):
+                    ident.setdefault(key, val.strip())
+                elif not line.strip() and len(ident) > 1:
+                    break       # end of the first processor block
+    except OSError:
+        ident["processor"] = platform.processor()
+    return repr(sorted(ident.items()))
+
+
+def load_library(name: str) -> ctypes.CDLL:
+    """Compile ``<name>.cc`` (if no artifact matches this source, these
+    flags and this host CPU) and dlopen it.  Raises
+    :class:`NativeBuildError` when the toolchain is missing or the
+    build fails — a caller that wants the pure-Python implementation
+    asks for it (``SparseTable(backend="python")``); it is never
+    substituted because ``g++`` happened to be absent."""
     with _LOCK:
         if name in _CACHE:
             return _CACHE[name]
         src = os.path.join(_SRC_DIR, f"{name}.cc")
+        cxx = os.environ.get("CXX", "g++")
+        h = hashlib.sha256()
         with open(src, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-        out = os.path.join(_build_dir(), f"{name}-{digest}.so")
+            h.update(f.read())
+        h.update(" ".join((cxx,) + _CXXFLAGS).encode())
+        h.update(_host_cpu().encode())
+        out = os.path.join(_build_dir(),
+                           f"{name}-{h.hexdigest()[:16]}.so")
         if not os.path.exists(out):
-            cxx = os.environ.get("CXX", "g++")
             # per-process temp name: concurrent workers with a cold cache
             # must not os.replace a half-written .so over each other
             tmp = f"{out}.{os.getpid()}.tmp"
-            # -ffp-contract=off: the SIMD fused-push path (ISSUE 16) is
-            # bit-exact with the scalar path only if neither is allowed
-            # to contract a*b+c into an FMA
-            cmd = [cxx, "-O3", "-march=native", "-ffp-contract=off",
-                   "-std=c++17", "-shared", "-fPIC", "-pthread", src,
-                   "-o", tmp]
+            cmd = [cxx, *_CXXFLAGS, src, "-o", tmp]
             try:
                 r = subprocess.run(cmd, capture_output=True, text=True,
                                    timeout=300)
-            except (OSError, subprocess.TimeoutExpired) as e:
-                _CACHE[name] = None
-                print(f"paddle_tpu.native: toolchain unavailable "
-                      f"({e}); using Python fallback for {name}",
-                      file=sys.stderr)
-                return None
-            if r.returncode != 0:
-                # -march=native can be rejected on exotic hosts; retry plain
-                cmd_plain = [c for c in cmd if c != "-march=native"]
-                r = subprocess.run(cmd_plain, capture_output=True, text=True,
-                                   timeout=300)
                 if r.returncode != 0:
-                    _CACHE[name] = None
-                    raise NativeBuildError(
-                        f"building {name}.cc failed:\n{r.stderr[-4000:]}")
+                    # -march=native can be rejected on exotic hosts
+                    r = subprocess.run(
+                        [c for c in cmd if c != "-march=native"],
+                        capture_output=True, text=True, timeout=300)
+            except (OSError, subprocess.TimeoutExpired) as e:
+                raise NativeBuildError(
+                    f"building {name}.cc: C++ toolchain unavailable "
+                    f"({e})") from e
+            if r.returncode != 0:
+                raise NativeBuildError(
+                    f"building {name}.cc failed:\n{r.stderr[-4000:]}")
             os.replace(tmp, out)
         lib = ctypes.CDLL(out)
         _CACHE[name] = lib
         return lib
 
 
-def ps_core() -> Optional[ctypes.CDLL]:
+def ps_core() -> ctypes.CDLL:
     """The sparse-table core (ps_core.cc) with argtypes declared."""
     lib = load_library("ps_core")
-    if lib is None or getattr(lib, "_pts_ready", False):
+    if getattr(lib, "_pts_ready", False):
         return lib
     c = ctypes
     i64p = c.POINTER(c.c_int64)
@@ -171,10 +199,10 @@ def ps_core() -> Optional[ctypes.CDLL]:
     return lib
 
 
-def datafeed() -> Optional[ctypes.CDLL]:
+def datafeed() -> ctypes.CDLL:
     """The MultiSlot ingest core (datafeed.cc) with argtypes declared."""
     lib = load_library("datafeed")
-    if lib is None or getattr(lib, "_dfd_ready", False):
+    if getattr(lib, "_dfd_ready", False):
         return lib
     c = ctypes
     u8p = c.POINTER(c.c_uint8)

@@ -50,19 +50,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     """
     drop = dropout_p if training else 0.0
     use_flash = False
-    try:
-        from ...ops.flash_attention import flash_eligible
-        qv = query._value
-        if qv.ndim == 4:
-            mv = attn_mask._value if attn_mask is not None else None
-            use_flash = flash_eligible(
-                qv.shape[1], qv.shape[3],
-                has_mask=mv is not None, dropout=drop,
-                mask_shape=None if mv is None else tuple(mv.shape),
-                mask_dtype=None if mv is None else mv.dtype,
-                kv_seq_len=key._value.shape[1])
-    except Exception:
-        use_flash = False
+    from ...ops.flash_attention import flash_eligible
+    qv = query._value
+    if qv.ndim == 4:
+        mv = attn_mask._value if attn_mask is not None else None
+        use_flash = flash_eligible(
+            qv.shape[1], qv.shape[3],
+            has_mask=mv is not None, dropout=drop,
+            mask_shape=None if mv is None else tuple(mv.shape),
+            mask_dtype=None if mv is None else mv.dtype,
+            kv_seq_len=key._value.shape[1])
 
     if use_flash:
         from ...ops.flash_attention import flash_attention as _fa

@@ -35,13 +35,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only resolves on TPU builds; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
@@ -65,7 +59,8 @@ def _keep_mask(seed_ref, mask_ref, b, h, qb, kb, block_q, block_k,
     idx = ((b * 256 + h) * 256 + qb) * 256 + kb
     pltpu.prng_seed(seed_ref[0], idx)
     bits = pltpu.prng_random_bits((block_q, block_k))
-    thresh = jnp.uint32(int(dropout_p * float(2 ** 32)) & 0xFFFFFFFF)
+    # dropout_p is a static python float; 4294967296.0 == 2 ** 32
+    thresh = jnp.uint32(int(dropout_p * 4294967296.0) & 0xFFFFFFFF)
     return pltpu.bitcast(bits, jnp.uint32) >= thresh
 
 
@@ -377,9 +372,10 @@ def _ref_chunked(q, k, v, bias, causal, scale, chunk=512):
     backward ever holds more than one chunk's ``[B, H, chunk, S_k]``
     score block (without the checkpoint, AD would stash every chunk's
     softmax — same total memory as the naive composition).  The
-    memory-efficient fallback wherever the Pallas kernel cannot run:
-    flash-ineligible shapes, and CPU-mesh dryruns of long-sequence
-    models (the 7B geometry proof compiles through this path)."""
+    memory-efficient route wherever the Pallas kernel cannot run:
+    shapes the kernel does not take (counted ``fallback``), and
+    CPU-mesh dryruns of long-sequence models (the 7B geometry proof
+    compiles through this path)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
 
@@ -429,6 +425,17 @@ def chunked_attention(q, k, v, bias=None, causal=False, scale=None,
 
 def _blocks_ok(sq, sk, block_q, block_k):
     return (sq % min(block_q, sq) == 0 and sk % min(block_k, sk) == 0)
+
+
+def _kernel_takes(q, k, bias, block_q, block_k):
+    """Shapes the Pallas kernels accept: block-divisible sequence
+    lengths and (when present) a ``[B, 1, 1, S_k]`` bias.  ONE
+    predicate shared by the dispatcher (which routes everything else to
+    the counted ``fallback``) and the kernel entry (which raises)."""
+    sq, sk = q.shape[2], k.shape[2]
+    if bias is not None and tuple(bias.shape) != (q.shape[0], 1, 1, sk):
+        return False
+    return _blocks_ok(sq, sk, block_q, block_k)
 
 
 def _dropout_blocks_ok(sq, sk, block_q, block_k):
@@ -513,13 +520,18 @@ def _fa_impl(q, k, v, bias=None, seed=None, test_mask=None,
     block_q, block_k = _resolve_blocks(sq, sk, block_q, block_k)
     _check_dropout_args(dropout_p, seed, test_mask, sq, sk, block_q,
                         block_k, bias)
-    if bias is not None and tuple(bias.shape) != (q.shape[0], 1, 1, sk):
-        return _ref_chunked(q, k, v, bias, causal, scale)
-    if _blocks_ok(sq, sk, block_q, block_k):
-        return _pallas_forward(q, k, v, bias, causal, scale, block_q,
-                               block_k, interpret, dropout_p=dropout_p,
-                               seed=seed, test_mask=test_mask)
-    return _ref_chunked(q, k, v, bias, causal, scale)
+    if not _kernel_takes(q, k, bias, block_q, block_k):
+        # the dispatcher routes such shapes to the reference BEFORE
+        # counting; reaching the kernel entry with one is a bug, never
+        # a quiet downgrade of a call already counted ``pallas``
+        raise ValueError(
+            "flash attention kernel needs block-divisible sequence "
+            f"lengths and a [B,1,1,S_k] bias, got sq={sq} sk={sk} "
+            f"blocks=({block_q},{block_k}) bias="
+            f"{None if bias is None else tuple(bias.shape)}")
+    return _pallas_forward(q, k, v, bias, causal, scale, block_q,
+                           block_k, interpret, dropout_p=dropout_p,
+                           seed=seed, test_mask=test_mask)
 
 
 def _fa_fwd(q, k, v, bias, seed, test_mask, causal, scale, block_q,
@@ -540,6 +552,7 @@ def _fa_fwd(q, k, v, bias, seed, test_mask, causal, scale, block_q,
                                    dropout_p=dropout_p, seed=seed,
                                    test_mask=test_mask)
         return out, (q, k, v, bias, seed, test_mask, out, lse)
+    # bias path (or a shape the kernel does not take: the entry raises)
     out = _fa_impl(q, k, v, bias, seed, test_mask, causal,
                    scale, block_q, block_k, interpret, dropout_p)
     return out, (q, k, v, bias, seed, test_mask, None, None)
@@ -557,11 +570,8 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, dropout_p, res,
                                       dropout_p=dropout_p, seed=seed,
                                       test_mask=test_mask)
         return dq, dk, dv, None, None, None
-    if bias is None:
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _ref_chunked(q_, k_, v_, None, causal, s),
-            q, k, v)
-        return (*vjp(g), None, None, None)
+    # bias path: the fused backward has no dbias kernel, so the
+    # backward (and only the backward) differentiates the reference
     _, vjp = jax.vjp(
         lambda q_, k_, v_, b_: _ref_chunked(q_, k_, v_, b_, causal, s),
         q, k, v, bias)
@@ -571,34 +581,115 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, dropout_p, res,
 _fa_impl.defvjp(_fa_fwd, _fa_bwd)
 
 
+def _under_mesh(kernel, q, k, v, bias, seed, test_mask):
+    """Run the kernel entry per shard when a mesh of more than one
+    device is installed: Mosaic custom calls cannot be partitioned by
+    XLA's SPMD pass, so the call is made manual with ``jax.shard_map``
+    — batch over the live data axes, heads over the attention-heads
+    axis ('tp'), specs from the canonical SpecLayout.  A dim the axes
+    do not divide stays replicated (correct, redundantly computed).
+
+    Composes with an enclosing partial-manual region (the 'pp'
+    shard_map in distributed/pipeline.py): only the still-automatic
+    axes are made manual, against the context mesh.  Inside a fully
+    manual region (ring / ulysses attention bodies) the arrays are
+    already per-shard and the kernel is called directly.  With no mesh,
+    or a one-device mesh, the program is exactly the unwrapped call."""
+    from ...distributed import mesh as mesh_mod
+    from ...distributed.planner.spec_layout import get_layout
+    mesh = mesh_mod.get_mesh(create=False)
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v, bias, seed, test_mask)
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = set() if ctx.empty else set(ctx.manual_axes)
+    auto = [a for a in mesh.axis_names if a not in manual]
+    if not auto:
+        return kernel(q, k, v, bias, seed, test_mask)
+    lay = get_layout()
+
+    b_axes = tuple(a for a in lay.act_axis("batch")
+                   if a in auto and mesh.shape[a] > 1)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in b_axes):
+        b_axes = ()
+    h_ax = lay.act_axis("attn_heads")
+    if not (h_ax in auto and mesh.shape[h_ax] > 1
+            and q.shape[1] % mesh.shape[h_ax] == 0):
+        h_ax = None
+    qspec = lay.attention_bhsd(b_axes, h_ax)
+    # optional operands ride along only when present
+    extra = [(bias, lay.attention_bhsd(b_axes, None)),   # [B,1,1,S_k]
+             (seed, lay.replicated()),
+             (test_mask, qspec)]                          # [B,H,S_q,S_k]
+    present = [(x, s) for x, s in extra if x is not None]
+    sharded = b_axes + ((h_ax,) if h_ax else ())
+
+    def body(q_, k_, v_, *rest):
+        rest = iter(rest)
+        bias_, seed_, mask_ = (next(rest) if x is not None else None
+                               for x, _ in extra)
+        if seed_ is not None and sharded:
+            # the kernel seeds its PRNG with (seed, LOCAL b, h, blocks):
+            # fold the shard's index in so two shards never draw the
+            # same mask (only over axes that shard the call — a
+            # replicated axis must keep identical draws)
+            idx = jnp.int32(0)
+            for ax in sharded:
+                idx = idx * mesh.shape[ax] + jax.lax.axis_index(ax)
+            seed_ = seed_ + idx.astype(jnp.int32) * jnp.int32(40503)
+        return kernel(q_, k_, v_, bias_, seed_, mask_)
+
+    kw = {"mesh": mesh} if ctx.empty else {}
+    return jax.shard_map(
+        body, in_specs=(qspec,) * 3 + tuple(s for _, s in present),
+        out_specs=qspec, axis_names=set(auto), check_vma=False,
+        **kw)(q, k, v, *(x for x, _ in present))
+
+
 def flash_attention_bhsd(q, k, v, bias=None, seed=None, test_mask=None,
                          causal=False, scale=None, block_q=None,
                          block_k=None, interpret=False, dropout_p=0.0):
     """Registry-dispatched flash attention on (B, H, S, D) tensors.
 
-    Routing (see :mod:`paddle_tpu.ops.pallas.registry`): ``xla_ref``
-    mode runs the chunked-recompute XLA reference; ``interpret`` runs
-    the Pallas kernel under the interpreter (an explicit
+    Routing (see :mod:`paddle_tpu.ops.pallas.registry`), decided BEFORE
+    the dispatch is counted: ``xla_ref`` mode runs the chunked-recompute
+    XLA reference; shapes the kernel does not take (non-dividing
+    blocks, a bias that is not ``[B,1,1,S_k]``) run it too, counted
+    ``fallback``; everything else runs the Pallas kernel — compiled, or
+    under the interpreter in ``interpret`` mode (an explicit
     ``interpret=True`` from the caller — the parity tests — forces
-    this regardless of mode).  A ``dropout_p > 0`` call always takes
-    the kernel: the reference has no dropout path, exactly the
-    constraint ``flash_eligible`` encodes for dispatch-level callers.
-    See ``_fa_impl`` for the kernel semantics (bias streaming, on-chip
-    PRNG dropout, the custom-vjp backward).
+    this regardless of mode).  A call counted ``pallas`` /
+    ``interpret`` therefore ran the kernel or raised.  A
+    ``dropout_p > 0`` call always takes the kernel: the reference has
+    no dropout path, exactly the constraint ``flash_eligible`` encodes
+    for dispatch-level callers.  Under an installed multi-device mesh
+    the kernel runs per shard (:func:`_under_mesh`).  See ``_fa_impl``
+    for the kernel semantics (bias streaming, on-chip PRNG dropout, the
+    custom-vjp backward).
     """
     mode = registry.resolve("flash_attention")
     if interpret:
         mode = "interpret"
     elif mode == "interpret":
         interpret = True
-    if mode == "xla_ref" and dropout_p == 0.0:
-        registry.note("flash_attention", "xla_ref")
-        sc = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-        return _ref_chunked(q, k, v, bias, causal, sc)
+    if dropout_p == 0.0:
+        path = None
+        if mode == "xla_ref":
+            path = "xla_ref"
+        elif not _kernel_takes(q, k, bias, *_resolve_blocks(
+                q.shape[2], k.shape[2], block_q, block_k)):
+            path = "fallback"
+        if path is not None:
+            registry.note("flash_attention", path)
+            sc = (scale if scale is not None
+                  else 1.0 / math.sqrt(q.shape[-1]))
+            return _ref_chunked(q, k, v, bias, causal, sc)
     registry.note("flash_attention", "pallas" if mode == "xla_ref"
                   else mode)
-    return _fa_impl(q, k, v, bias, seed, test_mask, causal, scale,
-                    block_q, block_k, interpret, dropout_p)
+
+    def kernel(q_, k_, v_, bias_, seed_, mask_):
+        return _fa_impl(q_, k_, v_, bias_, seed_, mask_, causal, scale,
+                        block_q, block_k, interpret, dropout_p)
+    return _under_mesh(kernel, q, k, v, bias, seed, test_mask)
 
 
 def flash_eligible(seq_len: int, head_dim: int, *, has_mask: bool = False,
@@ -618,9 +709,9 @@ def flash_eligible(seq_len: int, head_dim: int, *, has_mask: bool = False,
     (default 1024) for A/B experiments in the short-seq regime."""
     import os
 
-    import jax
+    from ...distributed.mesh import target_platform
     min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "1024"))
-    if not (jax.default_backend() == "tpu"
+    if not (target_platform() == "tpu"
             and head_dim in (64, 128, 256) and seq_len >= min_seq):
         return False
     if dropout > 0.0:

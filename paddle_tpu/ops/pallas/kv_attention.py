@@ -25,6 +25,18 @@ the SAME function): online softmax re-associates the f32
 exp/sum/weighted-sum chain, documented tolerance atol 2e-5 /
 rtol 1e-4.  The quantization itself is exact (the kernel multiplies
 the same int8 codes by the same f32 scales).
+
+TPU status (PR 21): the kernel runs under the interpreter only.  Its
+block specs tile the serving pools as they are laid out,
+``[nb, bs, KH, D]`` (``LlamaForCausalLM.init_paged_cache``): a
+``(1, bs, 1, D)`` pool block, ``(1, bs)`` scale blocks and a
+``(1, S*R)`` position block.  Mosaic requires a block's last two dims
+to be multiples of (8, 128) or the whole array dims, and a one-head
+slice of the ``KH`` dim is neither — the lowering error is quoted in
+PERF.md ("Bring-up on v5e").  A real repair is a head-major pool layout
+(ROADMAP S6 / D3), not a spec tweak, so the registration defaults this
+ONE kernel to ``xla_ref`` on TPU: a ``kv_cache_dtype="int8"`` server
+traces the gather path there instead of raising.
 """
 from __future__ import annotations
 
@@ -33,11 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
@@ -189,6 +197,7 @@ registry.register(
     tolerance="atol 2e-5 / rtol 1e-4 vs xla_ref (f32 online softmax "
               "re-association; the int8 dequant itself is exact)",
     eligible=_eligible,
+    tpu_default="xla_ref",
     doc="paged decode/verify attention reading int8 KV pools once: "
         "per-(block,slot) scales applied inside the table-driven "
         "gather, blockwise online softmax",

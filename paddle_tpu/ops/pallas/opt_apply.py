@@ -42,11 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
@@ -142,8 +138,7 @@ def opt_apply_pallas(kind, p, g, slots, hyper, *, interpret=False):
             gsz * _TILE_ROWS, _LANES)
 
     nslots = len(slots)
-    smem = (pl.BlockSpec(memory_space=pltpu.SMEM) if pltpu is not None
-            else pl.BlockSpec((1, HYPER_LEN), lambda i: (0, 0)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     blk = pl.BlockSpec((_TILE_ROWS, _LANES), lambda i: (i, 0))
     outs = pl.pallas_call(
         functools.partial(_opt_apply_kernel, kind, nslots),

@@ -20,7 +20,10 @@ Mode resolution per kernel, first match wins:
 2. ``PADDLE_PALLAS_<KERNEL>`` env (``pallas | xla_ref | interpret``);
 3. ``PADDLE_PALLAS=0`` — the global escape hatch: everything runs the
    XLA reference;
-4. default: ``pallas`` on the TPU backend, ``xla_ref`` elsewhere.
+4. default: the kernel's ``tpu_default`` (``pallas`` unless the
+   registration says otherwise) when the programs being compiled
+   target a TPU (``distributed.mesh.target_platform`` — the installed
+   mesh's devices, else the default backend), ``xla_ref`` elsewhere.
 
 Dispatch counters: python-side per-(kernel, path) counts prove which
 implementation actually ran — mirrored into the always-on labeled
@@ -56,6 +59,10 @@ class KernelSpec:
     tolerance: str                    # parity bound vs the XLA reference
     eligible_fn: Optional[Callable] = None
     doc: str = ""
+    # mode on a TPU target when nothing overrides it: "xla_ref" parks a
+    # kernel Mosaic cannot lower yet behind its reference instead of
+    # raising at trace time in every caller
+    tpu_default: str = "pallas"
 
 
 _REGISTRY: Dict[str, KernelSpec] = {}
@@ -66,10 +73,15 @@ _lock = threading.Lock()
 
 def register(name: str, pallas_fn: Callable, xla_ref_fn: Callable, *,
              tolerance: str, eligible: Optional[Callable] = None,
-             doc: str = "") -> KernelSpec:
+             doc: str = "", tpu_default: str = "pallas") -> KernelSpec:
+    if tpu_default not in ("pallas", "xla_ref"):
+        raise ValueError(
+            f"tpu_default must be 'pallas' or 'xla_ref', got "
+            f"{tpu_default!r}")
     spec = KernelSpec(name=name, pallas_fn=pallas_fn,
                       xla_ref_fn=xla_ref_fn, tolerance=tolerance,
-                      eligible_fn=eligible, doc=doc)
+                      eligible_fn=eligible, doc=doc,
+                      tpu_default=tpu_default)
     with _lock:
         _REGISTRY[name] = spec
         _COUNTS.setdefault(name, {})
@@ -109,8 +121,11 @@ def resolve(name: str) -> str:
         return env
     if os.environ.get("PADDLE_PALLAS", "1") == "0":
         return "xla_ref"
-    import jax
-    return "pallas" if jax.default_backend() == "tpu" else "xla_ref"
+    from ...distributed.mesh import target_platform
+    if target_platform() != "tpu":
+        return "xla_ref"
+    spec = _REGISTRY.get(name)
+    return spec.tpu_default if spec is not None else "pallas"
 
 
 def note(name: str, path: str):

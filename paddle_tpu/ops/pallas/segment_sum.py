@@ -27,11 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 

@@ -200,5 +200,16 @@ def test_round3_legacy_compat_surface():
     assert hasattr(wnh, "weight_norm")
     from paddle_tpu import static
     assert static.xpu_places() == static.cuda_places()
+    # a place is a binding to ONE device: no accelerator, or an index
+    # past the device count, is an error — never the host CPU or chip 0
+    import jax
+    from paddle_tpu.framework.place import Place
+    n = jax.device_count()
+    assert Place(n - 1).jax_device() == jax.devices()[n - 1]
+    with pytest.raises(ValueError, match="out of range"):
+        Place(n).jax_device()              # past the count: not clamped
+    with pytest.raises(ValueError, match="out of range"):
+        paddle.TPUPlace(0).jax_device()    # no accelerator: not the CPU
+    assert paddle.CPUPlace().jax_device() == jax.devices("cpu")[0]
     import paddle_tpu.nn as nn
     assert hasattr(nn, "extension")

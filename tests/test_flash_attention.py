@@ -55,13 +55,64 @@ def test_flash_gradients_match_reference():
 
 
 def test_non_divisible_seq_falls_back():
+    from paddle_tpu.ops.flash_attention import _fa_impl
+    from paddle_tpu.ops.pallas import registry
     rng = np.random.RandomState(2)
     q = jnp.asarray(rng.randn(1, 1, 100, 32).astype(np.float32))
+    registry.reset_dispatch_counts("flash_attention")
     out = flash_attention_bhsd(q, q, q, block_q=64, block_k=64,
                                interpret=True)
     ref = _ref(q, q, q, False, 1.0 / np.sqrt(32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3,
                                atol=2e-3)
+    # the reference route is chosen BEFORE the dispatch is counted: a
+    # shape the kernel does not take is a counted fallback, and a call
+    # that reaches the kernel entry (what "pallas"/"interpret" counts)
+    # runs the kernel or raises — it can no longer return the reference
+    assert registry.dispatch_counts("flash_attention") == {"fallback": 1}
+    with pytest.raises(ValueError, match="block-divisible"):
+        _fa_impl(q, q, q, None, None, None, False, None, 64, 64, True, 0.0)
+    with pytest.raises(ValueError, match="block-divisible"):
+        jax.grad(lambda x: _fa_impl(x, q, q, None, None, None, False,
+                                    None, 64, 64, True, 0.0).sum())(q)
+
+
+def test_flash_runs_per_shard_under_a_mesh():
+    """With a multi-device mesh installed the kernel call is made manual
+    (``jax.shard_map``: batch over the data axes, heads over 'tp') —
+    Mosaic calls cannot be SPMD-partitioned — including inside the
+    partial-manual 'pp' region of the pipeline, and equals the
+    unsharded result."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.distributed.planner.spec_layout import get_layout
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(4, 4, 128, 64).astype(np.float32))
+               for _ in range(3))
+
+    def loss(q_, k_, v_):
+        return (flash_attention_bhsd(q_, k_, v_, causal=True, block_q=64,
+                                     block_k=64, interpret=True) ** 2).sum()
+    want = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    # (fresh lambdas: jax caches traces per function object, and the
+    # installed mesh is not part of that key)
+    assert "shard_map" not in str(
+        jax.make_jaxpr(lambda *a: loss(*a))(q, k, v))
+
+    mesh = mesh_mod.init_mesh({"fsdp": 2, "pp": 2, "tp": 2})
+    assert "shard_map" in str(jax.make_jaxpr(lambda *a: loss(*a))(q, k, v))
+    got = jax.jit(jax.grad(lambda *a: loss(*a),
+                           argnums=(0, 1, 2)))(q, k, v)
+    for a, b in zip(want, got):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    # nested in the pipeline's partial-manual region over 'pp'
+    rep = get_layout().replicated()
+    staged = jax.shard_map(lambda *a: loss(*a), mesh=mesh,
+                           axis_names={"pp"},
+                           in_specs=(rep, rep, rep), out_specs=rep,
+                           check_vma=False)
+    np.testing.assert_allclose(float(jax.jit(staged)(q, k, v)),
+                               float(loss(q, k, v)), rtol=1e-5)
 
 
 def test_flash_additive_bias_matches_reference():

@@ -105,17 +105,51 @@ def test_prewarm_builds_one_executable_per_bucket(exported):
 
 
 def test_persistent_cache_writes_to_disk(exported, tmp_path):
-    import paddle_tpu.inference as infer
+    from paddle_tpu.framework import compile_cache
     path, _ = exported
     cache = tmp_path / "xla_cache"
-    # the process-level cache dir may already be pinned by an earlier
-    # test (first caller wins); point at whichever dir is live
+    # the process-level cache dir is pinned by the environment
+    # (conftest) or an earlier test (first caller wins); point at
+    # whichever dir is live
     pred = create_predictor(_config(path, tmp_cache=cache))
-    live = infer._cache_dir_enabled
+    live = compile_cache.cache_dir()
     assert live, "persistent compile cache never enabled"
     pred.prewarm([16])
     entries = [f for f in os.listdir(live) if f.endswith("-cache")]
     assert entries, "AOT compile wrote no persistent cache entries"
+
+
+def test_compile_cache_location_rule(monkeypatch, tmp_path):
+    """ONE function places the cache: with JAX_COMPILATION_CACHE_DIR
+    set it leaves ``jax_compilation_cache_dir`` alone (whatever path a
+    caller passes); unset, it picks the caller's path or the FIXED
+    in-checkout default — never a temp/pid/time-derived name."""
+    import jax
+    from jax._src import compilation_cache as _cc
+    from paddle_tpu.framework import compile_cache
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(_cc, "reset_cache", lambda: None)
+    monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+
+    def fresh(path=None):
+        monkeypatch.setattr(compile_cache, "_enabled", None)
+        updates.clear()
+        return compile_cache.ensure_compile_cache(path)
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert fresh(str(tmp_path / "ignored")) == str(tmp_path / "env")
+    assert "jax_compilation_cache_dir" not in updates
+    # first caller wins for the process
+    assert compile_cache.ensure_compile_cache("x") == str(tmp_path / "env")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fresh() == os.path.join(repo, ".jax_cache")
+    assert updates["jax_compilation_cache_dir"] == \
+        os.path.join(repo, ".jax_cache")
+    assert fresh(str(tmp_path / "cfg")) == str(tmp_path / "cfg")
 
 
 def test_server_coalesces_and_matches_unbatched(exported):

@@ -18,11 +18,10 @@ Model presets: ``7b`` / ``13b`` / ``tiny`` / the PROXY_SUITE names
 (``proxy_fsdp``, ``proxy_tp``, ``proxy_wide``).
 
 The ``--verify`` path needs the jax backend to expose ``--chips``
-(virtual) devices; when it does not, the CLI re-execs itself in a
-subprocess with ``JAX_PLATFORMS=cpu`` and
-``--xla_force_host_platform_device_count`` (plus the bf16-collective
-workaround flag the MULTICHIP dryruns use), exactly like
-``__graft_entry__._dryrun_in_subprocess``.
+(virtual) devices: the CLI always re-execs itself in a subprocess with
+``JAX_PLATFORMS=cpu`` and ``--xla_force_host_platform_device_count``
+(plus the bf16-collective workaround flag the CPU dryruns use), and the
+parent never imports JAX — on a host with a chip it must not claim it.
 """
 from __future__ import annotations
 
@@ -68,15 +67,6 @@ def _model_specs(name: str, args):
     return ModelSpec(**presets[name]), None
 
 
-def _needs_reexec(chips: int) -> bool:
-    try:
-        import jax
-        return not (jax.default_backend() == "cpu"
-                    and jax.device_count() >= chips)
-    except Exception:
-        return True
-
-
 def _reexec(argv, chips: int) -> int:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
@@ -117,8 +107,9 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    if (args.verify and os.environ.get("_PADDLE_PLAN_CHILD") != "1"
-            and _needs_reexec(args.chips)):
+    if args.verify and os.environ.get("_PADDLE_PLAN_CHILD") != "1":
+        # the parent never imports JAX (it would claim the chip where
+        # there is one); verification always runs in the CPU-pinned child
         return _reexec(list(argv if argv is not None
                             else sys.argv[1:]), args.chips)
 

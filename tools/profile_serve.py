@@ -65,9 +65,7 @@ def main():
     paddle.jit.save(model, path,
                     input_spec=[InputSpec([None, 3, hw, hw], "float32",
                                           "img")])
-    cfg = Config(path)
-    cfg.set_optim_cache_dir(os.path.join(tmp, "cache"))
-    pred = create_predictor(cfg)
+    pred = create_predictor(Config(path))
     rng = np.random.RandomState(0)
     x1 = [rng.standard_normal((1, 3, hw, hw)).astype("float32")]
 

@@ -170,6 +170,18 @@ env JAX_PLATFORMS=cpu python tools/plan.py --model proxy_fsdp \
     --chips 8 --verify --top-k 2 --json > /dev/null
 rc6=$?
 
+# TPU lowering check (ISSUE 21): AOT-compile every registered Pallas
+# kernel and a small decoder DistributedTrainStep (1 chip, fsdp4,
+# tp2 x fsdp2, pp2 x tp2) for a v5e:2x2 TOPOLOGY through the real
+# dispatch sites — needs libtpu, not a chip.  Catches what no
+# interpret-mode test can: a kernel Mosaic will not lower, a kernel call
+# the SPMD partitioner will not take.  Marked slow (kept out of the
+# 870 s tier-1 command); ~30 s here.
+echo "== tier-1 TPU lowering check: tests/test_tpu_lowering.py"
+env JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_lowering.py -q \
+    -p no:cacheprovider -p no:xdist -p no:randomly
+rc8=$?
+
 # Tiered-PS smoke (ISSUE 16): the ps_scale bench arm at smoke scale —
 # spill build + SIGKILL-free recovery parity + the zc/row/q8 wire
 # round trips over a live server.  Gates on MECHANISM (recovery count,
@@ -210,10 +222,11 @@ if [ "$LINT" -eq 1 ]; then
 fi
 
 echo "== tier-1: file-order rc=$rc1, shuffled rc=$rc2, chaos rc=$rc3," \
-     "trace rc=$rc4, lint rc=$rc5, plan rc=$rc6, ps_scale rc=$rc7"
+     "trace rc=$rc4, lint rc=$rc5, plan rc=$rc6, ps_scale rc=$rc7," \
+     "tpu_lowering rc=$rc8"
 if [ "$rc1" -ne 0 ] || [ "$rc2" -ne 0 ] || [ "$rc3" -ne 0 ] \
         || [ "$rc4" -ne 0 ] || [ "$rc5" -ne 0 ] || [ "$rc6" -ne 0 ] \
-        || [ "$rc7" -ne 0 ]; then
+        || [ "$rc7" -ne 0 ] || [ "$rc8" -ne 0 ]; then
     echo "== tier-1 FAILED (any pass being red fails the gate)"
     exit 1
 fi
