@@ -1,0 +1,1 @@
+"""The repo's benchmark: one command runs one cell once (see run.py)."""

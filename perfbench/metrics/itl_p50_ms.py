@@ -1,0 +1,6 @@
+from perfbench.harness.stats import percentile
+
+
+def read(ctx):
+    v = percentile(ctx["itl"], 50)
+    return None if v is None else v * 1e3

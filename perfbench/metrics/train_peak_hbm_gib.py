@@ -1,0 +1,2 @@
+def read(ctx):
+    return ctx["peak_bytes"] / 2 ** 30 if ctx["peak_bytes"] else None
