@@ -219,97 +219,6 @@ def _guard_ab(model, loss_fn, opt, smoke, step, args, steps):
     return out
 
 
-def _obs_ab(step, args, steps):
-    """BENCH_OBS=1: A/B the clean-path cost of telemetry (ISSUE 5) —
-    tracing sampled at ``trace_every=16`` plus metrics collection on —
-    against the silent step.  Target (like BENCH_GUARD): <=1% on the
-    compute-bound llama proxy; bandwidth-bound configs on this CPU
-    container are recorded with the PERF.md round 9 caveat."""
-    if os.environ.get("BENCH_OBS", "0") != "1":
-        return {}
-    import tempfile
-    import time as _time
-
-    import jax
-
-    from paddle_tpu.framework import monitor
-    from paddle_tpu.observability import trace
-
-    def stepfn():
-        loss = step(*args)
-        jax.block_until_ready(loss._value)
-
-    def loop():
-        stepfn()                               # warm (compile)
-        ts = []
-        for _ in range(3):
-            t0 = _time.perf_counter()
-            for _ in range(steps):
-                stepfn()
-            ts.append((_time.perf_counter() - t0) / steps)
-        return sorted(ts)[1]
-
-    every = int(os.environ.get("PADDLE_TRACE_EVERY", "16") or 16)
-    a = loop()
-    trace.enable(dir=tempfile.mkdtemp(prefix="bench_obs_trace_"),
-                 role="bench", every=every)
-    monitor.enable_metrics(True)
-    try:
-        b = loop()
-    finally:
-        trace.disable()
-        monitor.enable_metrics(False)
-    return {
-        "obs_ms_plain": round(a * 1e3, 3),
-        "obs_ms_telemetry": round(b * 1e3, 3),
-        "obs_overhead_pct": round((b - a) / a * 100.0, 2),
-        "obs_trace_every": every,
-    }
-
-
-def _flight_ab(step, args, steps):
-    """BENCH_FLIGHT=1: A/B the always-on cost of the flight recorder
-    (ISSUE 7) — the ring records one step event per step plus whatever
-    the run's seams emit; no I/O ever happens on the hot path, so the
-    cost is one json encode + deque append per event.  Target: at the
-    container noise floor (PERF.md round 11)."""
-    if os.environ.get("BENCH_FLIGHT", "0") != "1":
-        return {}
-    import time as _time
-
-    import jax
-
-    from paddle_tpu.observability import flight_recorder as fl
-
-    def stepfn():
-        loss = step(*args)
-        jax.block_until_ready(loss._value)
-
-    def loop():
-        stepfn()                               # warm (compile)
-        ts = []
-        for _ in range(3):
-            t0 = _time.perf_counter()
-            for _ in range(steps):
-                stepfn()
-            ts.append((_time.perf_counter() - t0) / steps)
-        return sorted(ts)[1]
-
-    fl.disable(ring=True)
-    try:
-        a = loop()
-    finally:
-        fl.enable(dumps=False)      # ring back on (the default state)
-    fl.clear()
-    b = loop()
-    return {
-        "flight_ms_off": round(a * 1e3, 3),
-        "flight_ms_on": round(b * 1e3, 3),
-        "flight_overhead_pct": round((b - a) / a * 100.0, 2),
-        "flight_ring_events": len(fl.events()),
-    }
-
-
 def _make_step(model, loss_fn, opt, smoke, guard_health=False):
     from paddle_tpu.distributed import fleet
     from paddle_tpu.distributed import mesh as mesh_mod
@@ -368,8 +277,6 @@ def _bench_resnet(smoke, peak_tflops):
                    analytic, peak_tflops, batch=batch, image_size=hw)
     res.update(_guard_ab(model, loss_fn, opt, smoke, step,
                          (img, label), steps))
-    res.update(_obs_ab(step, (img, label), steps))
-    res.update(_flight_ab(step, (img, label), steps))
     return res
 
 
@@ -519,8 +426,6 @@ def _bench_llama(smoke, peak_tflops):
                    n_params=nparams, **flash_info)
     res.update(_guard_ab(model, loss_fn, opt, smoke, step,
                          (ids, ids), steps))
-    res.update(_obs_ab(step, (ids, ids), steps))
-    res.update(_flight_ab(step, (ids, ids), steps))
     return res
 
 

@@ -94,6 +94,19 @@ gauges on the /metrics endpoint), and flight-recorder events
 and ``serve.spec_verify``) so ``tools/postmortem.py`` can autopsy a
 pool-exhaustion shed.
 
+The scheduler loop itself is on the profiler's clock (ISSUE 25): one
+``StepTimeline("serve")`` step per iteration with work, its phases
+``serve.admit`` -> ``serve.prefill.stage|dispatch|fetch|post`` (per
+batch) -> ``serve.decode.grow|stage|dispatch|fetch|emit`` (or one
+``serve.spec``), and ``serve.idle`` while nothing is in flight.  Each
+is a ``jax.profiler.TraceAnnotation`` on this thread's line of a
+profiler trace and a row of ``observability.timeline.spans("serve")``;
+the rows of ``serve.admit`` carry ``queue_wait_ms`` (one value per
+admitted sequence), those of ``serve.prefill.stage`` the padded
+``batch`` x ``bucket`` and the useful ``tokens``.
+``decode_ms`` / ``prefill_ms`` are read off the dispatch and fetch
+spans' own clock reads.
+
 ISSUE 12 (fleet observatory) adds the REQUEST dimension:
 ``submit(tenant=...)`` tags a request for usage accounting (always-on
 labeled counters ``serve_tenant_tokens_in/out`` /
@@ -121,6 +134,7 @@ from ..framework import monitor as _monitor
 from ..observability import flight_recorder as _flight
 from ..observability import trace as _trace
 from ..observability.request_trace import RequestTrace
+from ..observability.timeline import StepTimeline
 from .prefix_cache import PrefixCache
 from .serving import (RequestTimeout, ServeError, ServerClosed,
                       ServerDraining, ServerOverloaded)
@@ -412,6 +426,9 @@ class GenerationServer:
         # without the lock, so they run ON the scheduler thread between
         # steps rather than growing the lock graph
         self._cmds: _queue.Queue = _queue.Queue()
+        # the scheduler loop's phases (serve.admit, serve.decode.* ...):
+        # profiler spans + the in-memory ring of observability.timeline
+        self._tl = StepTimeline("serve")
         self._rid = 0
         self._arrival = 0
         self._compiles = 0
@@ -1003,23 +1020,34 @@ class GenerationServer:
 
     # -- scheduler ---------------------------------------------------
     def _loop(self):
+        tl = self._tl
+        step_i = 0
         try:
             while True:
-                self._drain_cmds()
                 with self._cond:
                     if not self._running:
+                        # commands still queued are run by stop()
                         return
                     if not self._active and not self._waiting \
                             and self._cmds.empty():
-                        self._cond.wait(timeout=0.05)
+                        with tl.phase("idle"):
+                            self._cond.wait(timeout=0.05)
                         continue
-                self._expire_waiting()
-                self._admit()
-                if self._active:
-                    if self._spec:
-                        self._spec_once()
-                    else:
-                        self._decode_once()
+                with tl.step(step_i):
+                    step_i += 1
+                    with tl.phase("admit") as ph:
+                        self._drain_cmds()
+                        self._expire_waiting()
+                        batches, waits = self._admit()
+                        ph.set(queue_wait_ms=waits)
+                    for bucket, seqs in batches:
+                        self._prefill_batch(seqs, bucket)
+                    if self._active:
+                        if self._spec:
+                            with tl.phase("spec"):
+                                self._spec_once()
+                        else:
+                            self._decode_once()
         except BaseException as e:   # noqa: BLE001 — fail streams loudly
             with self._lock:
                 victims = (list(self._waiting)
@@ -1066,9 +1094,11 @@ class GenerationServer:
     # -- admission + prefill -----------------------------------------
     def _admit(self):
         """Admit as many waiting sequences as slots + blocks allow, in
-        strict (priority, arrival) order, then prefill them in batches
-        grouped by prompt/suffix bucket (ONE dispatch per group chunk
-        — the batched-prefill win)."""
+        strict (priority, arrival) order, and group them into prefill
+        batches by prompt/suffix bucket (ONE dispatch per group chunk
+        — the batched-prefill win).  Returns ``(batches, waits)``:
+        ``[(bucket, seqs), ...]`` for :meth:`_prefill_batch` and each
+        admitted sequence's milliseconds since ``submit()``."""
         taken: List[_GenSeq] = []
         forks: List[tuple] = []
         rollback: Optional[_GenSeq] = None
@@ -1156,11 +1186,13 @@ class GenerationServer:
                            available=self._cache.available())
             if rollback.rt is not None:
                 rollback.rt.instant("admit_rollback")
+        waits: List[float] = []
         for seq in taken:
             # usage accounting at admission: queue age per wait,
             # prompt tokens once per REQUEST (re-admissions re-alias,
             # they don't re-ingest)
             queue_ms = (time.monotonic() - seq.t_submit) * 1e3
+            waits.append(queue_ms)
             if seq.evictions == 0:
                 _monitor.stat_add("serve_tokens_in", seq.L)
             if seq.tenant is not None:
@@ -1182,7 +1214,7 @@ class GenerationServer:
                 seq.rt.instant("admit", kind=kind, cached=seq.cached,
                                blocks=len(seq.blocks), slot=seq.slot)
         if not taken:
-            return
+            return [], waits
         # COW-fork each aliased tail block the suffix prefill will
         # write into (refcount > 1 counts the index entry, so an
         # indexed original is never clobbered): device-copy into the
@@ -1202,10 +1234,10 @@ class GenerationServer:
         for seq in taken:
             groups.setdefault(self._bucket_for(seq.L - seq.cached),
                               []).append(seq)
-        for bucket, seqs in sorted(groups.items()):
-            for i in range(0, len(seqs), self._pbatches[-1]):
-                self._prefill_batch(seqs[i:i + self._pbatches[-1]],
-                                    bucket)
+        step = self._pbatches[-1]
+        return [(bucket, seqs[i:i + step])
+                for bucket, seqs in sorted(groups.items())
+                for i in range(0, len(seqs), step)], waits
 
     def _bucket_for(self, L: int) -> int:
         for b in self._buckets:
@@ -1222,58 +1254,65 @@ class GenerationServer:
     def _prefill_batch(self, seqs: List[_GenSeq], bucket: int):
         """One prefill dispatch for up to max_prefill_batch sequences
         sharing a bucket; padding rows (length 0) write only trash."""
-        B = self._pbatch_for(len(seqs))
-        W = int(seqs[0].key_data.shape[-1])
-        prompt = np.zeros((B, bucket), np.int32)
-        start = np.zeros((B,), np.int32)
-        length = np.zeros((B,), np.int32)
-        tables = np.zeros((B, self._M), np.int32)
-        kd = np.zeros((B, W), np.uint32)
-        temp = np.ones((B,), np.float32)
-        top_k = np.zeros((B,), np.int32)
-        top_p = np.ones((B,), np.float32)
-        do_sample = np.zeros((B,), bool)
-        for i, seq in enumerate(seqs):
-            sfx = seq.prompt[seq.cached:]
-            prompt[i, :sfx.shape[0]] = sfx
-            start[i] = seq.cached
-            length[i] = sfx.shape[0]
-            tables[i, :len(seq.blocks)] = seq.blocks
-            kd[i] = seq.key_data
-            temp[i] = seq.temp
-            top_k[i] = seq.top_k
-            top_p[i] = seq.top_p
-            do_sample[i] = seq.do_sample
-            if seq.rt is not None:
-                seq.rt.begin("prefill")
-        t0 = time.perf_counter()
-        first, self._pools = self._prefill_fn(
-            self._pvals, self._pools, prompt, start, length, tables,
-            kd, temp, top_k, top_p, do_sample)
-        if self._spec:
-            _, self._dpools = self._draft_prefill_fn(
-                self._dvals, self._dpools, prompt, start, length,
-                tables, kd, temp, top_k, top_p, do_sample)
-        first = np.asarray(first)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        with self._lock:
-            self._stats["prefill_ms"] += dt_ms
-            self._stats["prefill_batches"] += 1
-            self._stats["prefill_bucket_hits"][bucket] = \
-                self._stats["prefill_bucket_hits"].get(bucket, 0) \
-                + len(seqs)
-            self._stats["prefill_tokens"] += int(
-                sum(s.L - s.cached for s in seqs))
-            self._stats["prefill_tokens_skipped"] += int(
-                sum(s.cached for s in seqs))
-        if _monitor.metrics_enabled():
-            _monitor.hist_observe("prefill_ms", dt_ms)
-        for seq in seqs:
-            if seq.rt is not None:
-                seq.rt.end("prefill", bucket=bucket, batch=len(seqs),
-                           suffix=seq.L - seq.cached)
-        for i, seq in enumerate(seqs):
-            self._post_prefill(seq, int(first[i]), bucket)
+        tl = self._tl
+        with tl.phase("prefill.stage") as ph:
+            B = self._pbatch_for(len(seqs))
+            W = int(seqs[0].key_data.shape[-1])
+            prompt = np.zeros((B, bucket), np.int32)
+            start = np.zeros((B,), np.int32)
+            length = np.zeros((B,), np.int32)
+            tables = np.zeros((B, self._M), np.int32)
+            kd = np.zeros((B, W), np.uint32)
+            temp = np.ones((B,), np.float32)
+            top_k = np.zeros((B,), np.int32)
+            top_p = np.ones((B,), np.float32)
+            do_sample = np.zeros((B,), bool)
+            for i, seq in enumerate(seqs):
+                sfx = seq.prompt[seq.cached:]
+                prompt[i, :sfx.shape[0]] = sfx
+                start[i] = seq.cached
+                length[i] = sfx.shape[0]
+                tables[i, :len(seq.blocks)] = seq.blocks
+                kd[i] = seq.key_data
+                temp[i] = seq.temp
+                top_k[i] = seq.top_k
+                top_p[i] = seq.top_p
+                do_sample[i] = seq.do_sample
+                if seq.rt is not None:
+                    seq.rt.begin("prefill")
+            tokens = int(length.sum())
+            ph.set(bucket=bucket, batch=B, tokens=tokens)
+        with tl.phase("prefill.dispatch") as disp:
+            first, self._pools = self._prefill_fn(
+                self._pvals, self._pools, prompt, start, length, tables,
+                kd, temp, top_k, top_p, do_sample)
+            if self._spec:
+                _, self._dpools = self._draft_prefill_fn(
+                    self._dvals, self._dpools, prompt, start, length,
+                    tables, kd, temp, top_k, top_p, do_sample)
+        with tl.phase("prefill.fetch") as fetch:
+            first = np.asarray(first)
+        # prefill_ms keeps its meaning (dispatch through fetch), read
+        # off the two spans' clocks
+        dt_ms = (fetch.t1 - disp.t0) * 1e3
+        with tl.phase("prefill.post"):
+            with self._lock:
+                self._stats["prefill_ms"] += dt_ms
+                self._stats["prefill_batches"] += 1
+                self._stats["prefill_bucket_hits"][bucket] = \
+                    self._stats["prefill_bucket_hits"].get(bucket, 0) \
+                    + len(seqs)
+                self._stats["prefill_tokens"] += tokens
+                self._stats["prefill_tokens_skipped"] += int(
+                    sum(s.cached for s in seqs))
+            if _monitor.metrics_enabled():
+                _monitor.hist_observe("prefill_ms", dt_ms)
+            for seq in seqs:
+                if seq.rt is not None:
+                    seq.rt.end("prefill", bucket=bucket, batch=len(seqs),
+                               suffix=seq.L - seq.cached)
+            for i, seq in enumerate(seqs):
+                self._post_prefill(seq, int(first[i]), bucket)
 
     def _post_prefill(self, seq: _GenSeq, first: int, bucket: int):
         # a replay-submitted request (ISSUE 18 failover: generated
@@ -1447,62 +1486,69 @@ class GenerationServer:
 
     # -- plain decode -------------------------------------------------
     def _decode_once(self):
-        self._grow_or_evict()
-        with self._lock:
-            live = sorted(self._active.values(), key=lambda s: s.slot)
-        if not live:
-            return
-        B, M = self._num_slots, self._M
-        W = live[0].key_data.shape[-1]
-        tokens = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B, 1), np.int32)
-        tables = np.zeros((B, M), np.int32)
-        wm = np.zeros((B, 1), bool)
-        kd = np.zeros((B, W), np.uint32)
-        rng_steps = np.zeros((B,), np.int32)
-        temp = np.ones((B,), np.float32)
-        top_k = np.zeros((B,), np.int32)
-        top_p = np.ones((B,), np.float32)
-        do_sample = np.zeros((B,), bool)
-        for seq in live:
-            s = seq.slot
-            tokens[s, 0] = seq.generated[seq.decoded]
-            positions[s, 0] = seq.L + seq.decoded
-            tables[s, :len(seq.blocks)] = seq.blocks
-            wm[s, 0] = True
-            kd[s] = seq.key_data
-            rng_steps[s] = seq.decoded + 1
-            temp[s] = seq.temp
-            top_k[s] = seq.top_k
-            top_p[s] = seq.top_p
-            do_sample[s] = seq.do_sample
-        t0 = time.perf_counter()
-        nxt, self._pools = self._decode_fn(
-            self._pvals, self._pools, tokens, positions, tables, wm,
-            kd, rng_steps, temp, top_k, top_p, do_sample)
-        nxt = np.asarray(nxt)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        replays = 0
-        every = _trace.trace_every()
-        for seq in live:
-            s = seq.slot
-            seq.decoded += 1
-            if seq.rt is not None and seq.decoded % every == 0:
-                # sampled per-request decode span (PADDLE_TRACE_EVERY)
-                seq.rt.span_at("decode", dt_ms, step=seq.decoded)
-            j = seq.decoded + 1          # 1-based index produced
-            if j <= len(seq.generated):
-                replays += 1             # catching up after eviction
-                if self._check_replay \
-                        and int(nxt[s]) != seq.generated[j - 1]:
-                    raise AssertionError(
-                        f"replayed decode step for request {seq.rid} "
-                        f"produced {int(nxt[s])}, stream already "
-                        f"emitted {seq.generated[j - 1]} — paged "
-                        "decode is not bit-stable")
-            else:
-                self._emit(seq, int(nxt[s]))
-        self._after_step(len(live), replays, dt_ms)
+        tl = self._tl
+        with tl.phase("decode.grow"):
+            self._grow_or_evict()
+        with tl.phase("decode.stage"):
+            with self._lock:
+                live = sorted(self._active.values(), key=lambda s: s.slot)
+            if not live:
+                return
+            B, M = self._num_slots, self._M
+            W = live[0].key_data.shape[-1]
+            tokens = np.zeros((B, 1), np.int32)
+            positions = np.zeros((B, 1), np.int32)
+            tables = np.zeros((B, M), np.int32)
+            wm = np.zeros((B, 1), bool)
+            kd = np.zeros((B, W), np.uint32)
+            rng_steps = np.zeros((B,), np.int32)
+            temp = np.ones((B,), np.float32)
+            top_k = np.zeros((B,), np.int32)
+            top_p = np.ones((B,), np.float32)
+            do_sample = np.zeros((B,), bool)
+            for seq in live:
+                s = seq.slot
+                tokens[s, 0] = seq.generated[seq.decoded]
+                positions[s, 0] = seq.L + seq.decoded
+                tables[s, :len(seq.blocks)] = seq.blocks
+                wm[s, 0] = True
+                kd[s] = seq.key_data
+                rng_steps[s] = seq.decoded + 1
+                temp[s] = seq.temp
+                top_k[s] = seq.top_k
+                top_p[s] = seq.top_p
+                do_sample[s] = seq.do_sample
+        with tl.phase("decode.dispatch") as disp:
+            nxt, self._pools = self._decode_fn(
+                self._pvals, self._pools, tokens, positions, tables, wm,
+                kd, rng_steps, temp, top_k, top_p, do_sample)
+        with tl.phase("decode.fetch") as fetch:
+            nxt = np.asarray(nxt)
+        # decode_ms keeps its meaning (dispatch through fetch), read
+        # off the two spans' clocks
+        dt_ms = (fetch.t1 - disp.t0) * 1e3
+        with tl.phase("decode.emit"):
+            replays = 0
+            every = _trace.trace_every()
+            for seq in live:
+                s = seq.slot
+                seq.decoded += 1
+                if seq.rt is not None and seq.decoded % every == 0:
+                    # sampled per-request decode span (PADDLE_TRACE_EVERY)
+                    seq.rt.span_at("decode", dt_ms, step=seq.decoded)
+                j = seq.decoded + 1          # 1-based index produced
+                if j <= len(seq.generated):
+                    replays += 1             # catching up after eviction
+                    if self._check_replay \
+                            and int(nxt[s]) != seq.generated[j - 1]:
+                        raise AssertionError(
+                            f"replayed decode step for request {seq.rid} "
+                            f"produced {int(nxt[s])}, stream already "
+                            f"emitted {seq.generated[j - 1]} — paged "
+                            "decode is not bit-stable")
+                else:
+                    self._emit(seq, int(nxt[s]))
+            self._after_step(len(live), replays, dt_ms)
 
     def _after_step(self, n_live: int, replays: int, dt_ms: float):
         with self._lock:

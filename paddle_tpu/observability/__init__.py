@@ -1,7 +1,9 @@
 """paddle_tpu.observability — unified tracing, metrics and timelines.
 
-The ISSUE 5 subsystem, three pillars over one design rule (everything
-off by default, opt-in by env, ~zero cost when off):
+The ISSUE 5 subsystem, three pillars.  The file sinks (JSONL spans,
+``/metrics``) are off by default and opt-in by env; the step
+timeline's profiler annotations and in-memory ring are always on and
+bounded (1.5 to 7 us a phase), and so is the flight recorder's ring:
 
 1. **Cross-process tracing** (:mod:`.trace`): ``Span`` trees with
    trace/span-id propagation stamped through the PS RPC frame header,
@@ -16,8 +18,14 @@ off by default, opt-in by env, ~zero cost when off):
    verdicts), exported as a Prometheus ``/metrics`` endpoint and/or a
    periodic JSONL flusher.
 3. **Step timeline** (:mod:`.timeline`): per-step phase attribution
-   (data wait / h2d / dispatch / health fetch / host) with
-   ``trace_every=N`` sampling.
+   for the train step (data wait / h2d / dispatch / health fetch /
+   host) and the serving scheduler's loop (``serve.admit`` /
+   ``serve.prefill.*`` / ``serve.decode.*``).  Every phase is a
+   ``jax.profiler.TraceAnnotation`` (on the device ops' clock in a
+   profiler trace) and one row of a bounded in-memory ring read with
+   ``timeline.spans(name, since, until)``; JSONL spans with
+   ``trace_every=N`` sampling and ``step_<phase>_ms`` histograms as
+   before.
 
 ISSUE 12 grows the subsystem into a FLEET observatory:
 
@@ -41,7 +49,8 @@ Env quick reference::
     PADDLE_METRICS=1  PADDLE_METRICS_PORT=9464  PADDLE_METRICS_FILE=...
     PADDLE_METRICS_HOST=127.0.0.1   (loopback default; opt into wider)
 
-Importable without jax (PS server subprocesses stay lightweight).
+Importable without jax (PS server subprocesses stay lightweight; the
+first ``StepTimeline`` made imports ``jax.profiler``).
 """
 from __future__ import annotations
 

@@ -456,7 +456,10 @@ def test_multiprocess_wide_deep_merged_trace(tmp_path):
     try:
         # wait for the replica to catch up (its sink then has the
         # replicate clock sample)
-        deadline = time.monotonic() + 20.0
+        # (the waits below are generous: under a loaded host a server
+        # subprocess takes its time, and a wait that is too short shows
+        # nothing about the trace)
+        deadline = time.monotonic() + 90.0
         while not os.path.exists(
                 tmp_path / f"trace-ps0r-{rep_pid}.jsonl"):
             assert time.monotonic() < deadline, "replica never attached"
@@ -469,7 +472,12 @@ def test_multiprocess_wide_deep_merged_trace(tmp_path):
                 p.terminate()
             except OSError:
                 pass
-            p.wait(timeout=10)
+        for p in (prim, rep):
+            try:
+                p.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                p.kill()
+                p.wait(timeout=60)
 
     sinks = [str(tmp_path / f"trace-trainer-{os.getpid()}.jsonl"),
              str(tmp_path / f"trace-ps0-{prim_pid}.jsonl"),
@@ -485,11 +493,32 @@ def test_multiprocess_wide_deep_merged_trace(tmp_path):
     merged = json.load(open(out))
     offs = merged["metadata"]["clock_offsets_us"]
     assert all(v is not None for v in offs.values()), offs
+    # how far a corrected timestamp of each process can be off: half
+    # the round trip its offset was estimated from (pid i+1 = sink i)
+    errs = merged["metadata"]["clock_error_us"]
+    assert list(errs) == list(offs) and errs[list(errs)[0]] == 0.0
+    err_of_pid = {i + 1: e for i, e in enumerate(errs.values())}
 
     evs = [e for e in merged["traceEvents"] if e["ph"] == "X"]
     by_span = {e["args"]["span"]: e for e in evs}
     pids = {e["pid"] for e in evs}
     assert len(pids) == 3              # three process tracks survived
+
+    # the serve thread of a connection takes one request at a time: the
+    # request that followed each server span on its track, and the
+    # client call that carried it
+    next_call = {}
+    tracks = {}
+    for k in sorted(evs, key=lambda k: k["ts"]):
+        if k["name"].startswith("ps.server."):
+            tracks.setdefault((k["pid"], k["tid"]), []).append(k)
+    for track in tracks.values():
+        for k, nxt in zip(track, track[1:]):
+            next_call[k["args"]["span"]] = by_span.get(
+                nxt["args"].get("parent"))
+
+    def end(ev):
+        return ev["ts"] + ev["dur"]
 
     checked = 0
     for e in evs:
@@ -502,11 +531,26 @@ def test_multiprocess_wide_deep_merged_trace(tmp_path):
         for k in kids:
             assert k["args"]["trace"] == e["args"]["trace"]
             assert k["pid"] != e["pid"]
-            # clock-corrected containment (1 ms slack for clock
-            # estimation error on the register round trip)
-            assert k["ts"] >= e["ts"] - 1000
-            assert k["ts"] + k["dur"] <= e["ts"] + e["dur"] + 1000
+            # clock-corrected containment: 1 ms of slack, plus what
+            # the recorded round trip says the correction itself can
+            # be off by (a loaded host widens that trip)
+            slack = 1000 + err_of_pid[k["pid"]]
+            assert e["ts"] - slack <= k["ts"] <= end(e) + slack
             checked += 1
+            if end(k) <= end(e) + slack:
+                continue
+            # The server span closes AFTER its reply is on the wire, so
+            # a serve thread that loses the CPU right there closes
+            # late whatever the clocks say: with 16 busy processes on
+            # 8 cores, 4 runs of 24 had one span end 517 to 3,516 us
+            # past this slack, each 2.3 to 4.8 ms longer than the
+            # client's whole call.  What the client recorded still
+            # bounds it: the thread closed this span before it took
+            # the connection's next request, so before the call that
+            # carried that request returned.
+            nxt = next_call.get(k["args"]["span"])
+            assert nxt is not None and nxt["pid"] == e["pid"], k
+            assert end(e) <= nxt["ts"] and end(k) <= end(nxt) + slack
     assert checked >= 12               # 6 pulls + 6 pushes at least
 
     # the replication chain: primary's forward span (child of its

@@ -75,6 +75,16 @@ def read_sink(path: str) -> dict:
 def solve_offsets(sinks: List[dict]) -> Dict[str, Optional[float]]:
     """Per-sink clock offset (sink_clock - root_clock, microseconds)
     via BFS over the lowest-RTT clock edges; None = unreachable."""
+    return solve_clocks(sinks)[0]
+
+
+def solve_clocks(sinks: List[dict]) -> Tuple[
+        Dict[str, Optional[float]], Dict[str, Optional[float]]]:
+    """``(offsets, errors)`` per sink, microseconds.  An edge's offset
+    is estimated at the midpoint of a round trip, so it is off by at
+    most half that trip's ``rtt_us``; ``errors`` adds those halves up
+    along the path from the root: how far a corrected timestamp of the
+    sink can lie from where the root's clock would have put it."""
     ids = [s["sink"] for s in sinks]
     # best (lowest-rtt) sample per directed pair: offset of peer vs self
     best: Dict[Tuple[str, str], Tuple[float, float]] = {}
@@ -85,22 +95,24 @@ def solve_offsets(sinks: List[dict]) -> Dict[str, Optional[float]]:
             if key not in best or rtt < best[key][1]:
                 best[key] = (float(c.get("offset_us", 0.0)), rtt)
     # undirected adjacency with signed offsets
-    adj: Dict[str, List[Tuple[str, float]]] = {i: [] for i in ids}
-    for (a, b), (off, _rtt) in best.items():
+    adj: Dict[str, List[Tuple[str, float, float]]] = {i: [] for i in ids}
+    for (a, b), (off, rtt) in best.items():
         if a in adj and b in adj:
-            adj[a].append((b, off))       # b_clock - a_clock = off
-            adj[b].append((a, -off))
+            adj[a].append((b, off, rtt))  # b_clock - a_clock = off
+            adj[b].append((a, -off, rtt))
     offsets: Dict[str, Optional[float]] = {i: None for i in ids}
+    errors: Dict[str, Optional[float]] = {i: None for i in ids}
     root = ids[0]
-    offsets[root] = 0.0
+    offsets[root] = errors[root] = 0.0
     frontier = [root]
     while frontier:
         cur = frontier.pop(0)
-        for nxt, off in adj[cur]:
+        for nxt, off, rtt in adj[cur]:
             if offsets.get(nxt) is None:
                 offsets[nxt] = offsets[cur] + off
+                errors[nxt] = errors[cur] + rtt / 2.0
                 frontier.append(nxt)
-    return offsets
+    return offsets, errors
 
 
 def merge_sinks(sinks: List[dict]) -> dict:
@@ -111,7 +123,7 @@ def merge_sinks(sinks: List[dict]) -> dict:
     warning goes to stderr, and the sink is listed under
     ``metadata.uncorrected`` so tooling can tell estimated-aligned
     tracks from as-recorded ones."""
-    offsets = solve_offsets(sinks)
+    offsets, errors = solve_clocks(sinks)
     uncorrected = []
     for s in sinks:
         if offsets[s["sink"]] is None:
@@ -181,9 +193,9 @@ def merge_sinks(sinks: List[dict]) -> dict:
                            "ts": ts})
     events.sort(key=lambda e: (e.get("ts", 0.0), e["ph"] != "M"))
     return {"traceEvents": events, "displayTimeUnit": "ms",
-            "metadata": {"clock_offsets_us": {
-                k: v for k, v in offsets.items()},
-                "uncorrected": uncorrected}}
+            "metadata": {"clock_offsets_us": dict(offsets),
+                         "clock_error_us": dict(errors),
+                         "uncorrected": uncorrected}}
 
 
 def merge_files(paths: List[str]) -> dict:
