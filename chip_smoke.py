@@ -483,6 +483,24 @@ def _kernel_cases(size):
         lambda: kreg.dispatch("int8_kv_attention", *kv, g_,
                               mode="xla_ref"),
         1e-4, 2e-5)
+
+    # paged_attention: a decode step over bf16 pools, ragged lengths
+    # (1, one block, one past it, the whole table), tables padded with
+    # the trash block
+    lens = np.array([1, bs, bs + 1, bs * mtab], np.int32)
+    ptbl = np.zeros((b, mtab), np.int32)
+    for i, n_ in enumerate(lens):
+        used = -(-int(n_) // bs)
+        ptbl[i, :used] = 1 + rng.permutation(nb - 1)[:used]
+    pa = [randn(b, s_, g_ * r_, d_, dtype=jnp.bfloat16),
+          randn(nb, bs, g_, d_, dtype=jnp.bfloat16),
+          randn(nb, bs, g_, d_, dtype=jnp.bfloat16), None, None,
+          jnp.asarray(ptbl), jnp.asarray(lens[:, None] - 1)]
+    cases["paged_attention"] = (
+        lambda mode: kreg.dispatch("paged_attention", *pa, g_, mode=mode),
+        lambda: kreg.dispatch("paged_attention", *pa, g_,
+                              mode="xla_ref"),
+        2e-2, 0.0)    # bf16 out (eps 2^-7)
     return cases
 
 

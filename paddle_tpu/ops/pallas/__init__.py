@@ -16,6 +16,8 @@ Kernels (see each module's docstring for the tolerance contract):
 - ``int8_matmul`` — int8-weight matmul with in-tile dequant (serving)
 - ``int8_kv_attention`` — paged decode/verify attention reading int8
   KV pools once, per-(block, slot) scales applied inside the gather
+- ``paged_attention`` — decode attention over bf16 paged KV pools read
+  through the block table up to each row's length (no gather into HBM)
 - ``segment_sum`` — device-side fused sparse-grad merge mirroring
   ``native/ps_core.cc``'s ``ps_segsum_inv``
 - ``pull_dequant`` — on-device reconstruction of int8 PS pull rows
@@ -31,7 +33,7 @@ from .flash_attention import (flash_attention,  # noqa: F401
                               flash_attention_bhsd)
 from .int8_matmul import int8_matmul_pallas, int8_matmul_ref  # noqa: F401
 from .kv_attention import (int8_paged_attention,  # noqa: F401
-                           paged_attention_ref)
+                           paged_attention, paged_attention_ref)
 from .opt_apply import opt_apply_pallas, opt_apply_ref  # noqa: F401
 from .pull_dequant import (pull_dequant_pallas,  # noqa: F401
                            pull_dequant_ref)

@@ -1,46 +1,60 @@
-"""Fused int8-KV dequant-attention for paged decode/verify (kernel 3).
+"""Paged decode attention over the serving KV pools: the XLA gather
+reference and two Pallas kernels that read the pools through the block
+table.
 
-PR 11's int8 KV pools quantize on write and dequantize on gather —
-but the XLA gather path is two passes over the pools: gather+dequant
-materializes the full f32 ``[B, T, KH, D]`` cache view, then attention
-reads it again.  This kernel applies the per-(block, slot) scales
-INSIDE the attention gather: the int8 pools are read ONCE, block by
-block through the sequence's block table (scalar-prefetched so the
-table drives the DMA index map), dequantized in VMEM, and folded into
-a blockwise online-softmax accumulation — the ROADMAP-named follow-up
-to PR 11.
+``paged_attention_ref`` is the XLA gather/attend path, lifted verbatim
+from ``LlamaAttention.forward_paged`` so the non-pallas serving
+contracts (replay, prefix sharing, eviction) are pinned by the SAME
+function.  It is the fallback and the parity oracle of both kernels.
+What it costs on the chip (PERF.md, PR 26): ``kpool[tbl]`` is
+materialised, and where ``num_slots * max_blocks == num_blocks`` that
+gather is as large as the pool; the einsums then run over every
+position of the table, masked afterwards.
 
-Grid ``(B, G, M)`` — batch x kv-head x table block, M innermost so the
-running (max, denom, acc) scratch carries across a sequence's blocks.
-The validity mask is the same ``slot <= position`` inequality the XLA
-path uses (simultaneously the causal mask within a verify block and
-the prefix mask against the cache); trash-block (physical block 0)
-slots always fail it, and a fully-masked block contributes exactly
-zero via the masked ``p`` term (never via ``exp(-inf)`` arithmetic).
+``paged_attention`` (kernel ``paged_attention``, PR 26) is the decode
+path (``S == 1``) for bf16 pools.  Grid ``(B,)``; the lengths
+(``pos + 1``) and the flattened table are scalar-prefetched; the pools
+stay in HBM (``memory_space=ANY``) and each row copies only its pages
+``0 .. pos // bs`` into VMEM, ``pages_per_block`` at a time, double
+buffered, the first block of the next row in flight while the last of
+this one is computed.  Blocks are folded in LOGICAL order into an
+online softmax (f32 logits, f32 statistics, f32 accumulation), so the
+output depends neither on physical block ids nor on the other rows of
+the batch; positions past the length are masked to an exact zero
+weight, pages past it are neither copied nor computed.
 
-Parity vs :func:`paged_attention_ref` (the XLA gather path, lifted
-verbatim from ``LlamaAttention.forward_paged`` so the non-pallas
-serving contracts — replay, prefix sharing, eviction — are pinned by
-the SAME function): online softmax re-associates the f32
-exp/sum/weighted-sum chain, documented tolerance atol 2e-5 /
-rtol 1e-4.  The quantization itself is exact (the kernel multiplies
-the same int8 codes by the same f32 scales).
+Layouts Mosaic took (compiled for v5e, ``tests/test_tpu_lowering.py``;
+run on one, PERF.md PR 26): the pool stays ``[nb, bs, KH, D]``.  A
+``(1, bs, 1, D)`` block of it does not lower (PR 21: a one-head slice
+of ``KH`` is neither a multiple of 8 nor the whole dim), but the whole
+page does: ``[nb, bs, KH, D]`` viewed as ``[nb, bs*KH, D]`` is a
+bitcast in XLA's tiled HBM layout (``T(8,128)(2,1)`` either way), and a
+``(bs*KH, D)`` page, (128, 128) bf16 at the serving shapes, is a legal
+DMA source and a tile-aligned VMEM block.  Its rows interleave the kv
+heads ``(t, g)``, so the kernel multiplies ALL query heads by the whole
+block in one MXU call and masks the 7/8 of the logits whose kv head is
+not the query's; a head-major pool ``[nb, KH, bs, D]`` would avoid that
+but turns the write of a token into ``KH`` rows of 256 B, and the
+measured kernel is DMA-bound as it is.
 
-TPU status (PR 21): the kernel runs under the interpreter only.  Its
-block specs tile the serving pools as they are laid out,
-``[nb, bs, KH, D]`` (``LlamaForCausalLM.init_paged_cache``): a
-``(1, bs, 1, D)`` pool block, ``(1, bs)`` scale blocks and a
-``(1, S*R)`` position block.  Mosaic requires a block's last two dims
-to be multiples of (8, 128) or the whole array dims, and a one-head
-slice of the ``KH`` dim is neither — the lowering error is quoted in
-PERF.md ("Bring-up on v5e").  A real repair is a head-major pool layout
-(ROADMAP S6 / D3), not a spec tweak, so the registration defaults this
-ONE kernel to ``xla_ref`` on TPU: a ``kv_cache_dtype="int8"`` server
-traces the gather path there instead of raising.
+``int8_paged_attention`` (kernel ``int8_kv_attention``, PR 13) fuses the
+dequant of int8 pools into the gather for decode/verify: grid
+``(B, G, M)``, M innermost so the running (max, denom, acc) scratch
+carries across a sequence's blocks; per-(block, slot) scales applied
+in VMEM.  Parity vs the reference: atol 2e-5 / rtol 1e-4 (online
+softmax re-associates the f32 exp/sum/weighted-sum chain; the dequant
+is exact).  It runs under the interpreter only: its ``(1, bs, 1, D)``
+pool block is the one that does not lower, so the registration defaults
+it to ``xla_ref`` on TPU.  The repair is the whole-page read above with
+the scales applied to the ``(bs*KH, D)`` rows (ROADMAP M4).
+
+In every path the validity mask is the same ``slot <= position``
+inequality; trash-block (physical block 0) slots always fail it.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +63,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
-__all__ = ["paged_attention_ref", "int8_paged_attention"]
+__all__ = ["paged_attention_ref", "paged_attention",
+           "int8_paged_attention"]
 
 _NEG = -1e30
 
@@ -87,6 +102,182 @@ def paged_attention_ref(qh, kpool, vpool, kscale, vscale, tbl, pos,
     logits = jnp.where(valid, logits, -jnp.inf)
     w = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bgrst,btgd->bsgrd", w, vgf).astype(qh.dtype)
+
+
+# ---------------------------------------------------------------------
+# bf16 decode kernel (PR 26)
+# ---------------------------------------------------------------------
+
+# pages per grid-step block: 16 was the fastest on a v5e at both serving
+# shapes (PERF.md, PR 26: 4 / 8 / 16 / 32 pages read 4.13 / 2.78 /
+# 2.56 / 2.93 ms for 8 layers of 128 rows x ~320 positions)
+_PAGES_PER_BLOCK = 16
+
+
+def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale,
+                       len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       kbuf, vbuf, sem, slot_ref):
+    b = pl.program_id(0)
+    rows = bs * kh                    # rows of one page, (t, g) order
+    n = P * rows
+    h = kh * r
+
+    def n_pages(bb):
+        return (len_ref[bb] + bs - 1) // bs
+
+    def each_page(bb, i, slot, op):
+        """``start`` or ``wait`` the K and V copies of block ``i`` of
+        row ``bb``: one page each, and none past the row's length.  A
+        loop on the scalar core, not ``P`` unrolled copies: the kernel
+        is traced and lowered once per layer at every start-up, and
+        unrolled it cost a server seconds of set-up (PERF.md, PR 26)."""
+        def one(p, carry):
+            page = tbl_ref[bb * m_tbl + i * P + p]
+            dst = pl.ds(pl.multiple_of(p * rows, rows), rows)
+            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, dst],
+                    sem.at[which, slot]), op)()
+            return carry
+        jax.lax.fori_loop(0, jnp.clip(n_pages(bb) - i * P, 0, P), one, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # rows no copy ever reached must hold finite values: their
+        # weight is an exact zero, and 0 * NaN is not
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        each_page(0, 0, 0, "start")
+
+    length = len_ref[b]
+    nblk = (n_pages(b) + P - 1) // P
+    q = q_ref[0]                                       # (H, D)
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
+    own_head = (col % kh) == (row // r)
+    t_loc = col // kh
+
+    def body(i, carry):
+        m, l, acc = carry
+        slot = slot_ref[0]
+        nxt = 1 - slot
+        last = i == nblk - 1
+
+        # the next block is in flight while this one is computed: the
+        # row's own, or the first of the next row
+        @pl.when(jnp.logical_not(last))
+        def _():
+            each_page(b, i + 1, nxt, "start")
+
+        @pl.when(jnp.logical_and(last, b + 1 < n_rows))
+        def _():
+            each_page(b + 1, 0, nxt, "start")
+        slot_ref[0] = nxt
+        each_page(b, i, slot, "wait")
+        k = kbuf[slot]                                 # (P*bs*KH, D)
+        v = vbuf[slot]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        valid = jnp.logical_and(own_head,
+                                t_loc < length - i * (P * bs))
+        s = jnp.where(valid, s, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        # masked slots contribute EXACT zeros, whatever the buffer holds
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + p.sum(axis=1, keepdims=True)
+        if v.dtype == jnp.bfloat16:
+            # f32 p . bf16 v in ONE pass over V in the MXU: p = hi + lo,
+            # both bf16, stacked as rows; what is dropped is under
+            # 2**-17 of p.  (Mosaic's own f32 dot rounds p to bf16 on
+            # the chip: 4% faster, four times the error, PERF.md PR 26)
+            hi = p.astype(jnp.bfloat16)
+            lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv = jnp.dot(jnp.concatenate([hi, lo], axis=0), v,
+                         preferred_element_type=jnp.float32)
+            pv = pv[:h] + pv[h:]
+        else:
+            pv = jnp.dot(p, v.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    d = q.shape[-1]
+    m, l, acc = jax.lax.fori_loop(
+        0, nblk, body,
+        (jnp.full((h, 1), _NEG, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, d), jnp.float32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
+def paged_attention(qh, kpool, vpool, kscale, vscale, tbl, pos,
+                    kv_heads, *, interpret=False):
+    """Decode attention (``S == 1``) reading K and V through the block
+    table, up to each row's length (see module doc).  Same arguments
+    and result as :func:`paged_attention_ref`; ``kscale``/``vscale``
+    must be None (int8 pools take ``int8_paged_attention``).  Jitted,
+    so that the layers of one decode program share ONE trace and ONE
+    lowering of the kernel."""
+    B, S, H, D = qh.shape
+    if S != 1 or kscale is not None or vscale is not None:
+        raise ValueError("paged_attention is the S == 1 kernel for "
+                         "unquantized pools")
+    nb, bs, KH, _ = kpool.shape
+    R = H // KH
+    M = tbl.shape[1]
+    P = min(_PAGES_PER_BLOCK, M)
+    rows = bs * KH
+    # the step's own K/V are scattered before the attention reads; a
+    # length of at least 1 keeps every row's first block in the chain
+    # of copies (position 0 of an inactive slot is the trash block's)
+    lengths = jnp.clip(pos[:, 0].astype(jnp.int32) + 1, 1, M * bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P * rows, D), kpool.dtype),
+            pltpu.VMEM((2, P * rows, D), vpool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, P, bs, KH, R, M, B,
+                          1.0 / math.sqrt(D)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), qh.dtype),
+        # rows run in order: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention",
+    )(lengths, tbl.astype(jnp.int32).reshape(-1), qh.reshape(B, H, D),
+      kpool.reshape(nb, rows, D), vpool.reshape(nb, rows, D))
+    return out.reshape(B, 1, KH, R, D)
+
+
+def _paged_eligible(qh, kpool, vpool, kscale, vscale, tbl, pos,
+                    kv_heads):
+    # compiled-mode gate, from what the call can see in its input: one
+    # query per row, bf16 pools in the queries' dtype, a page that is
+    # whole (16, 128) bf16 tiles, and no multi-device mesh (Mosaic
+    # calls cannot be partitioned; the reference can)
+    from ...distributed import mesh as mesh_mod
+    mesh = mesh_mod.get_mesh(create=False)
+    D = qh.shape[-1]
+    return (qh.shape[1] == 1 and kscale is None
+            and kpool.dtype == jnp.bfloat16 and qh.dtype == kpool.dtype
+            and D % 128 == 0 and (kpool.shape[1] * kv_heads) % 16 == 0
+            and (mesh is None or mesh.size == 1))
 
 
 def _int8_kv_attn_kernel(bs, sr, d, scale, tbl_ref, qpos_ref, q_ref, k_ref, v_ref,
@@ -201,4 +392,18 @@ registry.register(
     doc="paged decode/verify attention reading int8 KV pools once: "
         "per-(block,slot) scales applied inside the table-driven "
         "gather, blockwise online softmax",
+)
+
+
+registry.register(
+    "paged_attention", paged_attention, paged_attention_ref,
+    tolerance="atol 2e-5 / rtol 1e-4 vs xla_ref on f32 inputs (f32 "
+              "online softmax re-association; p.v in bf16 hi+lo halves "
+              "drops under 2**-17 of p); bit-identical across physical "
+              "block ids and batch neighbours",
+    eligible=_paged_eligible,
+    doc="decode attention over bf16 paged KV pools read through the "
+        "block table up to each row's length: whole pages DMA'd from "
+        "HBM, double buffered, blockwise online softmax; nothing is "
+        "gathered into HBM",
 )

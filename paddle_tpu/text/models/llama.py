@@ -334,10 +334,9 @@ class LlamaAttention(Layer):
         # ISSUE 13: kernel mode resolved OUTSIDE the traced closure and
         # bound into it, so any dispatch cache keys on the mode (a mode
         # switch must never replay the other path's program)
-        kv_mode = None
-        if quant:
-            from ...ops.pallas import registry as _kreg
-            kv_mode = _kreg.resolve("int8_kv_attention")
+        from ...ops.pallas import registry as _kreg
+        kv_kernel = "int8_kv_attention" if quant else "paged_attention"
+        kv_mode = _kreg.resolve(kv_kernel)
 
         def attn_paged(qv, kv, vv, pos, wm, kpool, vpool, tbl,
                        kscale=None, vscale=None):
@@ -415,22 +414,21 @@ class LlamaAttention(Layer):
             # so slot <= pos is simultaneously the causal mask within
             # the block and the prefix mask against the cache.
             #
-            # ISSUE 13: the gather/dequant/attend math lives in
-            # ops/pallas/kv_attention.paged_attention_ref (lifted
-            # verbatim, so the non-pallas serving contracts — replay,
-            # prefix sharing, eviction — are pinned by the SAME ops);
-            # int8 pools additionally dispatch through the registry so
-            # the fused dequant-attention kernel can read the pools
-            # once on TPU (``int8_kv_attention``; xla_ref elsewhere).
-            from ...ops.pallas.kv_attention import paged_attention_ref
-            if quant:
-                from ...ops.pallas import registry as _kreg
-                o = _kreg.dispatch(
-                    "int8_kv_attention", qh, kpool, vpool, kscale,
-                    vscale, tbl, pos, c.kv_heads, mode=kv_mode)
-            else:
-                o = paged_attention_ref(qh, kpool, vpool, None, None,
-                                        tbl, pos, c.kv_heads)
+            # The gather/attend math lives in ops/pallas/kv_attention.
+            # paged_attention_ref (lifted verbatim, so the non-pallas
+            # serving contracts — replay, prefix sharing, eviction — are
+            # pinned by the SAME ops); the registry picks, per traced
+            # program and from what this call sees, a kernel that reads
+            # the pools through the table instead: ``paged_attention``
+            # for a decode step (S == 1, bf16 pools, TPU target),
+            # ``int8_kv_attention`` for int8 pools (xla_ref on TPU
+            # until it lowers).  Verify and suffix-prefill calls keep
+            # the reference.
+            mode = kv_mode
+            if not quant and (S > 1 or verify_mode):
+                mode = "xla_ref"
+            o = _kreg.dispatch(kv_kernel, qh, kpool, vpool, kscale,
+                               vscale, tbl, pos, c.kv_heads, mode=mode)
             return ret(o)
 
         if quant:

@@ -10,6 +10,8 @@ documented on the registration (and in the README table):
 - ``int8_matmul``        dynamic path bit-exact; weight-only within
                          rtol 2e-2 @ bf16 / 1e-5 @ f32
 - ``int8_kv_attention``  atol 2e-5 / rtol 1e-4 (online softmax)
+- ``paged_attention``    atol 2e-5 / rtol 1e-4 on f32 (online softmax);
+  bit-identical across physical block ids and batch neighbours
 - ``segment_sum``        bit-exact for integer-valued grads, atol 1e-6
                          for arbitrary floats
 - ``flash_attention``    compat re-export + dispatch counters (numeric
@@ -55,7 +57,8 @@ def _rand(rng, *shape):
 def test_registry_lists_every_kernel_with_tolerance():
     ks = kreg.kernels()
     for name in ("flash_attention", "opt_apply", "int8_matmul",
-                 "int8_kv_attention", "segment_sum", "pull_dequant"):
+                 "int8_kv_attention", "paged_attention", "segment_sum",
+                 "pull_dequant"):
         assert name in ks, sorted(ks)
         assert ks[name].tolerance, name
         assert callable(ks[name].xla_ref_fn)
@@ -537,6 +540,285 @@ def test_llama_int8_default_route_is_xla_ref_on_cpu():
     c = kreg.dispatch_counts("int8_kv_attention")
     assert set(c) == {"xla_ref"} and c["xla_ref"] >= 1, c
     assert all(np.isfinite(o).all() for o in outs)
+
+
+# ---------------------------------------------------------------------
+# kernel 3b: bf16 paged decode attention through the block table
+# ---------------------------------------------------------------------
+
+def _paged_case(rng, lengths, G=2, R=2, D=16, bs=8, M=5, dtype=np.float32,
+                tables=None):
+    """A decode batch: row i holds ``lengths[i]`` positions on its own
+    physical blocks, its table padded with the trash block (0); the
+    trash block and every unused block hold noise a correct kernel
+    never weighs."""
+    B = len(lengths)
+    nb = 1 + B * M
+    qh = _rand(rng, B, 1, G * R, D)
+    kpool = _rand(rng, nb, bs, G, D)
+    vpool = _rand(rng, nb, bs, G, D)
+    tbl = np.zeros((B, M), np.int32)
+    for i, n in enumerate(lengths):
+        used = -(-n // bs)
+        tbl[i, :used] = 1 + i * M + rng.permutation(M)[:used]
+    if tables is not None:
+        tbl = np.asarray(tables, np.int32)
+    pos = np.asarray(lengths, np.int32)[:, None] - 1
+    return [jnp.asarray(qh, dtype), jnp.asarray(kpool, dtype),
+            jnp.asarray(vpool, dtype), None, None, jnp.asarray(tbl),
+            jnp.asarray(pos)], G
+
+
+_PAGED_CASES = {
+    # lengths 1, bs-1, bs, bs+1 and the full table, small shape
+    "small_ragged": dict(lengths=[1, 7, 8, 9, 40]),
+    # GQA 32/8 x 128, block 16, bf16 pools as served (the hi+lo p.v)
+    "gqa32x8x128_block16_bf16": dict(
+        lengths=[1, 15, 16, 17, 64], G=8, R=4, D=128, bs=16, M=4,
+        dtype=jnp.bfloat16),
+    "gqa32x8x128_block16_f32": dict(
+        lengths=[33, 64, 2], G=8, R=4, D=128, bs=16, M=4),
+    # more pages than one grid-step block holds, the last block partial
+    "three_blocks_of_pages": dict(lengths=[160, 67, 129, 1], bs=4, M=40),
+    "mha_tiny_head": dict(lengths=[5, 12], G=4, R=1, D=8, bs=4, M=3),
+    # slots with the write mask off: position 0, an all-zero table
+    "inactive_rows_zero_table": dict(
+        lengths=[1, 11, 1], bs=8, M=2,
+        tables=[[0, 0], [3, 4], [0, 0]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_CASES))
+def test_paged_attention_interpret_parity(case):
+    """The decode kernel against ``paged_attention_ref``: atol 2e-5 /
+    rtol 1e-4 on f32 inputs (online softmax re-associates the f32
+    sums, as for ``int8_kv_attention``); a bf16 case rounds both
+    results to bf16 last, so they may sit one bf16 ulp apart."""
+    from paddle_tpu.ops.pallas.kv_attention import (paged_attention,
+                                                    paged_attention_ref)
+    args, G = _paged_case(np.random.default_rng(21), **_PAGED_CASES[case])
+    ref = np.asarray(paged_attention_ref(*args, G).astype(jnp.float32))
+    ker = paged_attention(*args, G, interpret=True)
+    assert ker.shape == ref.shape and ker.dtype == args[0].dtype
+    ker = np.asarray(ker.astype(jnp.float32))
+    assert np.isfinite(ker).all()
+    if args[0].dtype == jnp.bfloat16:
+        np.testing.assert_allclose(ker, ref, atol=2 ** -9, rtol=2 ** -7)
+    else:
+        np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_attention_ignores_block_ids_and_neighbours(dtype):
+    """The eviction / replay contract: the same logical sequence gives
+    BIT-identical output on permuted physical blocks, in another slot,
+    beside other rows (blocks are reduced in logical order; what a
+    buffer holds past a row's length weighs exactly zero)."""
+    from paddle_tpu.ops.pallas.kv_attention import paged_attention
+    rng = np.random.default_rng(22)
+    bs, M, n = 4, 40, 150                 # three blocks of pages
+    (qh, kp, vp, _, _, tbl, pos), G = _paged_case(
+        rng, [n, 9, 160], bs=bs, M=M, dtype=jnp.dtype(dtype))
+    a = np.asarray(paged_attention(qh, kp, vp, None, None, tbl, pos, G,
+                                   interpret=True).astype(jnp.float32))
+    # row 0's sequence moves to permuted blocks of a fresh pool (the
+    # rest of it other noise), into slot 2, beside two other rows
+    used = -(-n // bs)
+    nb = kp.shape[0]
+    new_ids = 1 + rng.permutation(nb - 1)[:used]
+    kp2 = np.array(_rand(rng, *kp.shape))
+    vp2 = np.array(_rand(rng, *vp.shape))
+    old_ids = np.asarray(tbl)[0, :used]
+    kp2[new_ids] = np.asarray(kp.astype(jnp.float32))[old_ids]
+    vp2[new_ids] = np.asarray(vp.astype(jnp.float32))[old_ids]
+    tbl2 = np.zeros((3, M), np.int32)
+    tbl2[2, :used] = new_ids
+    tbl2[0, :1] = new_ids[:1]
+    tbl2[1, :M] = rng.integers(1, nb, M)
+    pos2 = np.asarray([[2], [M * bs - 1], [n - 1]], np.int32)
+    qh2 = np.array(_rand(rng, *qh.shape))
+    qh2[2] = np.asarray(qh.astype(jnp.float32))[0]
+    b = paged_attention(
+        jnp.asarray(qh2, qh.dtype), jnp.asarray(kp2, kp.dtype),
+        jnp.asarray(vp2, vp.dtype), None, None, jnp.asarray(tbl2),
+        jnp.asarray(pos2), G, interpret=True)
+    assert np.array_equal(a[0], np.asarray(b.astype(jnp.float32))[2])
+
+
+def _tiny_bf16_llama(kv_cache_dtype=None):
+    """A decoder whose decode call the compiled kernel would take: bf16
+    weights and pools, head_dim 128, a (16, 128)-tiled page."""
+    from paddle_tpu.text.models import LlamaForCausalLM, llama_tiny
+    paddle.seed(5)
+    cfg = llama_tiny(vocab_size=64, hidden_size=256,
+                     intermediate_size=64, num_hidden_layers=2,
+                     num_attention_heads=2, num_key_value_heads=1,
+                     max_position_embeddings=64,
+                     kv_cache_dtype=kv_cache_dtype)
+    m = LlamaForCausalLM(cfg)
+    m.bfloat16()
+    m.eval()
+    return m
+
+
+def _paged_call(m, ids, pos, pools, tbl, wm, **kw):
+    from paddle_tpu.framework.core import Tensor, no_grad
+    with no_grad():
+        lg, pools = m.forward_paged(
+            Tensor(np.asarray(ids, np.int32)),
+            Tensor(np.asarray(pos, np.int32)), pools,
+            np.asarray(tbl, np.int32), np.asarray(wm, bool), **kw)
+    return np.asarray((lg._value if hasattr(lg, "_value") else lg)
+                      .astype(jnp.float32)), pools
+
+
+def _bf16_paged_decode(m, mode, steps=3):
+    """Prefill 19 tokens over two blocks of 16, then ``steps`` decode
+    calls, beside an inactive slot (mask off, table of zeros)."""
+    kreg.set_mode("paged_attention", mode)
+    try:
+        pools = m.init_paged_cache(8, 16)
+        tbl = np.array([[3, 5, 2, 0], [0, 0, 0, 0]], np.int32)
+        rng = np.random.RandomState(1)
+        n = 19
+        ids = np.zeros((2, 32), np.int32)
+        ids[0, :n] = rng.randint(1, 64, (n,))
+        pos = np.broadcast_to(np.arange(32, dtype=np.int32), (2, 32))
+        wm = np.zeros((2, 32), bool)
+        wm[0, :n] = True
+        lg, pools = _paged_call(m, ids, pos, pools, tbl, wm,
+                                gather_at=np.asarray([n - 1, 0], np.int32))
+        outs = [lg[0, 0]]
+        for j in range(steps):
+            tok = int(np.argmax(outs[-1]))
+            lg, pools = _paged_call(m, [[tok], [0]], [[n + j], [0]],
+                                    pools, tbl, [[True], [False]])
+            assert np.isfinite(lg).all()
+            outs.append(lg[0, 0])
+        return outs
+    finally:
+        kreg.set_mode("paged_attention", None)
+
+
+def test_llama_paged_decode_kernel_parity():
+    """End-to-end through ``LlamaAttention.forward_paged``: decode
+    logits with the kernel (interpret) track the reference path, and
+    the counters name the routes (prefill takes neither)."""
+    m = _tiny_bf16_llama()
+    kreg.reset_dispatch_counts()
+    ref = _bf16_paged_decode(m, "xla_ref")
+    assert kreg.dispatch_counts("paged_attention") == {"xla_ref": 6}
+    kreg.reset_dispatch_counts()
+    got = _bf16_paged_decode(m, "interpret")
+    assert kreg.dispatch_counts("paged_attention") == {"interpret": 6}
+    for r, g in zip(ref, got):
+        np.testing.assert_allclose(g, r, atol=2e-2, rtol=2e-2)
+    assert [int(np.argmax(o)) for o in got] == \
+        [int(np.argmax(o)) for o in ref]
+
+
+def _trace_paged(m, S, target, verify_mode=False):
+    """Trace (never compile) one ``forward_paged`` call as if programs
+    were being compiled for ``target``; the counters of the route."""
+    import paddle_tpu.distributed.mesh as mesh_mod
+    from paddle_tpu.framework.core import Tensor, no_grad
+    pools = m.init_paged_cache(8, 16)
+    orig = mesh_mod.target_platform
+    mesh_mod.target_platform = lambda: target
+    kreg.reset_dispatch_counts()
+    try:
+        def f(ids, pos, pools, tbl, wm):
+            with no_grad():
+                lg, pools = m.forward_paged(
+                    Tensor(ids), Tensor(pos), pools, tbl, wm,
+                    verify_mode=verify_mode)
+            return lg._value if hasattr(lg, "_value") else lg
+        jax.eval_shape(f, np.zeros((2, S), np.int32),
+                       np.zeros((2, S), np.int32), pools,
+                       np.zeros((2, 4), np.int32), np.zeros((2, S), bool))
+    finally:
+        mesh_mod.target_platform = orig
+    return kreg.dispatch_counts()
+
+
+@pytest.mark.parametrize("what,expect", [
+    ("decode_cpu", {"paged_attention": {"xla_ref": 2}}),
+    ("decode_tpu", {"paged_attention": {"pallas": 2}}),
+    ("verify_tpu", {"paged_attention": {"xla_ref": 2}}),
+    ("verify_one_token_tpu", {"paged_attention": {"xla_ref": 2}}),
+    ("int8_pools_tpu", {"int8_kv_attention": {"xla_ref": 2}}),
+    ("f32_weights_tpu", {"paged_attention": {"fallback": 2}}),
+])
+def test_paged_decode_route_is_chosen_from_the_call(what, expect):
+    """No knob: a decode step over bf16 pools takes the kernel where
+    programs are compiled for a TPU and the reference on the CPU;
+    verify (S > 1, or ``verify_mode``), int8 pools and shapes the
+    kernel does not tile keep the reference, each counted."""
+    m = _tiny_bf16_llama("int8" if what == "int8_pools_tpu" else None)
+    if what == "f32_weights_tpu":
+        m.float()
+    target = "cpu" if what.endswith("_cpu") else "tpu"
+    S = 3 if what == "verify_tpu" else 1
+    counts = _trace_paged(m, S, target,
+                          verify_mode=what.startswith("verify"))
+    assert {k: v for k, v in counts.items() if v} == expect
+
+
+def test_generation_server_decode_program_routes(monkeypatch):
+    """The server's own ``decode_fn``, traced: ``xla_ref`` as built on
+    the CPU, the kernel when the same server is built for a TPU
+    target; prefill programs never reach the dispatch."""
+    import paddle_tpu.distributed.mesh as mesh_mod
+    from paddle_tpu.inference import GenerationServer
+    m = _tiny_bf16_llama()
+
+    def routes():
+        srv = GenerationServer(m, num_slots=2, block_size=16,
+                               max_model_len=64)
+        srv._build_programs()
+        kreg.reset_dispatch_counts()
+        B, W = 2, int(np.asarray(srv._seq_key_data(0)).shape[-1])
+        jax.eval_shape(
+            srv._decode_fn, srv._pvals, srv._pools,
+            np.zeros((B, 1), np.int32), np.zeros((B, 1), np.int32),
+            np.zeros((B, srv._M), np.int32), np.zeros((B, 1), bool),
+            np.zeros((B, W), np.uint32), np.zeros((B,), np.int32),
+            np.ones((B,), np.float32), np.zeros((B,), np.int32),
+            np.ones((B,), np.float32), np.zeros((B,), bool))
+        return kreg.dispatch_counts("paged_attention")
+
+    assert routes() == {"xla_ref": 2}
+    monkeypatch.setattr(mesh_mod, "target_platform", lambda: "tpu")
+    assert routes() == {"pallas": 2}
+
+
+def test_generation_server_with_kernel_matches_reference_route():
+    """A served greedy session with the kernel (interpret) in the
+    decode program gives the reference route's tokens."""
+    from paddle_tpu.inference import GenerationServer
+    m = _tiny_bf16_llama()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(1, 64, (l,)).astype("int32")
+               for l in (5, 18, 9)]
+
+    def serve(mode):
+        kreg.set_mode("paged_attention", mode)
+        srv = GenerationServer(m, num_slots=2, block_size=16,
+                               max_model_len=64,
+                               request_timeout_s=300.0)
+        srv.start()
+        try:
+            streams = [srv.submit(p, max_new_tokens=5) for p in prompts]
+            return [s.result(timeout=300) for s in streams]
+        finally:
+            srv.stop()
+            kreg.set_mode("paged_attention", None)
+
+    ref = serve("xla_ref")
+    kreg.reset_dispatch_counts()
+    got = serve("interpret")
+    assert kreg.dispatch_counts("paged_attention").get("interpret")
+    assert got == ref
 
 
 # ---------------------------------------------------------------------

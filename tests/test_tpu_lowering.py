@@ -67,7 +67,7 @@ def _compile_on(dev, fn, *args):
     avals alone; returns the compiled HLO text."""
     sh = jax.sharding.SingleDeviceSharding(dev)
     avals = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
                                        sharding=sh), args)
     return jax.jit(fn).lower(*avals).compile().as_text()
 
@@ -104,6 +104,19 @@ def _kernel_cases():
              np.zeros((nb, bs, g, d), i8), np.zeros((nb, bs, g, d), i8),
              np.zeros((nb, bs), f32), np.zeros((nb, bs), f32),
              np.zeros((4, m), i32), np.zeros((4, 1), i32)]),
+        # the decode kernel at both serving cells' shapes (PERF.md §4):
+        # 128 slots x 64 blocks and 32 slots x 256 blocks of 16 over
+        # ONE [8192, 16, 8, 128] bf16 pool pair, 32/8 heads x 128
+        "paged_attention": (
+            lambda kp, vp, *cells: [
+                kreg.dispatch("paged_attention", q, kp, vp, None, None,
+                              tbl, pos, 8)
+                for q, tbl, pos in zip(cells[0::3], cells[1::3],
+                                       cells[2::3])],
+            [np.zeros((8192, 16, 8, 128), jnp.bfloat16)] * 2 + [
+                a for b, mt in ((128, 64), (32, 256)) for a in (
+                    np.zeros((b, 1, 32, 128), jnp.bfloat16),
+                    np.zeros((b, mt), i32), np.zeros((b, 1), i32))]),
         "segment_sum": (
             lambda gr, inv: kreg.dispatch("segment_sum", gr, inv,
                                           num_segments=256),
