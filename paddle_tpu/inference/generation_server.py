@@ -135,6 +135,7 @@ from ..observability import flight_recorder as _flight
 from ..observability import trace as _trace
 from ..observability.request_trace import RequestTrace
 from ..observability.timeline import StepTimeline
+from . import recurrent_state as _rs
 from .prefix_cache import PrefixCache
 from .serving import (RequestTimeout, ServeError, ServerClosed,
                       ServerDraining, ServerOverloaded)
@@ -406,6 +407,14 @@ class GenerationServer:
         self._seed = int(seed)
         self._check_replay = bool(check_replay)
         self._prefix_on = bool(prefix_cache)
+        # per-slot recurrent state beside the paged pools
+        # (inference/recurrent_state.py): prefix sharing and speculation
+        # raise RecurrentStateUnsupported for such a model
+        self._stateful = _rs.check_features(self._model, prefix_cache,
+                                            draft_model)
+        # what the model's decode step counts, fetched behind the tokens
+        self._step_counters = tuple(
+            getattr(self._model, "step_counters", tuple)())
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -440,12 +449,12 @@ class GenerationServer:
             "decode_steps": 0, "replay_steps": 0,
             "decode_ms": 0.0, "prefill_ms": 0.0,
             "prefill_batches": 0, "prefill_tokens": 0,
-            "prefill_tokens_skipped": 0,
+            "prefill_tokens_skipped": 0, "state_resets": 0,
             "spec_verify_steps": 0, "draft_steps": 0,
             "spec_proposed": 0, "spec_accepted": 0,
             "admit_rollbacks": 0, "spec_index_withheld_tokens": 0,
             "shed_draining": 0, "migrated_in": 0, "migrated_out": 0,
-            "cancelled": 0,
+            "cancelled": 0, "moe_picks_here": 0, "moe_max_expert_load": 0,
             "prefill_bucket_hits": {b: 0 for b in self._buckets},
         }
 
@@ -473,8 +482,13 @@ class GenerationServer:
         prefix_on = self._prefix_on
 
         def make_call(model):
+            counted = bool(getattr(model, "step_counters", tuple)())
+
             def call_model(pvals, ids, pos, pools, tables, wm,
-                           gather_at=None, verify_mode=False):
+                           gather_at=None, verify_mode=False, **rows):
+                """(logits, pools, the model's step counters or None);
+                ``rows`` is ``slots=`` for a model with per-slot
+                state, nothing otherwise."""
                 st = model.state_dict()
                 old = {k: t._value for k, t in st.items()}
                 try:
@@ -482,9 +496,11 @@ class GenerationServer:
                         if k in pvals:
                             t._value = pvals[k]
                     with no_grad():
-                        logits, pools = model.forward_paged(
+                        out = model.forward_paged(
                             Tensor(ids), Tensor(pos), pools, tables, wm,
-                            gather_at=gather_at, verify_mode=verify_mode)
+                            gather_at=gather_at, verify_mode=verify_mode,
+                            **rows)
+                    logits, pools = out[0], out[1]
                 finally:
                     for k, t in st.items():
                         t._value = old[k]
@@ -495,14 +511,15 @@ class GenerationServer:
                     return v._value if isinstance(v, Tensor) else v
                 pools = [{kk: raw(vv) for kk, vv in d.items()}
                          for d in pools]
-                return lv, pools
+                return lv, pools, (raw(out[2]) if counted else None)
             return call_model
 
         call_model = make_call(self._model)
         self._pvals = {k: t._value
                        for k, t in self._model.state_dict().items()}
-        self._pools = self._model.init_paged_cache(self._num_blocks,
-                                                   self._bs)
+        self._pools = self._model.init_paged_cache(
+            self._num_blocks, self._bs,
+            **({"num_slots": self._num_slots} if self._stateful else {}))
         if self._spec:
             call_draft = make_call(self._draft)
             self._dvals = {k: t._value
@@ -550,16 +567,19 @@ class GenerationServer:
             # proves steady-state decode never retraces
             server._compiles += 1
             server._note_compile("decode", 1, tokens.shape[0])
-            logits, pools = call_model(pvals, tokens, positions, pools,
-                                       tables, wm)
+            logits, pools, counts = call_model(pvals, tokens, positions,
+                                               pools, tables, wm)
             lg = logits[:, -1, :].astype(jnp.float32)
             nxt = sample(lg, kd, rng_steps, temp, top_k, top_p,
                          do_sample)
+            if counts is not None:
+                # behind the tokens: one fetch, no second transfer
+                nxt = jnp.concatenate([nxt, counts.astype(nxt.dtype)])
             return nxt, pools
 
         def make_prefill(call, name):
             def prefill_fn(pvals, pools, prompt, start, length, table,
-                           kd, temp, top_k, top_p, do_sample):
+                           kd, temp, top_k, top_p, do_sample, **rows):
                 server._compiles += 1
                 server._note_compile(name, prompt.shape[1],
                                      prompt.shape[0])
@@ -574,9 +594,9 @@ class GenerationServer:
                 # prefill and a warm suffix prefill are the same
                 # floating-point program per position — the bit-
                 # identity the shared-prefix contract rests on
-                logits, pools = call(pvals, prompt, pos, pools, table,
-                                     wm, gather_at=gather_at,
-                                     verify_mode=prefix_on)
+                logits, pools, _ = call(pvals, prompt, pos, pools, table,
+                                        wm, gather_at=gather_at,
+                                        verify_mode=prefix_on, **rows)
                 lg = logits[:, -1, :].astype(jnp.float32)
                 first = sample(lg, kd, jnp.zeros_like(length), temp,
                                top_k, top_p, do_sample)
@@ -592,8 +612,8 @@ class GenerationServer:
             server._note_compile("verify", tokens.shape[1],
                                  tokens.shape[0])
             B, S = tokens.shape
-            logits, pools = call_model(pvals, tokens, positions, pools,
-                                       tables, wm, verify_mode=True)
+            logits, pools, _ = call_model(pvals, tokens, positions, pools,
+                                          tables, wm, verify_mode=True)
             lg = logits.astype(jnp.float32).reshape(B * S, -1)
             rep = lambda a: jnp.repeat(a, S, axis=0)
             sampled = sample(lg, rep(kd), rng_steps.reshape(B * S),
@@ -635,8 +655,8 @@ class GenerationServer:
                                 top_p, do_sample):
                 server._compiles += 1
                 server._note_compile("draft_decode", 1, tokens.shape[0])
-                logits, dpools = call_draft(dvals, tokens, positions,
-                                            dpools, tables, wm)
+                logits, dpools, _ = call_draft(dvals, tokens, positions,
+                                               dpools, tables, wm)
                 lg = logits[:, -1, :].astype(jnp.float32)
                 nxt = sample(lg, kd, rng_steps, temp, top_k, top_p,
                              do_sample)
@@ -693,7 +713,8 @@ class GenerationServer:
                         np.ones((pb,), np.float32),
                         np.zeros((pb,), bool))
                 _, self._pools = self._prefill_fn(
-                    self._pvals, self._pools, *args)
+                    self._pvals, self._pools, *args,
+                    **self._row_slots([], pb))
                 if self._spec:
                     _, self._dpools = self._draft_prefill_fn(
                         self._dvals, self._dpools, *args)
@@ -1001,6 +1022,9 @@ class GenerationServer:
         s["spec_accept_rate"] = (s["spec_accepted"]
                                  / max(s["spec_proposed"], 1))
         s["prefix_cache_enabled"] = self._prefix_on
+        # per-slot recurrent state beside the paged pools (zeros for a
+        # K/V model, and before start() has built the pools)
+        s.update(_rs.pool_bytes(self._pools or []))
         s["server"] = "generation"   # provenance, see PredictorServer
         # shared bucket-compile accounting shape with
         # PredictorServer.stats() (ISSUE 8 satellite; ISSUE 11 adds
@@ -1017,6 +1041,17 @@ class GenerationServer:
         s["traffic_compiles"] = sum(1 for r in records
                                     if r["cause"] != "prewarm")
         return s
+
+    def _row_slots(self, seqs, B: int) -> dict:
+        """``slots=`` of a batched prefill for a model with per-slot
+        state: rows are not slots there, so each row names its own (a
+        row that holds no sequence names ``num_slots``, which no write
+        reaches).  Nothing for a K/V model."""
+        if not self._stateful:
+            return {}
+        return {"slots": np.asarray(
+            [s.slot for s in seqs] + [self._num_slots] * (B - len(seqs)),
+            np.int32)}
 
     # -- scheduler ---------------------------------------------------
     def _loop(self):
@@ -1282,10 +1317,15 @@ class GenerationServer:
                     seq.rt.begin("prefill")
             tokens = int(length.sum())
             ph.set(bucket=bucket, batch=B, tokens=tokens)
+        if self._stateful and start.any():
+            raise _rs.RecurrentStateUnsupported(
+                "a prefill that starts mid-sequence: the latent layers "
+                "attend over the fresh block only")
         with tl.phase("prefill.dispatch") as disp:
             first, self._pools = self._prefill_fn(
                 self._pvals, self._pools, prompt, start, length, tables,
-                kd, temp, top_k, top_p, do_sample)
+                kd, temp, top_k, top_p, do_sample,
+                **self._row_slots(seqs, B))
             if self._spec:
                 _, self._dpools = self._draft_prefill_fn(
                     self._dvals, self._dpools, prompt, start, length,
@@ -1305,6 +1345,8 @@ class GenerationServer:
                 self._stats["prefill_tokens"] += tokens
                 self._stats["prefill_tokens_skipped"] += int(
                     sum(s.cached for s in seqs))
+                if self._stateful:   # each started from zero state
+                    self._stats["state_resets"] += len(seqs)
             if _monitor.metrics_enabled():
                 _monitor.hist_observe("prefill_ms", dt_ms)
             for seq in seqs:
@@ -1548,6 +1590,11 @@ class GenerationServer:
                             "decode is not bit-stable")
                 else:
                     self._emit(seq, int(nxt[s]))
+            if self._step_counters:
+                with self._lock:
+                    for i, name in enumerate(self._step_counters):
+                        self._stats[name] = self._stats.get(name, 0) \
+                            + int(nxt[B + i])
             self._after_step(len(live), replays, dt_ms)
 
     def _after_step(self, n_live: int, replays: int, dt_ms: float):

@@ -61,6 +61,9 @@ def export_sequence(server, request_id: int) -> Optional[dict]:
     re-anchored at import — the wall time a migration takes counts
     against the request's budget, it does not reset it.
     """
+    from .recurrent_state import refuse_migration
+    refuse_migration(server)   # per-slot recurrent state has no export
+
     def _do():
         with server._lock:
             seq = next((s for s in server._active.values()
@@ -126,6 +129,8 @@ def import_sequence(server, blob: dict):
     server is left exactly as found.
     """
     from .generation_server import _GenSeq
+    from .recurrent_state import refuse_migration
+    refuse_migration(server)
 
     kv = blob.get("kv")
     if kv is None:

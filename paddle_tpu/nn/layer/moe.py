@@ -17,6 +17,14 @@ style (Lepikhin et al. 2020), the canonical TPU formulation:
   lowering — no hand-written collectives;
 - load-balancing auxiliary loss (switch/GShard aux) exposed as
   ``layer.l_aux`` and differentiable.
+
+What :class:`MoELayer` is still for: TRAINING with a capacity (top-1 /
+top-2, dropped overflow, an auxiliary loss, experts sharded over 'ep'
+by XLA).  Models that route as today's sparse decoders do -- top-k of
+many experts by a sigmoid score, gated experts, a shared expert, no
+token dropped, and a chip that holds only a share of the experts --
+take :class:`DroplessMoELayer` below (serving; ``KimiLinearForCausalLM``
+is its user).
 """
 from __future__ import annotations
 
@@ -31,7 +39,7 @@ from ...framework.core import Tensor, _apply
 from ..initializer import Normal, XavierNormal
 from .layers import Layer, Parameter
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "DroplessMoELayer", "dropless_moe"]
 
 
 def _mark_ep(param, spec):
@@ -177,3 +185,225 @@ class MoELayer(Layer):
     def extra_repr(self):
         return (f"d_model={self.d_model}, d_hidden={self.d_hidden}, "
                 f"num_experts={self.num_experts}, top_k={self.top_k}")
+
+
+# ---------------------------------------------------------------------
+# dropless experts, sorted/grouped dispatch (serving)
+# ---------------------------------------------------------------------
+
+# rows of the expert-sorted picks that ONE step multiplies by ONE
+# expert's weights: each expert's group is padded to whole blocks, so a
+# block never straddles two experts and a step is three plain matmuls
+_GROUP_BLOCK = 256
+# under this many tokens every held expert multiplies every token and
+# the result is masked by the combine weights: at a decode step's 128
+# rows that costs what reading the weights costs, and XLA's grouped
+# product took twice as long (v5e, PERF.md PR 27: 1.96 against 3.91 ms
+# a layer of 64 experts 2304 x 1024).  Under the smallest prefill
+# shape a server is given (256 x 1): a prefill never takes it.
+_DENSE_BELOW = 256
+
+
+def _swiglu(x, wg, wu, wd):
+    h = jax.nn.silu(jnp.dot(x, wg, preferred_element_type=jnp.float32)) \
+        * jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    return jnp.dot(h.astype(x.dtype), wd,
+                   preferred_element_type=jnp.float32)
+
+
+def _route(x, router_w, router_b, top_k, scale, held):
+    """Sigmoid router in float32 over ALL experts: (local [T, k] the
+    picks' index among the held experts, w [T, k] their combine
+    weights, here [T, k] whether the pick is held here)."""
+    first, count = held
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router_w.astype(jnp.float32)))
+    _, idx = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    local = (idx - first).astype(jnp.int32)
+    return local, w, (local >= 0) & (local < count)
+
+
+def _experts_masked(x, local, w, wg, wu, wd):
+    """Every held expert over every token, the result weighed by the
+    combine weights (nought where the token did not choose the
+    expert): for few tokens."""
+    count = wg.shape[0]
+    onehot = jax.nn.one_hot(local, count, dtype=jnp.float32)   # [T,k,E]
+    wt = (onehot * w[..., None]).sum(1)                        # [T, E]
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, wg,
+                               preferred_element_type=jnp.float32)) \
+        * jnp.einsum("td,edf->etf", x, wu,
+                     preferred_element_type=jnp.float32)
+    out = jnp.einsum("etf,efd->etd", h.astype(x.dtype), wd,
+                     preferred_element_type=jnp.float32)
+    load = onehot.sum((0, 1)).astype(jnp.int32)
+    return jnp.einsum("etd,te->td", out, wt), load.sum(), load.max()
+
+
+def _experts_grouped(x, local, w, here, wg, wu, wd):
+    """Sorted/grouped dispatch in plain XLA: the picks sorted by expert
+    (picks on experts that live elsewhere sort last and are never
+    touched), each held expert's group laid out in whole blocks of
+    ``_GROUP_BLOCK`` rows, and one step per block that multiplies the
+    block by its expert's three matrices (a dynamic slice of the
+    stacked weights, never a gather per pick).  The number of steps is
+    static, the worst case ``P / block + count``; a step past the
+    blocks in use is skipped by a ``cond``, so the work follows the
+    picks that landed here.  No Mosaic kernel and no loop whose trip
+    count is computed on the device: with ``lax.ragged_dot`` (on TPU a
+    megablox-style kernel of XLA's own) or the megablox kernel here,
+    prefill traffic stopped a v5e; with this form the same traffic ran
+    clean (PERF.md, PR 27).
+    """
+    (T, d), top_k, count = x.shape, local.shape[1], wg.shape[0]
+    P_ = T * top_k
+    blk = min(_GROUP_BLOCK, P_)
+    i32 = jnp.int32
+    eid = jnp.where(here, local, count).reshape(P_)
+    order = jnp.argsort(eid, stable=True).astype(i32)
+    bounds = jnp.searchsorted(eid[order], jnp.arange(count + 1, dtype=i32),
+                              side="left").astype(i32)
+    sizes = bounds[1:] - bounds[:-1]                     # [count]
+    n_here = bounds[count]
+    # expert e owns blocks [b_end[e] - n_blk[e], b_end[e])
+    n_blk = (sizes + blk - 1) // blk
+    b_end = jnp.cumsum(n_blk).astype(i32)
+    b_start = b_end - n_blk
+    nb = P_ // blk + count                               # static bound
+    # each block's expert, each padded row's pick (clamped: a row past
+    # its group's end multiplies some real token and is never read)
+    b_exp = jnp.minimum(jnp.searchsorted(
+        b_end, jnp.arange(nb, dtype=i32), side="right"), count - 1
+        ).astype(i32)
+    row = jnp.arange(blk, dtype=i32)[None, :] \
+        + ((jnp.arange(nb, dtype=i32) - b_start[b_exp]) * blk)[:, None]
+    src = jnp.clip(bounds[b_exp][:, None] + row, 0, P_ - 1)
+    tok = order[src] // top_k                            # [nb, blk]
+
+    def one_block(t):
+        tb, e, used = t
+
+        def run():
+            ds = lambda m: jax.lax.dynamic_index_in_dim(m, e, 0, False)
+            return _swiglu(x[tb], ds(wg), ds(wu), ds(wd)).astype(x.dtype)
+        return jax.lax.cond(used, run,
+                            lambda: jnp.zeros((blk, d), x.dtype))
+    out = jax.lax.map(one_block,
+                      (tok, b_exp, jnp.arange(nb, dtype=i32) < b_end[-1]))
+    # where each pick sits in the sorted order, then in the blocks
+    inv = jnp.zeros((P_,), i32).at[order].set(jnp.arange(P_, dtype=i32))
+    e_of = jnp.minimum(eid, count - 1)
+    at = b_start[e_of] * blk + inv - bounds[e_of]
+    # a pick that is not here selects 0, not 0 * row
+    picked = out.reshape(nb * blk, d)[jnp.clip(at, 0, nb * blk - 1)]
+    picked = picked.reshape(T, top_k, d).astype(jnp.float32)
+    y = jnp.where(here[..., None], picked * w[..., None], 0.0).sum(1)
+    return y, n_here, sizes.max()
+
+
+def dropless_moe(x, router_w, router_b, wg, wu, wd, *, top_k: int,
+                 scale: float, held):
+    """The held experts' part of a sigmoid-routed expert layer.
+
+    ``x`` [T, d]; ``router_w`` [d, E] and ``router_b`` [E] over ALL E
+    experts of the layer; ``wg``/``wu`` [count, d, f] and ``wd``
+    [count, f, d] of the experts ``held = (first, count)``.  Router in
+    float32: scores ``sigmoid(x W_r)``, the ``top_k`` chosen by score +
+    bias, weights the chosen scores over their sum, times ``scale``.
+
+    No token is dropped, no weight is gathered per pick, and what the
+    absent experts would have added is left out.  ``_DENSE_BELOW``
+    tokens or more (prefill) take the sorted/grouped dispatch, in
+    which no expert multiplies a token that did not choose it (but for
+    the padding of each group to whole blocks); fewer
+    (a decode step) take the masked dense pass, the same sum, which
+    costs what reading the weights costs.
+
+    Returns ``(y [T, d] float32, picks_here, max_expert_load)``.
+    """
+    local, w, here = _route(x, router_w, router_b, top_k, scale, held)
+    if x.shape[0] < _DENSE_BELOW:
+        return _experts_masked(x, local, w, wg, wu, wd)
+    return _experts_grouped(x, local, w, here, wg, wu, wd)
+
+
+class DroplessMoELayer(Layer):
+    """Sparse experts as today's decoders route them (DeepSeek-V3
+    style): ``top_k`` of ``num_experts`` gated (SwiGLU) experts by a
+    sigmoid router with a selection bias, optionally one shared expert
+    every token takes, no capacity and no dropped token.
+
+    ``held_experts=(first, count)`` makes this chip's share of an
+    expert-parallel layer: the router keeps its full width, only the
+    ``count`` experts from ``first`` have weights here, and the result
+    is THEIR part of the layer's output plus the shared expert's (which
+    every chip computes alike).  The exchange between chips is not this
+    layer's; on one chip it runs without it.
+
+    Serving only: written for inference (``lax.cond`` per block of the
+    sorted picks; no auxiliary loss, no gradient tests).
+    :class:`MoELayer` is the trainable layer.  After a call
+    ``last_counts`` holds (picks that landed here, the largest held
+    expert's load).
+    """
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 top_k: int = 8, held_experts=None,
+                 shared_hidden: Optional[int] = None,
+                 routed_scaling_factor: float = 1.0,
+                 initializer_range: float = 0.02):
+        super().__init__()
+        first, count = held_experts or (0, num_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= num_experts):
+            raise ValueError(
+                f"held_experts={held_experts} outside 0..{num_experts}")
+        if not 1 <= top_k <= num_experts:
+            raise ValueError("top_k must lie in 1..num_experts")
+        self.d_model, self.d_hidden = d_model, d_hidden
+        self.num_experts, self.top_k = num_experts, top_k
+        self.held_experts = (int(first), int(count))
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        init = Normal(0.0, initializer_range)
+
+        def mk(*shape):
+            return self.create_parameter(shape, default_initializer=init)
+        self.router = mk(d_model, num_experts)
+        self.router_bias = mk(num_experts)
+        self.gate_w = mk(count, d_model, d_hidden)
+        self.up_w = mk(count, d_model, d_hidden)
+        self.down_w = mk(count, d_hidden, d_model)
+        self.shared = bool(shared_hidden)
+        if self.shared:
+            self.shared_gate = mk(d_model, shared_hidden)
+            self.shared_up = mk(d_model, shared_hidden)
+            self.shared_down = mk(shared_hidden, d_model)
+        self.last_counts = None
+
+    def apply_values(self, xv):
+        """``xv`` [..., d] (a raw array) -> (y like xv, picks_here,
+        max_expert_load): what :meth:`forward` computes, for callers
+        that are themselves inside a traced program."""
+        tok = xv.reshape(-1, self.d_model)
+        y, n_here, load = dropless_moe(
+            tok, self.router._value, self.router_bias._value,
+            self.gate_w._value, self.up_w._value, self.down_w._value,
+            top_k=self.top_k, scale=self.routed_scaling_factor,
+            held=self.held_experts)
+        if self.shared:
+            y = y + _swiglu(tok, self.shared_gate._value,
+                            self.shared_up._value,
+                            self.shared_down._value)
+        return y.astype(xv.dtype).reshape(xv.shape), n_here, load
+
+    def forward(self, x):
+        y, n_here, load = self.apply_values(x._value)
+        self.last_counts = (n_here, load)
+        return Tensor(y, stop_gradient=True)
+
+    def extra_repr(self):
+        return (f"d_model={self.d_model}, d_hidden={self.d_hidden}, "
+                f"num_experts={self.num_experts}, top_k={self.top_k}, "
+                f"held_experts={self.held_experts}")

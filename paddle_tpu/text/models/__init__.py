@@ -2,6 +2,9 @@ from .bert import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, BertPretrainingCriterion,
     bert_base, bert_large, bert_tiny, ernie_base,
 )
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig, KimiLinearForCausalLM, kimi_linear_tiny,
+)
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, RMSNorm,
     llama_tiny, llama_7b, llama_13b,
@@ -15,6 +18,7 @@ __all__ = [
     "BertConfig", "BertForPretraining", "BertModel",
     "BertPretrainingCriterion", "bert_base", "bert_large", "bert_tiny",
     "ernie_base",
+    "KimiLinearConfig", "KimiLinearForCausalLM", "kimi_linear_tiny",
     "LlamaConfig", "LlamaForCausalLM", "LlamaModel", "RMSNorm",
     "llama_tiny", "llama_7b", "llama_13b",
     "CrossEntropyCriterion", "TransformerConfig", "TransformerModel",

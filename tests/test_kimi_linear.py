@@ -1,0 +1,444 @@
+"""Kimi-Linear on the serving path, against the benchmark's ONE plain
+reference (``perfbench/configs/kimi-linear-48b-a3b.reference.py``,
+loaded by path) at a toy size: KDA's chunked scan against the token by
+token recurrence, absorbed against expanded latent attention, prefill
+then decode through the paged caches and through ``GenerationServer``,
+slot reuse, eviction and replay, the expert shares that add up to the
+uncut layer, the vocabulary slice, and the typed errors."""
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.inference import GenerationServer
+from paddle_tpu.inference.recurrent_state import RecurrentStateUnsupported
+from paddle_tpu.nn.layer import moe as MOE
+from paddle_tpu.nn.layer.moe import DroplessMoELayer, dropless_moe
+from paddle_tpu.text.models import KimiLinearForCausalLM
+from paddle_tpu.text.models import kimi_linear as KL
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOY = os.path.join(ROOT, "tests", "perfbench_tests", "toy", "configs",
+                   "kimi-linear-toy.json")
+SEED = 12345
+
+
+@pytest.fixture(scope="module")
+def bench():
+    import sys
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from perfbench.harness import manifest as M
+    d = os.path.join(ROOT, "perfbench", "configs")
+    return {"ref": M.load_module(
+                os.path.join(d, "kimi-linear-48b-a3b.reference.py"),
+                "kimi_reference_for_tests"),
+            "bind": M.load_module(
+                os.path.join(d, "kimi-linear-48b-a3b.program.py"),
+                "kimi_binding_for_tests")}
+
+
+@pytest.fixture(autouse=True)
+def small_chunks(monkeypatch):
+    """The toy's blocks are 16 to 48 tokens: a scan chunk of 16 puts
+    chunk boundaries inside them."""
+    monkeypatch.setattr(KL, "KDA_CHUNK", 16)
+
+
+def toy_cfg(**assumed):
+    with open(TOY) as f:
+        cfg = json.load(f)
+    cfg["assumed"] = dict(cfg["assumed"], **assumed)
+    return cfg
+
+
+def build(bench, cfg, dt_bias=0.0, seed=SEED):
+    """(model in float32 with the seed's weights, the reference's flat
+    tree of the same weights)."""
+    from perfbench.harness import weights as W
+    from perfbench.harness.program import install_weights
+    ref, bind = bench["ref"], bench["bind"]
+    mc = bind.model_config(cfg, 128)
+    mc.compute_dtype = "float32"
+    model = KimiLinearForCausalLM(mc)
+    model.eval()
+    specs = ref.param_specs(cfg)
+    install_weights(model, bind.name_map(cfg, model), specs, seed,
+                    jnp.float32)
+    params = W.make_tree(specs, W.seed_key(seed), jnp.float32)
+    if dt_bias:
+        # the public model starts near -4 (decay about 0.95 a token):
+        # a fault in state handling then lasts long enough to be seen
+        for n, p in model.named_parameters():
+            if n.endswith("dt_bias"):
+                p._value = p._value + dt_bias
+        params = {k: v + dt_bias if k.endswith(".dtb") else v
+                  for k, v in params.items()}
+    return model, params
+
+
+# ---------------------------------------------------------------------
+# KDA: chunked scan = recurrence, under padding and strong decay
+# ---------------------------------------------------------------------
+def _kda_inputs(B, L, H, d, decay, seed=0):
+    r = np.random.RandomState(seed)
+    n = lambda *s: jnp.asarray(r.randn(*s), jnp.float32)
+    q = KL._l2(n(B, L, H, d)) * d ** -0.5
+    k = KL._l2(n(B, L, H, d))
+    v = n(B, L, H, d)
+    g = -decay * jax.nn.softplus(n(B, L, H, d))
+    beta = jax.nn.sigmoid(n(B, L, H))
+    S0 = n(B, H, d, d) * 0.3
+    return S0, q, k, v, g, beta
+
+
+def _recurrent(S, q, k, v, g, beta):
+    def step(S, t):
+        return KL.kda_step(S, *t)
+    mv = lambda x: jnp.moveaxis(x, 1, 0)
+    S, o = jax.lax.scan(step, S, tuple(mv(x) for x in (q, k, v, g, beta)))
+    return S, jnp.moveaxis(o, 0, 1)
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+@pytest.mark.parametrize("decay", [0.05, 1.0, 12.0],
+                         ids=["slow", "fast", "overflowing"])
+def test_chunked_kda_equals_recurrent(chunk, decay):
+    # 75 tokens: crosses chunk and sub-block boundaries and ends in a
+    # padded chunk; "overflowing" decays e^-500 over a chunk, which a
+    # factorised exp(+G) could not hold in float32
+    S0, q, k, v, g, beta = _kda_inputs(2, 75, 2, 16, decay)
+    S1, o1 = KL.kda_chunked(S0, q, k, v, g, beta, chunk)
+    S2, o2 = _recurrent(S0, q, k, v, g, beta)
+    assert np.isfinite(np.asarray(o1)).all()
+    np.testing.assert_allclose(o1, o2, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(S1, S2, atol=2e-5, rtol=1e-4)
+
+
+def test_padded_positions_leave_the_state_alone():
+    S0, q, k, v, g, beta = _kda_inputs(2, 40, 2, 16, 1.0, seed=3)
+    live = (jnp.arange(40)[None, :] < jnp.asarray([23, 0])[:, None])
+    lf = live.astype(jnp.float32)
+    gm, bm = g * lf[..., None, None], beta * lf[..., None]
+    S1, _ = KL.kda_chunked(S0, q, k, v, gm, bm, 16)
+    S2, _ = KL.kda_chunked(S0[:1], q[:1, :23], k[:1, :23], v[:1, :23],
+                           g[:1, :23], beta[:1, :23], 16)
+    np.testing.assert_allclose(S1[0], S2[0], atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(S1[1], S0[1])     # an empty row
+    # and one decode step of a masked row: bit for bit
+    z = jnp.zeros_like(g[:, 0])
+    S3, _ = KL.kda_step(S0, q[:, 0], k[:, 0], v[:, 0], z, z[..., 0])
+    np.testing.assert_array_equal(S3, S0)
+
+
+def test_short_conv_carries_its_tail_through_padding():
+    r = np.random.RandomState(1)
+    x = jnp.asarray(r.randn(2, 3, 10, 8), jnp.float32)
+    w = jnp.asarray(r.randn(3, 4, 8), jnp.float32)
+    tail0 = jnp.zeros((2, 3, 3, 8), jnp.float32)
+    y, _ = KL.short_conv(x, tail0, w)
+    # in two pieces, the first padded beyond its 6 real positions
+    ya, ta = KL.short_conv(x[:, :, :8], tail0, w,
+                           length=jnp.asarray([6, 6]))
+    yb, tb = KL.short_conv(x[:, :, 6:], ta, w)
+    np.testing.assert_allclose(ya[:, :, :6], y[:, :, :6], atol=1e-6)
+    np.testing.assert_allclose(yb, y[:, :, 6:], atol=1e-6)
+    np.testing.assert_array_equal(tb, x[:, :, 7:])
+
+
+# ---------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------
+def _dense_moe(x, m):
+    """Every held expert over every token, masked: the oracle."""
+    first, count = m.held_experts
+    s = jax.nn.sigmoid(x @ m.router._value)
+    _, idx = jax.lax.top_k(s + m.router_bias._value, m.top_k)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * m.routed_scaling_factor
+    W = jnp.zeros_like(s).at[jnp.arange(x.shape[0])[:, None], idx].set(w)
+    y = 0
+    for e in range(count):
+        h = jax.nn.silu(x @ m.gate_w._value[e]) * (x @ m.up_w._value[e])
+        y = y + W[:, first + e, None] * (h @ m.down_w._value[e])
+    return y
+
+
+@pytest.mark.parametrize("block,dense_below", [(4, 0), (16, 0),
+                                               (256, 0), (256, 256)])
+def test_dropless_dispatch_equals_masked_dense(monkeypatch, block,
+                                               dense_below):
+    """Grouped dispatch in blocks of the sorted picks (prefill; a
+    group of 11 picks over three blocks of 4, inside one of 16, and
+    the whole batch smaller than a block) and the masked dense pass (a
+    decode step's few tokens): one sum."""
+    import paddle_tpu as paddle
+    monkeypatch.setattr(MOE, "_GROUP_BLOCK", block)
+    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)
+    paddle.seed(0)
+    m = DroplessMoELayer(32, 16, 16, top_k=4, held_experts=(4, 8),
+                         routed_scaling_factor=2.0)
+    x = jnp.asarray(np.random.RandomState(0).randn(21, 32), jnp.float32)
+    y, n_here, load = dropless_moe(
+        x, m.router._value, m.router_bias._value, m.gate_w._value,
+        m.up_w._value, m.down_w._value, top_k=4, scale=2.0,
+        held=(4, 8))
+    np.testing.assert_allclose(y, _dense_moe(x, m), atol=1e-6)
+    assert (int(n_here), int(load)) == (33, 11)
+
+
+def test_four_shares_add_up_to_the_uncut_layer(bench, monkeypatch):
+    """The parts all shares give, the shared expert counted once, are
+    the uncut reference's layer output -- for the reference's shares
+    and for the program's."""
+    ref = bench["ref"]
+    from perfbench.harness import weights as W
+    whole = toy_cfg(held_experts=[0, 8])
+    whole["num_experts"] = 8
+    specs = ref.param_specs(whole)
+    params = W.make_tree(specs, W.seed_key(SEED), jnp.float32)
+    lp = ref.layer_params(params, 1)
+    x = jnp.asarray(np.random.RandomState(2).randn(19, 64), jnp.float32)
+    want = ref.moe(whole, lp, x)
+    shared = ref.swiglu(x, lp["sg"], lp["su"], lp["sd"])
+    got_ref, got_prog = shared, shared
+    for first in (0, 2, 4, 6):
+        # two shares by the grouped dispatch, two by the dense pass
+        monkeypatch.setattr(MOE, "_DENSE_BELOW", 0 if first < 4 else 256)
+        part = dict(whole, num_experts=2,
+                    assumed=dict(whole["assumed"],
+                                 held_experts=[first, 2]))
+        lp_i = dict(lp, **{k: lp[k][first:first + 2]
+                           for k in ("eg", "eu", "ed")})
+        got_ref = got_ref + ref.moe(part, lp_i, x, shared=False)
+        y, _, _ = dropless_moe(
+            x, lp["router"], lp["rbias"], lp_i["eg"], lp_i["eu"],
+            lp_i["ed"], top_k=2, scale=whole["routed_scaling_factor"],
+            held=(first, 2))
+        got_prog = got_prog + y
+    np.testing.assert_allclose(got_ref, want, atol=1e-6)
+    np.testing.assert_allclose(got_prog, want, atol=1e-6)
+
+
+# ---------------------------------------------------------------------
+# the model through its paged caches, against the reference
+# ---------------------------------------------------------------------
+def _prefill_then_decode(model, ids, L, slot=2, N=4, bs=4, Mx=32, Lb=48):
+    """Logits at positions L-1 .. len(ids)-1: one batched prefill (the
+    sequence beside an empty row) and then one decode step a token,
+    the sequence in ``slot`` among idle slots."""
+    pools = model.init_paged_cache(N * Mx + 1, bs, N)
+    prompt = np.zeros((2, Lb), np.int32)
+    prompt[0, :L] = ids[:L]
+    pos = np.broadcast_to(np.arange(Lb, dtype=np.int32), (2, Lb))
+    wm = np.arange(Lb)[None] < np.asarray([L, 0])[:, None]
+    tbl = np.zeros((2, Mx), np.int32)
+    tbl[0] = np.arange(1, Mx + 1)
+    lg, pools, counts = model.forward_paged(
+        jnp.asarray(prompt), jnp.asarray(pos), pools,
+        jnp.asarray(tbl), jnp.asarray(wm),
+        gather_at=jnp.asarray([L - 1, 0]),
+        slots=jnp.asarray([slot, N], jnp.int32))
+    assert counts.shape == (len(model.step_counters()),)
+    got = [lg._value[0, 0]]
+    tbl = np.zeros((N, Mx), np.int32)
+    tbl[slot] = np.arange(1, Mx + 1)
+    for t in range(L, len(ids)):
+        tok, p = np.zeros((N, 1), np.int32), np.zeros((N, 1), np.int32)
+        w = np.zeros((N, 1), bool)
+        tok[slot, 0], p[slot, 0], w[slot, 0] = ids[t], t, True
+        lg, pools, _ = model.forward_paged(
+            jnp.asarray(tok), jnp.asarray(p), pools, jnp.asarray(tbl),
+            jnp.asarray(w))
+        got.append(lg._value[slot, 0])
+    return jnp.stack(got)
+
+
+@pytest.mark.parametrize("dt_bias,chunk,dense_below",
+                         [(0.0, 16, 256), (-4.0, 32, 0)])
+def test_prefill_then_decode_agree_with_the_reference(
+        bench, monkeypatch, dt_bias, chunk, dense_below):
+    cfg = toy_cfg()
+    monkeypatch.setattr(KL, "KDA_CHUNK", chunk)
+    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)   # 0: grouped
+    model, params = build(bench, cfg, dt_bias)
+    ids = np.random.RandomState(0).randint(1, 256, size=43).astype(np.int32)
+    want = bench["ref"].forward_logits(cfg, params, jnp.asarray(ids))
+    got = _prefill_then_decode(model, ids, L=37)
+    np.testing.assert_allclose(got, want[36:], atol=5e-6)
+
+
+def test_absorbed_latent_attention_equals_expanded(bench):
+    """The same tokens through the MLA layer as one fresh block
+    (expanded K and V) and one by one (absorbed, over the pages)."""
+    model, _ = build(bench, toy_cfg())
+    attn = model.model.layers[3].self_attn
+    assert model.model.layers[3].is_mla
+    r = np.random.RandomState(5)
+    x = jnp.asarray(r.randn(1, 11, 64), jnp.float32)
+    tbl = jnp.arange(1, 9, dtype=jnp.int32)[None]
+    pos = jnp.arange(11, dtype=jnp.int32)[None]
+    cache = attn.init_cache(9, 4, jnp.float32)
+    want, _ = attn.forward_paged(x, pos, cache, tbl,
+                                 jnp.ones((1, 11), bool))
+    got = []
+    for t in range(11):
+        o, cache = attn.forward_paged(x[:, t:t + 1], pos[:, t:t + 1],
+                                      cache, tbl, jnp.ones((1, 1), bool))
+        got.append(o[:, 0])
+    np.testing.assert_allclose(jnp.stack(got, 1), want, atol=2e-6)
+
+
+def test_a_latent_block_has_to_start_its_sequence(bench):
+    """Expanded latent attention sees the fresh block only: a block
+    that starts mid-sequence raises where the positions can be read,
+    and reads NaN under a trace, where nothing can raise."""
+    model, _ = build(bench, toy_cfg())
+    attn = model.model.layers[3].self_attn
+    x = jnp.asarray(np.random.RandomState(6).randn(2, 5, 64), jnp.float32)
+    tbl = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    pos = jnp.asarray([[0, 1, 2, 3, 4], [4, 5, 6, 7, 8]], jnp.int32)
+    cache = attn.init_cache(9, 4, jnp.float32)
+    args = (cache, tbl, jnp.ones((2, 5), bool))
+    with pytest.raises(ValueError, match="from position 0"):
+        attn.forward_paged(x, pos, *args)
+    o, _ = jax.jit(attn.forward_paged)(x, pos, *args)
+    assert bool(jnp.isfinite(o[0]).all()) and bool(jnp.isnan(o[1]).all())
+
+
+def test_a_vocabulary_slice_is_a_smaller_vocabulary(bench):
+    cfg = toy_cfg()
+    model, params = build(bench, cfg)
+    small = dict(cfg, vocab_size=64)
+    model_s, _ = build(bench, small)
+    for (n, p), (_, ps) in zip(model.named_parameters(),
+                               model_s.named_parameters()):
+        if n == "model.embed_tokens":
+            ps._value = p._value[:64]
+        elif n == "lm_head":
+            ps._value = p._value[:, :64]
+        else:
+            ps._value = p._value
+    ids = np.random.RandomState(4).randint(1, 64, size=30).astype(np.int32)
+    whole = _prefill_then_decode(model, ids, L=25)
+    part = _prefill_then_decode(model_s, ids, L=25)
+    np.testing.assert_allclose(part, whole[:, :64], atol=1e-6)
+
+
+# ---------------------------------------------------------------------
+# through GenerationServer
+# ---------------------------------------------------------------------
+def _serve(model, prompts, max_new=10, **kw):
+    opts = dict(num_slots=4, block_size=4, max_model_len=64,
+                prompt_buckets=[16, 32], max_prefill_batch=2,
+                check_replay=True)
+    opts.update(kw)
+    with GenerationServer(model, **opts) as srv:
+        streams = [srv.submit(p, max_new_tokens=max_new) for p in prompts]
+        outs = [s.result(timeout=300) for s in streams]
+        return outs, srv.stats()
+
+
+def _prompts(n, seed=0, lo=5, hi=30):
+    r = np.random.RandomState(seed)
+    return [r.randint(1, 256, size=r.randint(lo, hi)).astype(np.int32)
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("dt_bias", [0.0, -4.0])
+def test_served_tokens_are_the_references_best(bench, dt_bias):
+    """Through submit(): more requests than slots, batched prefill in
+    two buckets, slots reused.  Every served token is the reference's
+    best at its position, up to float32 rounding of the logits."""
+    cfg = toy_cfg()
+    model, params = build(bench, cfg, dt_bias)
+    prompts = _prompts(7)
+    outs, st = _serve(model, prompts)
+    assert st["state_slots"] == 4 and st["state_bytes"] > 0
+    assert st["latent_pool_bytes"] > 0 and st["state_resets"] == 7
+    assert st["moe_picks_here"] > 0
+    assert 0 < st["moe_max_expert_load"] <= st["moe_picks_here"]
+    assert st["traffic_compiles"] == 0
+    for p, out in zip(prompts, outs):
+        ids = np.concatenate([p, np.asarray(out[:-1], np.int32)])
+        lg = bench["ref"].forward_logits(cfg, params, jnp.asarray(ids))
+        at = lg[len(p) - 1:]
+        gap = at.max(-1) - jnp.take_along_axis(
+            at, jnp.asarray(out)[:, None], -1)[:, 0]
+        assert float(gap.max()) < 1e-5
+
+
+def test_a_reused_slot_starts_from_zero_state(bench):
+    model, _ = build(bench, toy_cfg(), dt_bias=-4.0)
+    a, b = _prompts(2, seed=7)
+    # one slot: b takes the slot a left, state and tail still in it
+    (_, second), _ = _serve(model, [a, b], num_slots=1)
+    (alone,), _ = _serve(model, [b], num_slots=1)
+    assert second == alone
+
+
+def test_evict_and_replay_gives_the_same_tokens(bench):
+    model, _ = build(bench, toy_cfg(), dt_bias=-4.0)
+    prompts = _prompts(4, seed=9, lo=20, hi=30)
+    calm, _ = _serve(model, prompts, max_new=20)
+    # 4 sequences of up to 50 positions need ~50 blocks of 4: 24 force
+    # evictions, re-prefill from zero state and replay (check_replay
+    # asserts every replayed token)
+    tight, st = _serve(model, prompts, max_new=20, num_blocks=25)
+    assert st["evicted"] > 0 and st["replay_steps"] > 0
+    assert tight == calm
+
+
+def test_what_knows_only_kv_blocks_is_refused(bench):
+    model, _ = build(bench, toy_cfg())
+    from paddle_tpu.inference import migration
+    with pytest.raises(RecurrentStateUnsupported, match="prefix_cache"):
+        GenerationServer(model, prefix_cache=True)
+    with pytest.raises(RecurrentStateUnsupported, match="speculative"):
+        GenerationServer(model, draft_model=model)
+    with GenerationServer(model, num_slots=2, block_size=4,
+                          max_model_len=32, prompt_buckets=[16]) as srv:
+        s = srv.submit(_prompts(1)[0][:8], max_new_tokens=4)
+        with pytest.raises(RecurrentStateUnsupported, match="migration"):
+            migration.export_sequence(srv, 1)
+        s.result(timeout=120)
+    pools = model.init_paged_cache(9, 4, 2)
+    with pytest.raises(RecurrentStateUnsupported):
+        model.forward_paged(jnp.zeros((2, 3), jnp.int32),
+                            jnp.zeros((2, 3), jnp.int32), pools,
+                            jnp.zeros((2, 8), jnp.int32),
+                            jnp.ones((2, 3), bool), verify_mode=True)
+
+
+def test_a_kv_model_is_served_as_before():
+    """No recurrent state: the server keeps the jitted programs it
+    built, stages no slots and reports empty state."""
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models import LlamaForCausalLM, llama_tiny
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_tiny())
+    model.eval()
+    with GenerationServer(model, num_slots=2, block_size=4,
+                          max_model_len=32, prompt_buckets=[16]) as srv:
+        assert srv._stateful is False
+        assert hasattr(srv._prefill_fn, "lower")       # the jit itself
+        assert hasattr(srv._decode_fn, "lower")
+        out = srv.submit(np.arange(1, 9), max_new_tokens=4).result(120)
+        st = srv.stats()
+    assert len(out) == 4
+    assert (st["state_slots"], st["state_bytes"], st["latent_pool_bytes"],
+            st["state_resets"], st["moe_picks_here"]) == (0, 0, 0, 0, 0)
+
+
+def test_chunked_queries_attend_like_one_block():
+    r = np.random.RandomState(3)
+    q = jnp.asarray(r.randn(2, 64, 2, 24), jnp.float32)
+    k = jnp.asarray(r.randn(2, 64, 2, 24), jnp.float32)
+    v = jnp.asarray(r.randn(2, 64, 2, 16), jnp.float32)
+    np.testing.assert_allclose(KL._attend(q, k, v, 0.2, chunk=16),
+                               KL._attend(q, k, v, 0.2, chunk=64),
+                               atol=1e-6)
