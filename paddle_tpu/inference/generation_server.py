@@ -283,6 +283,59 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
     return sorted(set(out))
 
 
+def _sample_rows(lg, kd, rng_steps, temp, top_k, top_p, do_sample):
+    """Per-row next-token selection over float32 logits ``lg [B, V]``:
+    exact argmax for greedy rows, temperature/top-k/top-p categorical
+    for sampling rows — one program covers any mix, at the price of one
+    vocabulary sort, softmax, cumsum, the keys and the draw for every
+    row.  The key for token j of a request is
+    fold_in(request_key, j-1): a pure function of the stream position,
+    so replay after eviction — and spec-decode verification, which
+    samples the same stream at many positions in one call — reproduce
+    the draw exactly."""
+    import jax
+    import jax.numpy as jnp
+
+    V = lg.shape[-1]
+    x = lg / jnp.maximum(temp, 1e-6)[:, None]
+    srt = jnp.sort(x, axis=-1)[:, ::-1]
+    kk = jnp.clip(top_k, 1, V).astype(jnp.int32)
+    kth = jnp.take_along_axis(srt, (kk - 1)[:, None], axis=-1)
+    use_k = ((top_k > 0) & (top_k < V))[:, None]
+    x = jnp.where(use_k & (x < kth), -jnp.inf, x)
+    # the top-k mask is monotone (it takes the tail of srt, ties at kth
+    # stay), so this IS the masked x sorted: no second sort
+    srt = jnp.where(use_k & (srt < kth), -jnp.inf, srt)
+    probs = jax.nn.softmax(srt, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = jnp.maximum((cum < top_p[:, None]).sum(-1) + 1, 1)
+    kth2 = jnp.take_along_axis(srt, (keep - 1)[:, None], axis=-1)
+    use_p = (top_p < 1.0)[:, None]
+    x = jnp.where(use_p & (x < kth2), -jnp.inf, x)
+    impl = {2: "threefry2x32", 4: "rbg"}.get(
+        int(kd.shape[-1]), "threefry2x32")
+    base = jax.random.wrap_key_data(kd, impl=impl)
+    keys = jax.vmap(jax.random.fold_in)(base, rng_steps)
+    sampled = jax.vmap(jax.random.categorical)(keys, x)
+    return jnp.where(do_sample, sampled.astype(jnp.int32),
+                     jnp.argmax(lg, axis=-1).astype(jnp.int32))
+
+
+def _sample_tokens(lg, kd, rng_steps, temp, top_k, top_p, do_sample):
+    """:func:`_sample_rows` behind a ``lax.cond`` on ``any(do_sample)``,
+    decided on the device: a step whose rows are all greedy runs the
+    argmax alone, and a batch that gains or loses its last sampling row
+    never compiles."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.cond(
+        jnp.any(do_sample),
+        lambda: _sample_rows(lg, kd, rng_steps, temp, top_k, top_p,
+                             do_sample),
+        lambda: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+
+
 class GenerationServer:
     """Continuous-batching generative gateway over a KV-cache-capable
     causal LM (``supports_kv_cache()`` / ``forward_paged``).
@@ -449,6 +502,10 @@ class GenerationServer:
             "decode_steps": 0, "replay_steps": 0,
             "decode_ms": 0.0, "prefill_ms": 0.0,
             "prefill_batches": 0, "prefill_tokens": 0,
+            # decode, verify and prefill dispatches that held a
+            # sampling row (a decode or verify dispatch that held none
+            # ran the sampler's argmax alone)
+            "sampled_steps": 0,
             "prefill_tokens_skipped": 0, "state_resets": 0,
             "spec_verify_steps": 0, "draft_steps": 0,
             "spec_proposed": 0, "spec_accepted": 0,
@@ -514,7 +571,24 @@ class GenerationServer:
                 return lv, pools, (raw(out[2]) if counted else None)
             return call_model
 
+        def make_sample(model):
+            loops = getattr(model, "loops_on_device", None)
+
+            def sample(n_tokens, *args):
+                """Each row's next token in a program whose model part
+                runs ``n_tokens`` tokens: :func:`_sample_tokens`.  A
+                ``conditional`` behind a device loop whose steps branch
+                stopped a v5e (PERF.md section 7, X), so where the
+                model says its program holds such a loop
+                (``loops_on_device``) the program gets the sampler
+                without the branch."""
+                if loops is not None and loops(n_tokens):
+                    return _sample_rows(*args)
+                return _sample_tokens(*args)
+            return sample
+
         call_model = make_call(self._model)
+        sample = make_sample(self._model)
         self._pvals = {k: t._value
                        for k, t in self._model.state_dict().items()}
         self._pools = self._model.init_paged_cache(
@@ -522,44 +596,13 @@ class GenerationServer:
             **({"num_slots": self._num_slots} if self._stateful else {}))
         if self._spec:
             call_draft = make_call(self._draft)
+            draft_sample = make_sample(self._draft)
             self._dvals = {k: t._value
                            for k, t in self._draft.state_dict().items()}
             self._dpools = self._draft.init_paged_cache(
                 self._num_blocks, self._bs)
         else:
             self._dpools = []
-
-        def sample(lg, kd, rng_steps, temp, top_k, top_p, do_sample):
-            """Per-row next-token selection: exact argmax for greedy
-            rows, temperature/top-k/top-p categorical for sampling
-            rows — one program covers any mix.  The key for token j of
-            a request is fold_in(request_key, j-1): a pure function of
-            the stream position, so replay after eviction — and
-            spec-decode verification, which samples the same stream at
-            many positions in one call — reproduce the draw exactly."""
-            V = lg.shape[-1]
-            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            x = lg / jnp.maximum(temp, 1e-6)[:, None]
-            srt = jnp.sort(x, axis=-1)[:, ::-1]
-            kk = jnp.clip(top_k, 1, V).astype(jnp.int32)
-            kth = jnp.take_along_axis(srt, (kk - 1)[:, None], axis=-1)
-            use_k = ((top_k > 0) & (top_k < V))[:, None]
-            x = jnp.where(use_k & (x < kth), -jnp.inf, x)
-            srt2 = jnp.sort(x, axis=-1)[:, ::-1]
-            probs = jax.nn.softmax(srt2, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            keep = jnp.maximum((cum < top_p[:, None]).sum(-1) + 1, 1)
-            kth2 = jnp.take_along_axis(srt2, (keep - 1)[:, None],
-                                       axis=-1)
-            use_p = (top_p < 1.0)[:, None]
-            x = jnp.where(use_p & (x < kth2), -jnp.inf, x)
-            impl = {2: "threefry2x32", 4: "rbg"}.get(
-                int(kd.shape[-1]), "threefry2x32")
-            base = jax.random.wrap_key_data(kd, impl=impl)
-            keys = jax.vmap(jax.random.fold_in)(base, rng_steps)
-            sampled = jax.vmap(jax.random.categorical)(keys, x)
-            return jnp.where(do_sample, sampled.astype(jnp.int32),
-                             greedy)
 
         def decode_fn(pvals, pools, tokens, positions, tables, wm, kd,
                       rng_steps, temp, top_k, top_p, do_sample):
@@ -570,14 +613,14 @@ class GenerationServer:
             logits, pools, counts = call_model(pvals, tokens, positions,
                                                pools, tables, wm)
             lg = logits[:, -1, :].astype(jnp.float32)
-            nxt = sample(lg, kd, rng_steps, temp, top_k, top_p,
-                         do_sample)
+            nxt = sample(tokens.size, lg, kd, rng_steps, temp, top_k,
+                         top_p, do_sample)
             if counts is not None:
                 # behind the tokens: one fetch, no second transfer
                 nxt = jnp.concatenate([nxt, counts.astype(nxt.dtype)])
             return nxt, pools
 
-        def make_prefill(call, name):
+        def make_prefill(call, sample, name):
             def prefill_fn(pvals, pools, prompt, start, length, table,
                            kd, temp, top_k, top_p, do_sample, **rows):
                 server._compiles += 1
@@ -598,8 +641,9 @@ class GenerationServer:
                                         wm, gather_at=gather_at,
                                         verify_mode=prefix_on, **rows)
                 lg = logits[:, -1, :].astype(jnp.float32)
-                first = sample(lg, kd, jnp.zeros_like(length), temp,
-                               top_k, top_p, do_sample)
+                first = sample(prompt.size, lg, kd,
+                               jnp.zeros_like(length), temp, top_k,
+                               top_p, do_sample)
                 return first, pools
             return prefill_fn
 
@@ -616,9 +660,9 @@ class GenerationServer:
                                           tables, wm, verify_mode=True)
             lg = logits.astype(jnp.float32).reshape(B * S, -1)
             rep = lambda a: jnp.repeat(a, S, axis=0)
-            sampled = sample(lg, rep(kd), rng_steps.reshape(B * S),
-                             rep(temp), rep(top_k), rep(top_p),
-                             rep(do_sample))
+            sampled = sample(
+                tokens.size, lg, rep(kd), rng_steps.reshape(B * S),
+                rep(temp), rep(top_k), rep(top_p), rep(do_sample))
             return sampled.reshape(B, S), pools
 
         def fork_fn(pools, dpools, src, dst):
@@ -640,14 +684,15 @@ class GenerationServer:
         on_cpu = target_platform() == "cpu"
         donate = () if on_cpu else (1,)
         self._decode_fn = jax.jit(decode_fn, donate_argnums=donate)
-        self._prefill_fn = jax.jit(make_prefill(call_model, "prefill"),
-                                   donate_argnums=donate)
+        self._prefill_fn = jax.jit(
+            make_prefill(call_model, sample, "prefill"),
+            donate_argnums=donate)
         if self._prefix_on:
             dfork = () if on_cpu else (0, 1)
             self._fork_fn = jax.jit(fork_fn, donate_argnums=dfork)
         if self._spec:
             self._draft_prefill_fn = jax.jit(
-                make_prefill(call_draft, "draft_prefill"),
+                make_prefill(call_draft, draft_sample, "draft_prefill"),
                 donate_argnums=donate)
 
             def draft_decode_fn(dvals, dpools, tokens, positions,
@@ -658,8 +703,8 @@ class GenerationServer:
                 logits, dpools, _ = call_draft(dvals, tokens, positions,
                                                dpools, tables, wm)
                 lg = logits[:, -1, :].astype(jnp.float32)
-                nxt = sample(lg, kd, rng_steps, temp, top_k, top_p,
-                             do_sample)
+                nxt = draft_sample(tokens.size, lg, kd, rng_steps, temp,
+                                   top_k, top_p, do_sample)
                 return nxt, dpools
             self._draft_decode_fn = jax.jit(draft_decode_fn,
                                             donate_argnums=donate)
@@ -1339,6 +1384,7 @@ class GenerationServer:
             with self._lock:
                 self._stats["prefill_ms"] += dt_ms
                 self._stats["prefill_batches"] += 1
+                self._stats["sampled_steps"] += bool(do_sample.any())
                 self._stats["prefill_bucket_hits"][bucket] = \
                     self._stats["prefill_bucket_hits"].get(bucket, 0) \
                     + len(seqs)
@@ -1595,11 +1641,14 @@ class GenerationServer:
                     for i, name in enumerate(self._step_counters):
                         self._stats[name] = self._stats.get(name, 0) \
                             + int(nxt[B + i])
-            self._after_step(len(live), replays, dt_ms)
+            self._after_step(len(live), replays, dt_ms,
+                             bool(do_sample.any()))
 
-    def _after_step(self, n_live: int, replays: int, dt_ms: float):
+    def _after_step(self, n_live: int, replays: int, dt_ms: float,
+                    sampled: bool):
         with self._lock:
             self._stats["decode_steps"] += 1
+            self._stats["sampled_steps"] += sampled
             self._stats["replay_steps"] += replays
             self._stats["decode_ms"] += dt_ms
             n_steps = self._stats["decode_steps"]
@@ -1834,4 +1883,5 @@ class GenerationServer:
                            proposed=proposed_total,
                            accepted=accepted_total,
                            accept_rate=round(a_tot / max(p_tot, 1), 3))
-        self._after_step(len(live), replays, dt_ms)
+        self._after_step(len(live), replays, dt_ms,
+                         bool(do_sample.any()))

@@ -25,7 +25,10 @@ stages and dispatches exactly what it did.
 Apart from the state: a model whose ``step_counters()`` names int32
 counters returns them as ``forward_paged``'s third value; the decode
 program hands them back in the same vector as the sampled tokens (one
-fetch) and ``stats()`` adds them up under their names.
+fetch) and ``stats()`` adds them up under their names.  And a model
+with ``loops_on_device(n_tokens)`` says which programs hold a device
+loop whose steps branch; the server's sampler puts no ``cond`` behind
+those (``GenerationServer._build_programs``).
 """
 from __future__ import annotations
 

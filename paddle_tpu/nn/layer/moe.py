@@ -398,6 +398,13 @@ class DroplessMoELayer(Layer):
                             self.shared_down._value)
         return y.astype(xv.dtype).reshape(xv.shape), n_here, load
 
+    @staticmethod
+    def loops_on_device(n_tokens: int) -> bool:
+        """Whether ``n_tokens`` tokens take the grouped dispatch, which
+        lowers to a device loop whose steps branch (a ``while`` of
+        ``conditional``s)."""
+        return n_tokens >= _DENSE_BELOW
+
     def forward(self, x):
         y, n_here, load = self.apply_values(x._value)
         self.last_counts = (n_here, load)

@@ -31,13 +31,15 @@ Per layer (pre-norm RMSNorm blocks, untied head):
 Serving only: the chunked scan of the KDA prefill has no backward pass
 here, and the expert layer is written for inference (nn/layer/moe.py).
 
-The paged-cache protocol differs from a K/V model's in three places.
+The paged-cache protocol differs from a K/V model's in four places.
 ``init_paged_cache`` takes ``num_slots``.  ``forward_paged`` takes
 ``slots=`` ([B] int32): each row's slot in a batched prefill, where
 rows are not slots (a row that holds no sequence names ``num_slots``,
 which no write reaches); without it row i is slot i, which is what a
 decode step over every slot is.  And it returns a third value, the
 int32 counters :meth:`KimiLinearForCausalLM.step_counters` names.
+``loops_on_device(n_tokens)`` tells the server which of its programs
+hold the expert layers' device loop.
 """
 from __future__ import annotations
 
@@ -574,6 +576,14 @@ class KimiLinearForCausalLM(_Params):
         fetches them behind a decode step's tokens and adds them up in
         ``stats()`` under these names."""
         return ("moe_picks_here", "moe_max_expert_load")
+
+    def loops_on_device(self, n_tokens: int) -> bool:
+        """Whether ``forward_paged`` over ``n_tokens`` tokens lowers a
+        device loop whose steps branch: the expert layers' grouped
+        dispatch does.  ``GenerationServer`` puts no ``conditional`` of
+        its own behind such a program (a v5e stopped on one)."""
+        return any(lyr.is_moe and lyr.mlp.loops_on_device(n_tokens)
+                   for lyr in self.model.layers)
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          num_slots: Optional[int] = None):

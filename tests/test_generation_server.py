@@ -242,7 +242,7 @@ def test_postmortem_classifies_pool_exhaustion_bad():
 
 def test_overload_sheds_typed(lm):
     srv = GenerationServer(lm, num_slots=1, block_size=4,
-                           max_model_len=16, prompt_buckets=[8],
+                           max_model_len=64, prompt_buckets=[8],
                            max_waiting=2, request_timeout_s=60.0)
     # not started: submissions must fail closed, not queue silently
     with pytest.raises(ServerClosed):
@@ -250,7 +250,9 @@ def test_overload_sheds_typed(lm):
     srv.start()
     try:
         p = _prompts(seed=11, lens=(4,))[0]
-        first = srv.submit(p, max_new_tokens=8)
+        # long enough to hold the slot through the three submits below,
+        # however fast a decode step is
+        first = srv.submit(p, max_new_tokens=56)
         next(iter(first))      # admitted: the only slot is now busy
         waiters = [srv.submit(p, max_new_tokens=8) for _ in range(2)]
         # waiting queue at its cap of 2 -> typed shed

@@ -372,6 +372,59 @@ def test_served_tokens_are_the_references_best(bench, dt_bias):
         assert float(gap.max()) < 1e-5
 
 
+def test_a_sampling_request_costs_no_program_and_is_counted(bench):
+    """ISSUE 30: the sampler's work sits in a ``cond`` inside the
+    programs, so this server compiles the 7 programs the commit before
+    compiled for these arguments (6 prefill shapes + decode), none
+    when a request samples, and counts the dispatches that held it."""
+    model, _ = build(bench, toy_cfg())
+    with GenerationServer(model, num_slots=4, block_size=4,
+                          max_model_len=64, prompt_buckets=[16, 32],
+                          max_prefill_batch=2, check_replay=True) as srv:
+        assert srv.num_compiles() == 7
+        a, b = _prompts(2, seed=3)
+        greedy = srv.submit(a, max_new_tokens=6).result(timeout=300)
+        assert srv.stats()["sampled_steps"] == 0
+        kw = dict(max_new_tokens=5, do_sample=True, temperature=0.7,
+                  top_p=0.9, seed=3)
+        drawn = srv.submit(b, **kw).result(timeout=300)
+        assert srv.submit(b, **kw).result(timeout=300) == drawn
+        st = srv.stats()
+        assert len(greedy) == 6 and len(drawn) == 5
+        assert st["sampled_steps"] == 2 * 5     # prefill + 4 decodes
+        assert st["num_compiles"] == 7 and st["traffic_compiles"] == 0
+
+
+@pytest.mark.parametrize("dense_below, in_cond", [
+    (256, {"decode": True, "prefill": True}),    # nothing is grouped
+    (8, {"decode": True, "prefill": False}),     # prefill 2 x 16 is
+    (4, {"decode": False, "prefill": False}),    # and 4 decode rows are
+])
+def test_the_sampler_branches_only_behind_a_program_without_the_expert_loop(
+        bench, monkeypatch, dense_below, in_cond):
+    """The grouped expert product is a device loop whose steps branch;
+    a ``conditional`` behind it stopped a v5e (PERF.md section 7, X), so
+    a program that holds it sorts outside any ``cond``."""
+    from test_sampler import _program_args, _sorts
+    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)
+    model, _ = build(bench, toy_cfg())
+    srv = GenerationServer(model, num_slots=4, block_size=4,
+                           max_model_len=64, prompt_buckets=[16],
+                           max_prefill_batch=2)
+    srv._build_programs()
+    for which, fn, rows in [
+            ("decode", srv._decode_fn, {}),
+            ("prefill", srv._prefill_fn, srv._row_slots([], 2))]:
+        args = _program_args(srv, which)
+        traced = fn.trace(srv._pvals, srv._pools, *args, **rows)
+        assert model.loops_on_device(args[0].size) != in_cond[which]
+        # the grouped dispatch sorts its picks too; the sampler's sort
+        # is the program's last
+        *experts, sampler = _sorts(traced.jaxpr.jaxpr)
+        assert sampler == in_cond[which] and not any(experts)
+        assert bool(experts) != in_cond[which]
+
+
 def test_a_reused_slot_starts_from_zero_state(bench):
     model, _ = build(bench, toy_cfg(), dt_bias=-4.0)
     a, b = _prompts(2, seed=7)

@@ -1,23 +1,30 @@
 #!/usr/bin/env python3
 """Are the device programs of the benchmark's serving cells the same in
-two trees?  Without a chip: builds each Mistral cell's server from
-<tree>, lowers and compiles ``decode_fn`` and the smallest and largest
-``prefill_fn`` for a described v5e, and writes digests of the StableHLO
-and of the optimized HLO (whole, and with what carries file paths and
-line numbers taken out: the serialized Mosaic payload, op metadata) to
-<out>/serving_hlo_<tag>.json, the texts beside it.
+two trees, and did compiling them get slower?  Without a chip: builds
+each serving cell's server from <tree>, lowers and compiles
+``decode_fn`` and the smallest and largest ``prefill_fn`` for a
+described v5e, prints lowering and compile seconds per program, and
+writes them with digests of the StableHLO and of the optimized HLO
+(whole, and with what carries file paths and line numbers taken out:
+the serialized Mosaic payload, op metadata), ``memory_analysis()`` and
+every ``sort`` of the optimized HLO (its shape, and whether it sits in a
+``conditional``'s branch) to <out>/serving_hlo_<tag>.json, the texts
+beside it.
 
-    JAX_PLATFORMS=cpu python3 tools/aot_serving_hlo.py <tree> <tag> <out>
+    JAX_PLATFORMS=cpu python3 tools/aot_serving_hlo.py <tree> <tag> <out> [cell ...]
 
-Run it once per tree (parent unpacked with ``git archive``, then the
-change) and compare the two JSON files: equal ``*_less_*`` digests,
-bytes and operation counts mean the chip runs the same programs, and
-only the compile cache's key moved.  A compile is not a chip run: it
-says nothing about time.  (PERF.md, PR 27, used it for the edits to
-``generation_server.py``.)"""
+No cell named: the two Mistral cells and the Kimi cell.  Run it once
+per tree (parent unpacked with ``git archive``, then the change), one
+run at a time so that the seconds compare, and compare the two JSON
+files: equal ``*_less_*`` digests, bytes and operation counts mean the
+chip runs the same programs, and only the compile cache's key moved.
+A compile is not a chip run: it says nothing about the program's time.
+(PERF.md: PR 27 used it for the edits to ``generation_server.py``,
+PR 30 for the sampler's ``cond``.)"""
 import os, sys, time, json, hashlib, re
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT, TAG, OUT = os.path.abspath(sys.argv[1]), sys.argv[2], sys.argv[3]
+CELLS = sys.argv[4:] or ["mistral7b-serve-decode", "mistral7b-serve-prefill", "kimi-linear-serve-decode"]
 os.makedirs(OUT, exist_ok=True)
 sys.path.insert(0, ROOT)
 import numpy as np, jax, jax.numpy as jnp
@@ -36,7 +43,45 @@ assert mesh_mod.target_platform() == "tpu"
 man = M.load_manifest()
 out = {}
 def S(shape, dt): return jax.ShapeDtypeStruct(shape, dt, sharding=one)
-for cellname in ("mistral7b-serve-decode", "mistral7b-serve-prefill"):
+
+
+def sorts_of(hlo: str):
+    """[(shape, in a conditional's branch?)] for every sort of an
+    optimized HLO module: a sort counts as inside when no path of calls
+    reaches its computation from ENTRY but through a branch."""
+    comps, entry, name = {}, None, None
+    for ln in hlo.split("\n"):
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$", ln)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif ln.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(ln)
+    free = {c: set() for c in comps}     # callees reached without taking a branch
+    edge = re.compile(r"(to_apply|calls|body|condition|true_computation|false_computation|branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
+    for c, lines in comps.items():
+        for kind, names in edge.findall("\n".join(lines)):
+            if kind in ("to_apply", "calls", "body", "condition"):
+                free[c] |= set(re.findall(r"[\w.\-]+", names)) & set(comps)
+    outside, todo = set(), [entry]
+    while todo:
+        c = todo.pop()
+        if c not in outside:
+            outside.add(c)
+            todo += list(free[c])
+    found = []
+    for c, lines in comps.items():
+        for ln in lines:
+            m = re.search(r"= \(?(\w+\[[\d,]*\])[^=]* sort\(", ln)
+            if m:
+                found.append((m.group(1), c not in outside))
+    return sorted(found)
+
+
+for cellname in CELLS:
     cell = M.Cell(man, cellname)
     cfg, sv = cell.config, cell.spec["server"]
     model = cell.binding().build_serving(cfg, sv["max_model_len"])
@@ -56,11 +101,15 @@ for cellname in ("mistral7b-serve-decode", "mistral7b-serve-prefill"):
         else:
             b, pb = map(int, w.split("x"))
             args = (S((pb, b), jnp.int32), S((pb,), jnp.int32), S((pb,), jnp.int32), S((pb, Mx), jnp.int32), S((pb, W), jnp.uint32), S((pb,), jnp.float32), S((pb,), jnp.int32), S((pb,), jnp.float32), S((pb,), jnp.bool_))
-            low = srv._prefill_fn.lower(pv, pools, *args)
+            # a model with per-slot state takes each row's slot by name
+            rows = {k: sds(v) for k, v in srv._row_slots([], pb).items()}
+            low = srv._prefill_fn.lower(pv, pools, *args, **rows)
         shlo = low.as_text()
+        t_low = time.time()
         # a Mosaic payload carries its call sites' lines: take the serialized kernel out of the comparison, keep its size
         nolines = re.sub(r'backend_config = "[^"]*"', lambda m: f'backend_config = <{len(m.group(0))} bytes>', shlo)
         c = low.compile()
+        t_comp = time.time()
         opt = c.as_text()
         opt_nometa = re.sub(r', metadata=\{[^}]*\}', '', opt)
         # a Mosaic call's serialized kernel: keep its size only
@@ -75,12 +124,15 @@ for cellname in ("mistral7b-serve-decode", "mistral7b-serve-prefill"):
                 "StackFrames")))
         ma = c.memory_analysis()
         out[f"{cellname}/{w}"] = {
+            "lower_s": round(t_low - t, 1), "compile_s": round(t_comp - t_low, 1),
+            "sorts": [{"shape": s, "in_conditional": i} for s, i in sorts_of(opt_nometa)],
             "stablehlo_sha": hashlib.sha256(shlo.encode()).hexdigest()[:16],
             "stablehlo_less_kernel_payload_sha": hashlib.sha256(nolines.encode()).hexdigest()[:16],
             "stablehlo_bytes": len(shlo), "optimized_sha": hashlib.sha256(opt.encode()).hexdigest()[:16],
             "optimized_less_metadata_sha": hashlib.sha256(opt_nometa.encode()).hexdigest()[:16],
             "custom_calls": opt.count("tpu_custom_call"), "temp_bytes": ma.temp_size_in_bytes, "arg_bytes": ma.argument_size_in_bytes,
-            "flops": c.cost_analysis().get("flops", 0), "compile_s": round(time.time() - t, 1)}
+            "output_bytes": ma.output_size_in_bytes, "code_bytes": ma.generated_code_size_in_bytes,
+            "flops": c.cost_analysis().get("flops", 0)}
         open(f"{OUT}/serving_hlo_{TAG}_{cellname}_{w}.stablehlo.txt", "w").write(shlo)
         open(f"{OUT}/serving_hlo_{TAG}_{cellname}_{w}.opt.txt", "w").write(opt_nometa)
         print(cellname, w, out[f"{cellname}/{w}"], flush=True)
