@@ -54,12 +54,23 @@ __all__ = ["AuditReport", "audit_fn", "audit_traced", "audit_jaxpr",
            "kernel_inventory", "hlo_kernel_inventory",
            "COLLECTIVE_PRIMS", "CALLBACK_PRIMS", "KERNEL_PRIMS"]
 
-# jaxpr-level collective primitives (psum lowers as psum2 on jax 0.4.x)
+# jaxpr-level collective primitives -> the family they are counted
+# under.  The names are those of the installed JAX (0.9): inside a
+# ``shard_map`` that tracks varying axes a ``lax.psum`` traces to
+# ``psum_invariant`` (and the transpose of a ``pvary``, i.e. the
+# gradient of a replicated input, is one too); ``psum`` itself is what
+# ``check_vma=False`` bodies emit.  tests/test_graft_lint.py traces
+# every family through ``shard_map``, so a renamed primitive fails
+# there instead of reading as "no collectives".
 COLLECTIVE_PRIMS = {
-    "psum": "psum", "psum2": "psum", "pmax": "pmax", "pmin": "pmin",
-    "all_gather": "all_gather", "all_to_all": "all_to_all",
+    "psum": "psum", "psum_invariant": "psum", "unreduced_psum": "psum",
+    "pmax": "pmax", "pmin": "pmin",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "all_gather_reduced": "all_gather",
+    "all_to_all": "all_to_all", "ragged_all_to_all": "all_to_all",
     "ppermute": "ppermute", "pgather": "pgather",
-    "reduce_scatter": "reduce_scatter", "psum_scatter": "reduce_scatter",
+    "reduce_scatter": "reduce_scatter",
+    "unreduced_reduce_scatter": "reduce_scatter",
 }
 
 # host-callback primitives: anything here inside a step program is a
@@ -163,7 +174,7 @@ class AuditReport:
 
     def collective_count(self, kind: Optional[str] = None) -> int:
         """Collective ops in the program.  When compiled HLO text was
-        audited, the post-SPMD instruction counts are the ground truth
+        audited, the post-SPMD counts (arrays moved) are the ground truth
         (jit-with-shardings programs carry no jaxpr collectives at
         all); otherwise the jaxpr primitive counts are used.  ``kind``
         filters to one family (``"psum"`` maps to HLO ``all-reduce``,
@@ -236,13 +247,13 @@ def collective_inventory(closed_jaxpr) -> Dict[str, Dict[str, int]]:
 
 
 def _kernel_name(eqn) -> str:
-    """Best-effort kernel name for a pallas/Mosaic custom call: the
-    pallas_call's NameAndSrcInfo carries the kernel function name."""
-    nsi = eqn.params.get("name_and_src_info")
-    nm = getattr(nsi, "name", None)
-    if nm:
-        return str(nm)
+    """Kernel name of a pallas/Mosaic custom call: the ``name=`` the
+    call site gave ``pallas_call``, else the kernel function's own name
+    (kept on the kernel jaxpr's ``debug_info``), else the primitive."""
     nm = eqn.params.get("name")
+    if not nm:
+        dbg = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+        nm = getattr(dbg, "func_name", None)
     return str(nm) if nm else eqn.primitive.name
 
 
@@ -277,7 +288,11 @@ def hlo_kernel_inventory(hlo_text: str) -> Dict[str, int]:
 def hlo_collective_inventory(hlo_text: str) -> Dict[str, Dict[str, int]]:
     """Count + bytes of collective instructions in compiled HLO text —
     the post-SPMD ground truth for jit-with-shardings programs, where
-    the jaxpr carries no explicit collectives at all."""
+    the jaxpr carries no explicit collectives at all.  ``count`` is the
+    number of arrays moved: XLA's combiner passes merge neighbouring
+    collectives into one tuple-shaped instruction as its thresholds see
+    fit, so ``(f32[16], f32[16,8]) all-reduce(...)`` counts 2 — what
+    the program asked for, whatever the compiler batched."""
     inv: Dict[str, Dict[str, int]] = {}
     for line in hlo_text.splitlines():
         for op in _HLO_COLLECTIVES:
@@ -289,14 +304,15 @@ def hlo_collective_inventory(hlo_text: str) -> Dict[str, Dict[str, int]]:
             #   %x = f32[128,256]{1,0} all-reduce(...)
             typ = line[line.index("=") + 1:idx]
             nbytes = 0
-            for dt, dims in _HLO_SHAPE_RE.findall(typ):
+            shapes = _HLO_SHAPE_RE.findall(typ)
+            for dt, dims in shapes:
                 n = 1
                 for d in dims.split(","):
                     if d:
                         n *= int(d)
                 nbytes += n * _HLO_DTYPE_BYTES.get(dt, 4)
             d = inv.setdefault(op, {"count": 0, "bytes": 0})
-            d["count"] += 1
+            d["count"] += max(1, len(shapes))
             d["bytes"] += nbytes
             break
     return inv

@@ -96,7 +96,7 @@ class ReshardMeter:
 
 
 #: process-wide meter — the elastic trainer's streamed save/restore
-#: paths account here; tests and tools/profile_reshard.py read/reset it
+#: paths account here; tests/test_elastic_device.py reads and resets it
 reshard_meter = ReshardMeter()
 
 

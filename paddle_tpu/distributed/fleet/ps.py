@@ -640,7 +640,7 @@ class SparseTable:
     def spill_stats(self) -> dict:
         """``{hot, cold, promoted, demoted}`` row counts — hot/cold are
         the live split, promoted/demoted are lifetime tier-crossing
-        totals (the churn signal tools/profile_ps.py --tier reports)."""
+        totals (the churn signal; tests/test_ps_tiering.py reads them)."""
         if self._native is None:
             return dict(hot=len(self._rows), cold=0, promoted=0,
                         demoted=0)

@@ -7,7 +7,7 @@ structured activation estimate.  The slow half (``search.verify_plan``)
 replaces the estimate with XLA's own memory analysis via
 ``compile_abstract``; the analytic model exists to RANK candidates so
 only the top-k pay a compile, and its error vs XLA is *measured*
-(``bench.py plan``, ``calibrate.py``), not assumed.
+(``tests/test_planner.py``, ``calibrate.py``), not assumed.
 
 State terms (params / moments / grads / AMP shadow) are exact
 dtype-width × sharded-numel accounting over the canonical specs.  The
@@ -412,8 +412,8 @@ def analytic_collectives(model: ModelSpec, train: TrainSpec,
 
 # ----------------------------------------------------------------------
 # proxy suite — the configs the planner's predicted-vs-XLA error is
-# measured on (tests/test_planner.py pins the bound; bench.py "plan"
-# re-measures it every round).  f32 compute: the CPU backend aborts on
+# measured on (tests/test_planner.py pins the bound and re-measures it
+# on every tier-1 run).  f32 compute: the CPU backend aborts on
 # bf16 collectives without an XLA flag (see __graft_entry__), and the
 # suite must verify in-process under tier-1.
 # ----------------------------------------------------------------------

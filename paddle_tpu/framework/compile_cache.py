@@ -2,7 +2,7 @@
 
 Every owner of compiled programs (``DistributedTrainStep``,
 ``GenerationServer.start``, ``inference.Predictor``) and the two drivers
-(``bench.py``, ``chip_smoke.py``) call :func:`ensure_compile_cache`
+(``perfbench/run.py``, ``chip_smoke.py``) call :func:`ensure_compile_cache`
 before their first compile.  The location rule:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it by itself — this

@@ -25,8 +25,8 @@ Freshness: every event batch carries its ingest timestamp (stamped by
 the source, or at dequeue when the source does not); the push stamps it
 through as the mutation's ``iwm`` watermark, replicas applying the
 record observe event-ingested -> servable-at-THIS-replica latency into
-the ``ps_freshness_ms`` histogram — the SLO and the ``bench.py
-online`` percentiles read from that real data path, not a synthetic
+the ``ps_freshness_ms`` histogram — the SLO (``tests/test_online_loop.py``
+holds it) reads its percentiles from that real data path, not a synthetic
 probe.
 
 Client-side pre-merge: duplicate ids inside a batch merge BEFORE the

@@ -209,7 +209,7 @@ def fused_health(grads: Sequence, loss=None, precise: bool = True):
     """In-jit building block: returns the f32[3] health array WITHOUT any
     host transfer — compose it into a jitted train step and hand the
     result to :meth:`TrainGuard.check` (DistributedTrainStep
-    guard_health and bench.py BENCH_GUARD do this).  ``precise=False``
+    guard_health does this: tests/test_train_guard.py).  ``precise=False``
     selects the single-pass reduction (indicator instead of element
     count, unmasked norm) — the right choice inside a hot step."""
     reduce = _health_reduce if precise else _health_reduce_fast

@@ -89,13 +89,6 @@ def test_device_cache_bucketing_and_pins():
     assert hasattr(c, "release")
 
 
-def test_bench_metric_registry():
-    import bench
-    for fn in ("_bench_resnet", "_bench_bert", "_bench_llama",
-               "_bench_wide_deep"):
-        assert hasattr(bench, fn), fn
-
-
 def test_bert_masked_positions_surface():
     from paddle_tpu.text.models.bert import BertForPretraining
     assert "masked_positions" in inspect.signature(

@@ -4,7 +4,6 @@ Parity model: reference distributed/table/common_sparse_table tests —
 lazy row init, optimizer update semantics vs a numpy oracle, geo delta
 push, save/load, concurrency.
 """
-import os
 import threading
 
 import numpy as np
@@ -337,18 +336,67 @@ def test_use_native_flag():
 
 
 @requires_native
-def test_wide_deep_native_e2e_smoke(monkeypatch):
-    """wide_deep end-to-end through HeterTrainer with use_native=True
-    (the r6 bench default): loss finite, native backend actually on."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    monkeypatch.setenv("BENCH_PS_NATIVE", "1")
-    monkeypatch.setenv("BENCH_STEPS", "4")
-    monkeypatch.setenv("BENCH_BATCH", "64")
-    out = bench._bench_wide_deep(smoke=True, peak_tflops=100.0)
-    assert out["ps_backend"] == "native"
-    assert out["value"] > 0
-    assert np.isfinite(out["loss_last"])
-    assert out["plausible"]
+def test_wide_deep_native_e2e_smoke():
+    """wide&deep end to end through HeterTrainer over the native
+    SparseTable (4 slots x dim 8, 13 dense features, hidden 64, 4 steps
+    of 64): pull is one batched C gather, the pulled rows ride into the
+    jitted dense step as an input, push is the fused native update.
+    Native backend actually on, loss finite, examples flow."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.fleet.heter import HeterTrainer
+
+    n_slots, dim, n_dense, hidden = 4, 8, 13, 64
+    batch, steps, vocab = 64, 4, 1000
+    table = SparseTable(dim, optimizer="sgd", lr=0.05)
+    assert table.is_native
+    rng = np.random.RandomState(0)
+    state = {"losses": [], "params": (
+        jnp.asarray(rng.randn(n_slots * dim + n_dense, hidden) * 0.05,
+                    jnp.float32),
+        jnp.zeros((hidden,), jnp.float32),
+        jnp.asarray(rng.randn(hidden, 1) * 0.05, jnp.float32),
+        jnp.asarray(rng.randn(n_dense, 1) * 0.05, jnp.float32))}
+
+    @jax.jit
+    def dense_fwd_bwd(params, emb, dense, label):
+        def loss_of(params, emb):
+            w1, b1, w2, wide_w = params
+            deep_in = jnp.concatenate(
+                [emb.reshape(batch, n_slots * dim), dense], axis=1)
+            h = jax.nn.relu(deep_in @ w1 + b1)
+            logit = jnp.clip((h @ w2 + dense @ wide_w)[:, 0], -15, 15)
+            return jnp.mean(jnp.logaddexp(0.0, logit) - logit * label)
+        loss, (gp, ge) = jax.value_and_grad(
+            loss_of, argnums=(0, 1))(params, emb)
+        return loss, tuple(p - 0.05 * g for p, g in zip(params, gp)), ge
+
+    def dense_step(embs, batch_data):
+        loss, state["params"], ge = dense_fwd_bwd(
+            state["params"], embs["slots"], jnp.asarray(batch_data[1]),
+            jnp.asarray(batch_data[2]))
+        state["losses"].append(loss)
+        return loss, {"slots": ge.reshape(-1, dim)}
+
+    # Zipf-skewed ids, one id space per slot, a learnable label
+    zipf = np.clip(rng.zipf(1.3, size=(steps, batch, n_slots)), 1, vocab)
+    batches = []
+    for i in range(steps):
+        ids = ((zipf[i] - 1) + np.arange(n_slots) * vocab).astype(np.int64)
+        dense = rng.rand(batch, n_dense).astype(np.float32)
+        batches.append((ids, dense, (dense[:, 0] > 0.5).astype(np.float32)))
+
+    tr = HeterTrainer({"slots": table}, dense_step, sync_mode=False,
+                      push_lag=1)
+    t0 = time.perf_counter()
+    n = tr.run(batches, lambda b: {"slots": b[0].reshape(-1)})
+    losses = [float(l) for l in state["losses"]]   # forces the chain
+    dt = time.perf_counter() - t0
+    tr.shutdown()
+    assert n == steps and len(losses) == steps
+    assert np.isfinite(losses).all()
+    assert batch * n / dt > 0
+    assert 0 < len(table) <= steps * batch * n_slots

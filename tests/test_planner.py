@@ -182,15 +182,45 @@ def test_proxy_top_plans_lower_and_carry_xla_peaks():
         assert r.verify_error
 
 
-def test_rejected_pp_plans_are_dropped_not_returned():
-    """On this container pp>1 cannot lower (jaxlib 0.4.37 PartitionId
-    env limit, same as the 8 pipeline tier-1 failures) — the planner
-    must DROP those candidates and still return lowerable plans."""
+def test_pp_plans_that_lower_are_returned_verified():
+    """The best-ranked candidate of the 8-chip proxy is a pipeline plan
+    (pp4 x fsdp2); it lowers on the installed JAX, so the planner
+    returns it verified and rejects nothing on the way."""
+    ms, ts = proxy_specs(PROXY_SUITE[0])
+    pl = Planner(ms, ts)
+    ranked = pl.rank(8)
+    assert ranked[0].degrees["pp"] > 1
+    plans = pl.plan(8, verify_top_k=1)
+    assert [p.tag for p in plans] == [ranked[0].tag]
+    assert plans[0].verified and plans[0].verify_error is None
+    assert plans[0].verified_peak_bytes > 0
+    assert pl.rejected == []
+
+
+def test_candidates_that_cannot_lower_are_dropped_not_returned(
+        monkeypatch):
+    """A candidate whose lowering raises (a pipeline plan on a jaxlib
+    that cannot partition it, as 0.4.37 could not) is a RESULT: the
+    planner drops it into ``rejected`` with its typed error, goes on
+    down the ranking and still returns a plan that lowers."""
+    from paddle_tpu.distributed.planner import search
+    real = search._verify_compile
+
+    def no_pipeline(model, train, degrees, chips):
+        if degrees.get("pp", 1) > 1:
+            raise NotImplementedError("PartitionId is not supported")
+        return real(model, train, degrees, chips)
+
+    monkeypatch.setattr(search, "_verify_compile", no_pipeline)
     ms, ts = proxy_specs(PROXY_SUITE[0])
     pl = Planner(ms, ts)
     plans = pl.plan(8, verify_top_k=1)
-    assert plans and all(p.verified for p in plans)
-    assert all(p.degrees.get("pp", 1) == 1 for p in plans)
+    assert len(plans) == 1 and plans[0].verified
+    assert plans[0].degrees.get("pp", 1) == 1
+    assert pl.rejected and all(
+        r.degrees["pp"] > 1 and not r.verified and r.verify_error
+        == "NotImplementedError: PartitionId is not supported"
+        for r in pl.rejected)
 
 
 # ----------------------------------------------------------------------
