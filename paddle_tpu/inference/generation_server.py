@@ -94,18 +94,36 @@ gauges on the /metrics endpoint), and flight-recorder events
 and ``serve.spec_verify``) so ``tools/postmortem.py`` can autopsy a
 pool-exhaustion shed.
 
+Plain decode is a pipeline of depth one (ISSUE 32): an iteration
+dispatches decode step n+1 and only then reads step n, which the
+device ran meanwhile.  Each row's last token stays on the device (the
+decode program takes the vector the step before returned, and a token
+from the host only for a row that joined since or replays); what else
+a step needs the host knows one step early, a request's end by length
+included.  An ``eos`` cannot be counted: its row rides one step more
+and that step's token for it is dropped.  What reads or rewrites
+sequence state as if no step were in flight (a queued command, an
+eviction, ``stop()``) reads the step in flight first; speculative mode
+reads every iteration at once.  ``stats()["decode_steps_overlapped"]``
+counts the steps dispatched over an unread one.
+
 The scheduler loop itself is on the profiler's clock (ISSUE 25): one
 ``StepTimeline("serve")`` step per iteration with work, its phases
 ``serve.admit`` -> ``serve.prefill.stage|dispatch|fetch|post`` (per
 batch) -> ``serve.decode.grow|stage|dispatch|fetch|emit`` (or one
-``serve.spec``), and ``serve.idle`` while nothing is in flight.  Each
-is a ``jax.profiler.TraceAnnotation`` on this thread's line of a
-profiler trace and a row of ``observability.timeline.spans("serve")``;
-the rows of ``serve.admit`` carry ``queue_wait_ms`` (one value per
-admitted sequence), those of ``serve.prefill.stage`` the padded
-``batch`` x ``bucket`` and the useful ``tokens``.
-``decode_ms`` / ``prefill_ms`` are read off the dispatch and fetch
-spans' own clock reads.
+``serve.spec``), and ``serve.idle`` while nothing is in flight; the
+``fetch`` and ``emit`` of an iteration are those of the step
+dispatched one iteration earlier.  Each is a
+``jax.profiler.TraceAnnotation`` on this thread's line of a profiler
+trace and a row of ``observability.timeline.spans("serve")``; the rows
+of ``serve.admit`` carry ``queue_wait_ms`` (one value per admitted
+sequence), those of ``serve.prefill.stage`` the padded ``batch`` x
+``bucket`` and the useful ``tokens``, those of
+``serve.decode.dispatch`` ``overlapped`` (0 or 1).  ``decode_ms`` /
+``prefill_ms`` are read off the dispatch and fetch spans' own clock
+reads and count no instant twice: a decode step runs from its
+dispatch, or from the end of the fetch before it where that is later,
+to the end of its own fetch.
 
 ISSUE 12 (fleet observatory) adds the REQUEST dimension:
 ``submit(tenant=...)`` tags a request for usage accounting (always-on
@@ -126,7 +144,7 @@ from __future__ import annotations
 import queue as _queue
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -240,7 +258,7 @@ class _GenSeq:
         "top_k", "top_p", "key_data", "priority", "arrival", "deadline",
         "stream", "generated", "decoded", "blocks", "slot", "evictions",
         "t_submit", "t_first_tok", "cached", "draft_decoded", "tenant",
-        "rt")
+        "rt", "unread")
 
     def __init__(self, rid, prompt, max_new, eos, do_sample, temp,
                  top_k, top_p, key_data, priority, arrival, deadline,
@@ -260,7 +278,8 @@ class _GenSeq:
         self.deadline = deadline
         self.stream = GenerationStream(rid)
         self.generated: List[int] = []        # emitted tokens t1..tn
-        self.decoded = 0          # decode steps done since (re)prefill
+        self.decoded = 0          # decode steps READ since (re)prefill
+        self.unread = 0           # 1 while it rides in the step in flight
         self.blocks: List[int] = []
         self.slot: Optional[int] = None
         self.evictions = 0
@@ -272,6 +291,14 @@ class _GenSeq:
         # per-request span lane; None keeps the traced-off path at one
         # attribute check per site
         self.rt: Optional[RequestTrace] = None
+
+
+class _Unread(NamedTuple):
+    """A decode step that is dispatched and not yet read: what it
+    returned is the server's ``_prev``, still on the device."""
+    rows: List[_GenSeq]    # the sequences that ride in it
+    t0: float              # the clock at the start of its dispatch
+    sampled: bool          # a row of it samples
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -500,6 +527,8 @@ class GenerationServer:
             "evicted": 0, "finished": 0, "shed_overload": 0,
             "shed_timeout": 0, "tokens_generated": 0,
             "decode_steps": 0, "replay_steps": 0,
+            # decode steps dispatched while the step before was unread
+            "decode_steps_overlapped": 0,
             "decode_ms": 0.0, "prefill_ms": 0.0,
             "prefill_batches": 0, "prefill_tokens": 0,
             # decode, verify and prefill dispatches that held a
@@ -527,6 +556,12 @@ class GenerationServer:
         self._draft_decode_fn = None
         self._verify_fn = None
         self._fork_fn = None
+        self._prev = None
+        # the decode step that is dispatched and not yet read
+        self._inflight: Optional[_Unread] = None
+        # decode_ms and prefill_ms count no instant twice: the end of
+        # the last fetch either of them counted
+        self._timed_until = 0.0
 
     # -- program construction ----------------------------------------
     def _build_programs(self):
@@ -603,13 +638,24 @@ class GenerationServer:
                 self._num_blocks, self._bs)
         else:
             self._dpools = []
+        # what the last decode step returned, on the device: the next
+        # one's token feed, and what :meth:`_read_inflight` fetches
+        self._prev = np.zeros(
+            (self._num_slots + len(self._step_counters),), np.int32)
 
-        def decode_fn(pvals, pools, tokens, positions, tables, wm, kd,
-                      rng_steps, temp, top_k, top_p, do_sample):
+        def decode_fn(pvals, pools, prev, tokens, positions, tables, wm,
+                      kd, rng_steps, temp, top_k, top_p, do_sample):
             # python side effect runs at TRACE time only: the counter
             # proves steady-state decode never retraces
             server._compiles += 1
             server._note_compile("decode", 1, tokens.shape[0])
+            # the token feed stays on the device: ``prev`` is what the
+            # step before returned (tokens first, the model's step
+            # counters behind them), and a row takes its token from it
+            # unless the host staged one (>= 0): a row that joined
+            # since, or one that replays tokens the host holds
+            tokens = jnp.where(tokens >= 0, tokens,
+                               prev[:tokens.shape[0], None])
             logits, pools, counts = call_model(pvals, tokens, positions,
                                                pools, tables, wm)
             lg = logits[:, -1, :].astype(jnp.float32)
@@ -774,8 +820,13 @@ class GenerationServer:
                     np.zeros((B,), np.int32),
                     np.ones((B,), np.float32),
                     np.zeros((B,), bool))
-        nxt, self._pools = self._decode_fn(self._pvals, self._pools,
-                                           *dec_args)
+        # the decode program's token feed is the vector the step before
+        # returned: warm it up on a host array and then on its own
+        # result, the only form traffic ever passes
+        for _ in range(2):
+            self._prev, self._pools = self._decode_fn(
+                self._pvals, self._pools, self._prev, *dec_args)
+        nxt = self._prev
         if self._spec:
             dn, self._dpools = self._draft_decode_fn(
                 self._dvals, self._dpools, *dec_args)
@@ -1105,29 +1156,41 @@ class GenerationServer:
         try:
             while True:
                 with self._cond:
-                    if not self._running:
-                        # commands still queued are run by stop()
-                        return
-                    if not self._active and not self._waiting \
-                            and self._cmds.empty():
+                    running = self._running
+                    if running and not self._active and not self._waiting \
+                            and self._cmds.empty() \
+                            and self._inflight is None:
                         with tl.phase("idle"):
                             self._cond.wait(timeout=0.05)
                         continue
+                if not running:
+                    # stop(): what was dispatched is delivered; commands
+                    # still queued are run by stop()
+                    if self._inflight is not None:
+                        with tl.step(step_i):
+                            self._read_inflight()
+                    return
                 with tl.step(step_i):
                     step_i += 1
+                    if not self._cmds.empty():
+                        # a command (cancel, migration) reads and
+                        # rewrites sequence state as if no step were in
+                        # flight: read it first
+                        self._read_inflight()
                     with tl.phase("admit") as ph:
-                        self._drain_cmds()
+                        if self._inflight is None:
+                            self._drain_cmds()
                         self._expire_waiting()
                         batches, waits = self._admit()
                         ph.set(queue_wait_ms=waits)
                     for bucket, seqs in batches:
                         self._prefill_batch(seqs, bucket)
-                    if self._active:
-                        if self._spec:
+                    if self._spec:
+                        if self._active:
                             with tl.phase("spec"):
                                 self._spec_once()
-                        else:
-                            self._decode_once()
+                    elif self._active or self._inflight is not None:
+                        self._decode_once()
         except BaseException as e:   # noqa: BLE001 — fail streams loudly
             with self._lock:
                 victims = (list(self._waiting)
@@ -1135,6 +1198,7 @@ class GenerationServer:
                 self._waiting.clear()
                 self._active.clear()
                 self._running = False
+            self._inflight = None    # its tokens go with the streams
             for seq in victims:
                 if seq.rt is not None:
                     seq.rt.finish("scheduler_error")
@@ -1380,6 +1444,7 @@ class GenerationServer:
         # prefill_ms keeps its meaning (dispatch through fetch), read
         # off the two spans' clocks
         dt_ms = (fetch.t1 - disp.t0) * 1e3
+        self._timed_until = fetch.t1
         with tl.phase("prefill.post"):
             with self._lock:
                 self._stats["prefill_ms"] += dt_ms
@@ -1524,6 +1589,7 @@ class GenerationServer:
                 self._active.pop(seq.slot, None)
                 self._free_slots.append(seq.slot)
                 seq.slot = None
+            seq.unread = 0
 
     def _evict(self, seq: _GenSeq):
         """Block-pool exhaustion: free the victim's blocks and send it
@@ -1548,19 +1614,28 @@ class GenerationServer:
             seq.rt.begin("queue")   # waiting for re-admission
         _flight.maybe_dump("BlockPoolExhausted")
 
-    def _grow_or_evict(self):
-        """Before a decode/verify step every live sequence must own the
-        blocks its next K/V writes land in (one position for plain
-        decode, up to spec_k+1 for a spec iteration); a dry pool evicts
-        the lowest-priority sequence (highest priority number, then
-        youngest)."""
+    def _blocks_lacking(self, seq, ahead=0):
+        """How many blocks the sequence lacks for the position its next
+        step writes, counted past the step in flight (``ahead``
+        positions further for a spec iteration)."""
+        p = min(seq.L + seq.decoded + seq.unread + ahead,
+                self._max_len - 1)
+        return p // self._bs + 1 - len(seq.blocks)
+
+    def _grow_or_evict(self, rows=None):
+        """Before a decode/verify step every sequence of it (``rows``;
+        every live one by default) must own the blocks its next K/V
+        writes land in (one position for plain decode, counted past the
+        step in flight; up to spec_k+1 for a spec iteration); a dry
+        pool evicts the lowest-priority sequence (highest priority
+        number, then youngest)."""
         ahead = self._k if self._spec else 0
-        for seq in sorted(self._active.values(), key=lambda s: s.slot):
-            if seq.slot is None:
-                continue      # evicted below us this round
-            p = min(seq.L + seq.decoded + ahead, self._max_len - 1)
-            need = p // self._bs + 1
-            while len(seq.blocks) < need and seq.slot is not None:
+        if rows is None:
+            rows = sorted(self._active.values(), key=lambda s: s.slot)
+        for seq in rows:
+            # a sequence evicted below us this round has no slot
+            while seq.slot is not None \
+                    and self._blocks_lacking(seq, ahead) > 0:
                 with self._lock:
                     blk = self._cache.alloc()
                     if blk is not None:
@@ -1572,18 +1647,50 @@ class GenerationServer:
                 # the growing sequence itself can be the lowest
                 # priority: it re-queues and this slot sits out
 
-    # -- plain decode -------------------------------------------------
+    # -- plain decode: a pipeline of depth one -------------------------
+    def _riders(self):
+        """The sequences of the next decode step, by slot, and whether
+        the pool holds the blocks they lack.  A sequence whose step in
+        flight yields its last token by length rides no further: the
+        host counts tokens, it need not read one to know.  An ``eos``
+        cannot be counted, so such a sequence rides on, and if the step
+        in flight turns out to have ended it, the next step's token for
+        it is dropped (:meth:`_read_inflight`)."""
+        with self._lock:
+            rows = sorted((s for s in self._active.values()
+                           if s.decoded + s.unread + 1 < s.max_new),
+                          key=lambda s: s.slot)
+            free = self._cache.available()
+        lack = sum(max(self._blocks_lacking(s), 0) for s in rows)
+        return rows, lack <= free
+
     def _decode_once(self):
+        """Dispatch decode step n+1, then read step n, which the device
+        ran while the host staged n+1.  All that n+1 needs of n is each
+        row's token, and that stays on the device (``decode_fn``'s
+        ``prev``); positions, tables, keys and lengths the host knows
+        one step early.  With nothing in flight the step is dispatched
+        and left unread.  What must see sequence state as if no step
+        were in flight reads it first, and this iteration then runs at
+        depth zero: an eviction here, a command in :meth:`_loop`."""
         tl = self._tl
+        rows, fits = self._riders()
+        if self._inflight is not None and not (rows and fits):
+            # nothing rides on, or the pool is dry: the read may finish
+            # sequences and free their blocks before anything is evicted
+            self._read_inflight()
+            rows, fits = self._riders()
+        if not rows:
+            return
         with tl.phase("decode.grow"):
-            self._grow_or_evict()
+            self._grow_or_evict(rows)
         with tl.phase("decode.stage"):
-            with self._lock:
-                live = sorted(self._active.values(), key=lambda s: s.slot)
-            if not live:
-                return
+            if not fits:              # evictions: see who is left
+                rows, _ = self._riders()
+                if not rows:
+                    return
             B, M = self._num_slots, self._M
-            W = live[0].key_data.shape[-1]
+            W = rows[0].key_data.shape[-1]
             tokens = np.zeros((B, 1), np.int32)
             positions = np.zeros((B, 1), np.int32)
             tables = np.zeros((B, M), np.int32)
@@ -1594,33 +1701,66 @@ class GenerationServer:
             top_k = np.zeros((B,), np.int32)
             top_p = np.ones((B,), np.float32)
             do_sample = np.zeros((B,), bool)
-            for seq in live:
+            for seq in rows:
                 s = seq.slot
-                tokens[s, 0] = seq.generated[seq.decoded]
-                positions[s, 0] = seq.L + seq.decoded
+                d = seq.decoded + seq.unread      # the token it feeds
+                # -1: the token the step in flight is producing, taken
+                # on the device
+                tokens[s, 0] = seq.generated[d] \
+                    if d < len(seq.generated) else -1
+                positions[s, 0] = seq.L + d
                 tables[s, :len(seq.blocks)] = seq.blocks
                 wm[s, 0] = True
                 kd[s] = seq.key_data
-                rng_steps[s] = seq.decoded + 1
+                rng_steps[s] = d + 1
                 temp[s] = seq.temp
                 top_k[s] = seq.top_k
                 top_p[s] = seq.top_p
                 do_sample[s] = seq.do_sample
-        with tl.phase("decode.dispatch") as disp:
+                seq.unread += 1
+        overlapped = int(self._inflight is not None)
+        with tl.phase("decode.dispatch", overlapped=overlapped) as disp:
             nxt, self._pools = self._decode_fn(
-                self._pvals, self._pools, tokens, positions, tables, wm,
-                kd, rng_steps, temp, top_k, top_p, do_sample)
+                self._pvals, self._pools, self._prev, tokens, positions,
+                tables, wm, kd, rng_steps, temp, top_k, top_p, do_sample)
+        with self._lock:
+            self._stats["decode_steps_overlapped"] += overlapped
+        self._read_inflight()         # step n, which ran meanwhile
+        self._prev = nxt
+        self._inflight = _Unread(rows, disp.t0, bool(do_sample.any()))
+
+    def _read_inflight(self):
+        """Fetch the tokens of the decode step in flight, if there is
+        one, and emit them.  A row whose sequence ended by ``eos`` in
+        the step before rode in this one all the same: its token is
+        dropped.  That step fed the sequence's real last token at its
+        real position into a block the sequence still owned when the
+        step was dispatched, and every later program (the prefill of
+        the slot's and the blocks' next owner among them) follows it
+        through the donated pools: whatever reuses the block finds
+        what the synchronous loop left there plus that token's K/V,
+        and a slot's recurrent state is reset by its next prefill."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return
+        tl = self._tl
         with tl.phase("decode.fetch") as fetch:
-            nxt = np.asarray(nxt)
-        # decode_ms keeps its meaning (dispatch through fetch), read
-        # off the two spans' clocks
-        dt_ms = (fetch.t1 - disp.t0) * 1e3
+            nxt = np.asarray(self._prev)
+        # a step's decode_ms runs to the end of its fetch from its
+        # dispatch, or from the end of the fetch before it (the step's
+        # before it, a prefill's) where that is later: decode_ms and
+        # prefill_ms count no instant twice
+        dt_ms = (fetch.t1 - max(step.t0, self._timed_until)) * 1e3
+        self._timed_until = fetch.t1
         with tl.phase("decode.emit"):
             replays = 0
             every = _trace.trace_every()
-            for seq in live:
+            for seq in step.rows:
                 s = seq.slot
+                if s is None:
+                    continue                 # ended in the step before
                 seq.decoded += 1
+                seq.unread -= 1
                 if seq.rt is not None and seq.decoded % every == 0:
                     # sampled per-request decode span (PADDLE_TRACE_EVERY)
                     seq.rt.span_at("decode", dt_ms, step=seq.decoded)
@@ -1637,12 +1777,12 @@ class GenerationServer:
                 else:
                     self._emit(seq, int(nxt[s]))
             if self._step_counters:
+                B = self._num_slots
                 with self._lock:
                     for i, name in enumerate(self._step_counters):
                         self._stats[name] = self._stats.get(name, 0) \
                             + int(nxt[B + i])
-            self._after_step(len(live), replays, dt_ms,
-                             bool(do_sample.any()))
+            self._after_step(len(step.rows), replays, dt_ms, step.sampled)
 
     def _after_step(self, n_live: int, replays: int, dt_ms: float,
                     sampled: bool):

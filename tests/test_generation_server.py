@@ -433,3 +433,281 @@ def test_eviction_deadline_epoch_is_submit_time(lm):
             s.result(timeout=60)
     finally:
         srv.stop()
+
+
+# -- ISSUE 32: the decode loop is a pipeline of depth one --------------
+# Step n+1 is dispatched before step n is read; each row's last token
+# stays on the device.  The reference below reads every step at once,
+# as the loop did before: the streams have to be the same token for
+# token whatever ends, joins, is evicted or cancelled meanwhile.
+
+class _ReadAtOnce(GenerationServer):
+    """The synchronous reference: every decode step is read before
+    anything else happens."""
+
+    def _decode_once(self):
+        super()._decode_once()
+        self._read_inflight()
+
+
+class _TalliedLM(LlamaForCausalLM):
+    """What the server holds for a model with per-slot state and step
+    counters, at toy size: beside the K/V pools each slot's tally of
+    the token ids it was fed since its prefill began, which picks the
+    next token (tally mod vocabulary) — a step fed twice or not at
+    all, a token fed at the wrong row, or a slot that kept its last
+    owner's tally changes the stream — and two counters a decode step
+    returns behind its tokens: 1, and its live rows."""
+
+    def has_recurrent_state(self):
+        return True
+
+    def step_counters(self):
+        return ("toy_steps", "toy_rows")
+
+    def init_paged_cache(self, num_blocks, block_size, num_slots):
+        import jax.numpy as jnp
+        return super().init_paged_cache(num_blocks, block_size) + [
+            {"state": jnp.zeros((num_slots,), jnp.int32)}]
+
+    def forward_paged(self, input_ids, positions, pools, block_tables,
+                      write_mask, gather_at=None, verify_mode=False,
+                      slots=None):
+        import jax
+        import jax.numpy as jnp
+        *kv, st = pools
+        logits, kv = super().forward_paged(
+            input_ids, positions, kv, block_tables, write_mask,
+            gather_at=gather_at, verify_mode=verify_mode)
+        ids, pos = input_ids._value, positions._value
+        rows = jnp.arange(ids.shape[0]) if slots is None else slots
+        live = write_mask.any(-1)
+        # a prefill that starts a sequence starts from zero
+        old = jnp.where(pos[:, 0] == 0, 0, st["state"].at[rows].get(
+            mode="fill", fill_value=0))
+        tally = old + jnp.where(write_mask, ids, 0).sum(-1)
+        n = st["state"].shape[0]
+        state = st["state"].at[jnp.where(live, rows, n)].set(
+            tally, mode="drop")
+        lv = logits._value
+        V = lv.shape[-1]
+        lv = lv + 100.0 * jax.nn.one_hot(1 + tally % (V - 1), V,
+                                         dtype=lv.dtype)[:, None, :]
+        counts = jnp.stack([jnp.int32(1), live.sum().astype(jnp.int32)])
+        return lv, kv + [{"state": state}], counts
+
+
+@pytest.fixture(scope="module")
+def tallied(lm):
+    paddle.seed(0)
+    m = _TalliedLM(lm.config)
+    m.eval()
+    return m
+
+
+def _first_seen_at(stream, lo, hi, skip=()):
+    """An index in [lo, hi) whose token the stream had not shown
+    before, or None."""
+    for k in range(lo, min(hi, len(stream))):
+        if stream[k] not in stream[:k] and k not in skip:
+            return k
+    return None
+
+
+def _case_eos_reuse(cls, lm, tallied):
+    """More requests than slots, a pool that holds two sequences and no
+    more, and an ``eos`` that ends rows while the next step, in which
+    they ride, is in flight: slot and blocks go to a waiting request at
+    once, behind that step."""
+    model = tallied       # its tokens vary, so an ``eos`` is first seen
+    prompts = _prompts(seed=21, lens=(6, 5, 7, 6, 5, 7))
+    kw = dict(num_slots=2, block_size=4, max_model_len=24, num_blocks=13,
+              max_prefill_batch=1, check_replay=True,
+              request_timeout_s=120.0)
+    with _ReadAtOnce(model, **kw) as probe:
+        free = [probe.submit(p, max_new_tokens=12).result(timeout=120)
+                for p in prompts]
+    ends = [_first_seen_at(s, 2, 9) for s in free]
+    assert sum(k is not None for k in ends) >= 4
+    with cls(model, **kw) as srv:
+        streams = [srv.submit(p, max_new_tokens=12,
+                              eos_token_id=None if k is None else s[k])
+                   for p, s, k in zip(prompts, free, ends)]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    for out, s, k, stream in zip(outs, free, ends, streams):
+        assert out == (s if k is None else s[:k + 1])
+        assert stream.finish_reason == ("length" if k is None else "eos")
+    assert st["free_blocks"] == st["total_blocks"] and st["evicted"] == 0
+    assert st["toy_steps"] == st["decode_steps"]
+    return outs, st
+
+
+def _case_evict_replay(cls, lm, tallied):
+    """13 allocatable blocks for 4 sequences that each grow to 6: a row
+    is evicted while its token of the step in flight is unread, then
+    re-admitted and replayed under ``check_replay``."""
+    with cls(lm, num_slots=4, block_size=4, max_model_len=24,
+             num_blocks=14, check_replay=True,
+             request_timeout_s=120.0) as srv:
+        outs = _run_scarce(srv, do_sample=True, concurrent=True)
+    st = srv.stats()   # after stop(): the last step is read
+    assert st["evicted"] > 0 and st["replay_steps"] > 0
+    assert st["free_blocks"] == st["total_blocks"]
+    return outs, st
+
+
+def _case_sampled_beside_greedy(cls, lm, tallied):
+    prompts = _prompts(seed=23, lens=(5, 9, 3, 12, 7, 4))
+    with cls(lm, num_slots=4, block_size=4, max_model_len=32,
+             check_replay=True, request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=6 + 2 * i, seed=50 + i,
+                              **kw)
+                   for i, (p, kw) in enumerate(zip(prompts, [
+                       {}, dict(do_sample=True, temperature=0.8),
+                       dict(do_sample=True, temperature=1.3, top_k=8),
+                       {}, dict(do_sample=True, top_p=0.7),
+                       dict(do_sample=True, temperature=0.9, top_k=12,
+                            top_p=0.8)]))]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    assert [len(o) for o in outs] == [6, 8, 10, 12, 14, 16]
+    assert 0 < st["sampled_steps"] <= st["decode_steps"] \
+        + st["prefill_batches"]
+    return outs, st
+
+
+def _case_state_and_counters(cls, lm, tallied):
+    """Per-slot state and step counters: the tally picks every token,
+    slots are reused, and the counters add up to one a step."""
+    prompts = _prompts(seed=24, lens=(5, 9, 3, 12, 7, 4, 6))
+    with cls(tallied, num_slots=3, block_size=4, max_model_len=32,
+             check_replay=True, request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=4 + 2 * i)
+                   for i, p in enumerate(prompts)]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    for p, out in zip(prompts, outs):        # the tally, by hand
+        fed = [int(p.sum())]
+        for t in out[:-1]:
+            fed.append(fed[-1] + t)
+        assert out == [1 + f % 63 for f in fed]
+    assert st["state_slots"] == 3 and st["state_resets"] == 7
+    assert st["toy_steps"] == st["decode_steps"] > 0
+    # every token but a request's first came from a decode step's row
+    assert st["toy_rows"] == sum(len(o) - 1 for o in outs)
+    return outs, st
+
+
+def _case_prefix_cache(cls, lm, tallied):
+    """Prefix sharing on: a second turn's prompt is a first turn's
+    prompt and answer, so it aliases blocks indexed when that turn
+    ended by ``eos`` with a step in flight.  (Not an answer whose last
+    token completes a block: no step of the synchronous loop feeds a
+    sequence's last token, yet ``_finish`` indexes the block, and the
+    next turn attends to K/V that was never written — ROADMAP D22.
+    The step in flight does feed it, so there the pipeline agrees
+    with a cold server and the reference does not.)"""
+    first = _prompts(seed=25, lens=(7, 6, 9))
+    kw = dict(num_slots=2, block_size=4, max_model_len=48,
+              prefix_cache=True, check_replay=True,
+              request_timeout_s=120.0)
+    with _ReadAtOnce(lm, **kw) as probe:
+        free = [probe.submit(p, max_new_tokens=10).result(timeout=120)
+                for p in first]
+    ends = [_first_seen_at(s, 1, 9, skip=[k for k in range(9)
+                                          if (len(p) + k + 1) % 4 == 0])
+            for p, s in zip(first, free)]
+    assert any(k is not None for k in ends)
+    with cls(lm, **kw) as srv:
+        turn1 = [srv.submit(p, max_new_tokens=10,
+                            eos_token_id=None if k is None else s[k])
+                 for p, s, k in zip(first, free, ends)]
+        outs = [s.result(timeout=120) for s in turn1]
+        turn2 = [srv.submit(np.concatenate(
+            [p, np.asarray(o, np.int32), np.asarray([5, 9], np.int32)]),
+            max_new_tokens=6) for p, o in zip(first, outs)]
+        outs += [s.result(timeout=120) for s in turn2]
+    st = srv.stats()   # after stop(): the last step is read
+    assert st["prefix_hits"] >= 3 and st["prefix_hit_tokens"] >= 3 * 8
+    return outs, st
+
+
+def _case_cancel(cls, lm, tallied):
+    """``cancel()`` with a step in flight: the cancelled stream keeps a
+    prefix of what it would have been, its slot goes to the next
+    request, the others never notice."""
+    prompts = _prompts(seed=26, lens=(5, 8, 6, 7))
+    with cls(lm, num_slots=3, block_size=4, max_model_len=40,
+             check_replay=True, request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=30) for p in prompts[:3]]
+        it = iter(streams[0])
+        head = [next(it) for _ in range(3)]
+        assert srv.cancel(streams[0].request_id)
+        late = srv.submit(prompts[3], max_new_tokens=8)
+        outs = [s.result(timeout=120) for s in streams[1:] + [late]]
+    st = srv.stats()   # after stop(): the last step is read
+    got = streams[0].tokens
+    assert streams[0].finish_reason == "cancelled" and got[:3] == head
+    assert st["cancelled"] == 1 and st["finished"] == 3
+    assert st["free_blocks"] == st["total_blocks"]
+    return [got[:3]] + outs, st, got
+
+
+def _case_drain(cls, lm, tallied):
+    """``stop(drain=True)`` with steps in flight and requests waiting:
+    every stream runs to its end."""
+    prompts = _prompts(seed=27, lens=(5, 8, 6, 7, 4))
+    srv = cls(lm, num_slots=2, block_size=4, max_model_len=32,
+              check_replay=True, request_timeout_s=120.0).start()
+    try:
+        streams = [srv.submit(p, max_new_tokens=9 + i)
+                   for i, p in enumerate(prompts)]
+        next(iter(streams[0]))
+        srv.stop(drain=True, timeout=120)
+        outs = [s.result(timeout=5) for s in streams]
+    finally:
+        srv.stop()
+    assert [s.finish_reason for s in streams] == ["length"] * 5
+    return outs, srv.stats()
+
+
+@pytest.mark.parametrize("case", [
+    _case_eos_reuse, _case_evict_replay, _case_sampled_beside_greedy,
+    _case_state_and_counters, _case_prefix_cache, _case_cancel,
+    _case_drain], ids=lambda f: f.__name__[6:])
+def test_pipelined_streams_equal_the_synchronous_reference(
+        case, lm, tallied):
+    want, ref, *cut_ref = case(_ReadAtOnce, lm, tallied)
+    got, st, *cut = case(GenerationServer, lm, tallied)
+    assert got == want
+    assert ref["decode_steps_overlapped"] == 0 < ref["decode_steps"]
+    assert 0 < st["decode_steps_overlapped"] < st["decode_steps"]
+    assert st["traffic_compiles"] == ref["traffic_compiles"] == 0
+    if cut:       # a cancelled stream: as far as both got, the same
+        n = min(len(cut[0]), len(cut_ref[0]))
+        assert n >= 3 and cut[0][:n] == cut_ref[0][:n]
+    else:
+        assert st["tokens_generated"] == ref["tokens_generated"]
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_overlap_counter_says_whether_the_pipeline_engaged(lm, spec):
+    """A decode-only run overlaps every step but the first; a server
+    in speculative mode reads every verify step at once.  Neither
+    compiles a program the loop before it did not."""
+    kw = dict(draft_model=lm, spec_k=3) if spec else {}
+    with GenerationServer(lm, num_slots=4, block_size=4, max_model_len=48,
+                          request_timeout_s=120.0, **kw) as srv:
+        n = srv.num_compiles()
+        streams = [srv.submit(p, max_new_tokens=30)
+                   for p in _prompts(seed=28, lens=(5, 9, 3, 12))]
+        assert all(len(s.result(timeout=120)) == 30 for s in streams)
+    st = srv.stats()   # after stop(): the last step is read
+    assert srv.num_compiles() == n and st["traffic_compiles"] == 0
+    if spec:
+        assert st["decode_steps_overlapped"] == 0 < st["decode_steps"]
+    else:
+        # 29 steps a request; one that was admitted alone starts early
+        assert 29 <= st["decode_steps"] <= 29 + 3
+        assert st["decode_steps_overlapped"] >= 0.9 * st["decode_steps"]

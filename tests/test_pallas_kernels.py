@@ -809,7 +809,7 @@ def test_generation_server_decode_program_routes(monkeypatch):
         kreg.reset_dispatch_counts()
         B, W = 2, int(np.asarray(srv._seq_key_data(0)).shape[-1])
         jax.eval_shape(
-            srv._decode_fn, srv._pvals, srv._pools,
+            srv._decode_fn, srv._pvals, srv._pools, srv._prev,
             np.zeros((B, 1), np.int32), np.zeros((B, 1), np.int32),
             np.zeros((B, srv._M), np.int32), np.zeros((B, 1), bool),
             np.zeros((B, W), np.uint32), np.zeros((B,), np.int32),

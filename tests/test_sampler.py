@@ -192,10 +192,13 @@ def _program_args(srv, which):
                 z((pb,), bool))
     s = (Bs, S) if which == "verify" else (Bs, 1)
     steps = s if which == "verify" else (Bs,)
-    return (z(s, np.int32), z(s, np.int32), z((Bs, M), np.int32),
-            z(s, bool), z((Bs, 2), np.uint32), z(steps, np.int32),
-            np.ones((Bs,), np.float32), z((Bs,), np.int32),
-            np.ones((Bs,), np.float32), z((Bs,), bool))
+    # the target's decode program takes the step before's result first
+    prev = (srv._prev,) if which == "decode" else ()
+    return prev + (
+        z(s, np.int32), z(s, np.int32), z((Bs, M), np.int32),
+        z(s, bool), z((Bs, 2), np.uint32), z(steps, np.int32),
+        np.ones((Bs,), np.float32), z((Bs,), np.int32),
+        np.ones((Bs,), np.float32), z((Bs,), bool))
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill", "verify",
@@ -209,9 +212,7 @@ def test_each_program_holds_one_sort_and_where(lm, which):
         "verify": (srv._verify_fn, srv._pvals, srv._pools),
         "draft_decode": (srv._draft_decode_fn, srv._dvals, srv._dpools),
     }[which]
-    args = _program_args(srv, "decode" if which == "draft_decode"
-                         else which)
-    traced = fn.trace(vals, pools, *args)
+    traced = fn.trace(vals, pools, *_program_args(srv, which))
     assert _sorts(traced.jaxpr.jaxpr) == [True]    # one, in a branch
     assert traced.lower().as_text().count("stablehlo.sort") == 1
 
@@ -232,7 +233,8 @@ def test_no_cond_behind_a_model_that_loops_on_the_device(lm, monkeypatch):
     # (slots, 1), (slots, spec_k + 1) and (rows, bucket) tokens
     for which, fn, vals, pools, tokens in [
             ("decode", srv._decode_fn, srv._pvals, srv._pools, 4),
-            ("decode", srv._draft_decode_fn, srv._dvals, srv._dpools, 4),
+            ("draft_decode", srv._draft_decode_fn, srv._dvals,
+             srv._dpools, 4),
             ("verify", srv._verify_fn, srv._pvals, srv._pools, 4 * 4),
             ("prefill", srv._prefill_fn, srv._pvals, srv._pools, 2 * 48)]:
         traced = fn.trace(vals, pools, *_program_args(srv, which))

@@ -105,7 +105,7 @@ def test_every_span_of_the_plain_loop_is_in_the_ring(session):
     assert all(r.step is None for r in _named(rows, "serve.idle"))
     # only the rows a reader needs carry counts
     assert {r.name for r in rows if r.args} == \
-        {"serve.admit", "serve.prefill.stage"}
+        {"serve.admit", "serve.prefill.stage", "serve.decode.dispatch"}
 
 
 def test_spec_loop_is_one_span_and_leaves_no_hole(lm):
@@ -130,13 +130,21 @@ def test_phases_lie_inside_their_step_and_never_overlap(session):
     phases.sort(key=lambda r: r.t_start)
     for a, b in zip(phases, phases[1:]):
         assert a.t_end <= b.t_start, (a, b)
-    # within a decode step the phases come in the order of the work
+    # within a decode step the phases come in the order of the work:
+    # the step is dispatched, then the one before it is read
     order = ["serve.admit", "serve.decode.grow", "serve.decode.stage",
              "serve.decode.dispatch", "serve.decode.fetch",
              "serve.decode.emit"]
-    last = max(r.step for r in _named(rows, "serve.decode.emit"))
+    last = max(r.step for r in _named(rows, "serve.decode.dispatch")
+               if r.args["overlapped"])
     got = [r.name for r in phases if r.step == last]
     assert [n for n in got if n in order] == order
+    # a step dispatched with nothing in flight is left unread: the
+    # iteration that dispatches the next one (or finds none to
+    # dispatch) reads it
+    first = min(r.step for r in _named(rows, "serve.decode.dispatch"))
+    assert not [r for r in phases if r.step == first
+                and r.name in ("serve.decode.fetch", "serve.decode.emit")]
 
 
 def test_counts_at_the_span_boundaries_agree_with_stats(session):
@@ -158,14 +166,51 @@ def test_counts_at_the_span_boundaries_agree_with_stats(session):
 
 
 def test_decode_ms_and_prefill_ms_are_read_off_the_spans(session):
+    """The fetch of a decode step comes one iteration after its
+    dispatch, behind the next step's dispatch and whatever prefill lay
+    between: ``decode_ms`` takes a step from its dispatch, or from the
+    end of the fetch before it where that is later, to the end of its
+    own fetch, so that ``decode_ms`` + ``prefill_ms`` count no instant
+    twice and stay inside the loop's wall time."""
     rows, st = session
-    for kind, counter in (("decode", "decode_ms"),
-                          ("prefill", "prefill_ms")):
-        disp = _named(rows, f"serve.{kind}.dispatch")
-        fetch = _named(rows, f"serve.{kind}.fetch")
-        assert len(disp) == len(fetch)
-        total = sum(f.t_end - d.t_start for d, f in zip(disp, fetch)) * 1e3
-        assert st[counter] == pytest.approx(total, rel=1e-9)
+    marks = sorted((r for r in rows if r.name in (
+        "serve.decode.dispatch", "serve.decode.fetch",
+        "serve.prefill.dispatch", "serve.prefill.fetch")),
+        key=lambda r: r.t_start)
+    total = {"decode": 0.0, "prefill": 0.0}
+    unread = {"decode": [], "prefill": []}
+    until = 0.0
+    for r in marks:
+        _, kind, what = r.name.split(".")
+        if what == "dispatch":
+            unread[kind].append(r.t_start)
+        else:                 # results are read in the order dispatched
+            total[kind] += r.t_end - max(unread[kind].pop(0), until)
+            until = r.t_end
+    assert unread == {"decode": [], "prefill": []}
+    assert st["decode_ms"] == pytest.approx(total["decode"] * 1e3, rel=1e-9)
+    assert st["prefill_ms"] == pytest.approx(total["prefill"] * 1e3,
+                                             rel=1e-9)
+    steps = _named(rows, "serve")
+    wall_ms = (max(r.t_end for r in steps)
+               - min(r.t_start for r in steps)) * 1e3
+    assert 0 < st["decode_ms"] + st["prefill_ms"] <= wall_ms
+
+
+def test_overlapped_is_an_arg_of_every_decode_dispatch(session):
+    rows, st = session
+    disp = _named(rows, "serve.decode.dispatch")
+    assert len(disp) == st["decode_steps"] > 0
+    assert all(set(r.args) == {"overlapped"} and r.args["overlapped"]
+               in (0, 1) for r in disp)
+    assert sum(r.args["overlapped"] for r in disp) == \
+        st["decode_steps_overlapped"] > 0
+    # an overlapped step's dispatch lies between the dispatch and the
+    # fetch of the step before it
+    fetch = _named(rows, "serve.decode.fetch")
+    for before, d, f in zip(disp, disp[1:], fetch):
+        if d.args["overlapped"]:
+            assert before.t_end <= d.t_start and d.t_end <= f.t_start
 
 
 def test_a_readmission_waits_once_more(lm):

@@ -96,7 +96,8 @@ for cellname in CELLS:
     for w in ["decode", f"{bk[0]}x1", f"{bk[-1]}x{sv['max_prefill_batch']}"]:
         t = time.time()
         if w == "decode":
-            args = (S((B, 1), jnp.int32), S((B, 1), jnp.int32), S((B, Mx), jnp.int32), S((B, 1), jnp.bool_), S((B, W), jnp.uint32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.bool_))
+            # the step before's result (tokens, then the model's step counters) comes first
+            args = (S(srv._prev.shape, jnp.int32), S((B, 1), jnp.int32), S((B, 1), jnp.int32), S((B, Mx), jnp.int32), S((B, 1), jnp.bool_), S((B, W), jnp.uint32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.bool_))
             low = srv._decode_fn.lower(pv, pools, *args)
         else:
             b, pb = map(int, w.split("x"))
