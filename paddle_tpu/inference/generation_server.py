@@ -542,6 +542,8 @@ class GenerationServer:
             "shed_draining": 0, "migrated_in": 0, "migrated_out": 0,
             "cancelled": 0, "moe_picks_here": 0, "moe_max_expert_load": 0,
             "prefill_bucket_hits": {b: 0 for b in self._buckets},
+            # whatever else the model's decode program counts
+            **{name: 0 for name in self._step_counters},
         }
 
         # device state: params + pools + compiled step fns (lazy so the

@@ -1,7 +1,9 @@
 """What ``GenerationServer`` does for a model whose cache is not only
 keys and values: a model with ``has_recurrent_state()`` (KDA layers:
 a recurrent matrix and convolution tails per SLOT, fixed in size
-whatever the context) beside block-paged pools.
+whatever the context; LFM2's gated short convolutions: tails alone)
+beside block-paged pools (latent pages, or plain K/V pages in the
+layers that attend).
 
 - The per-slot state lives in the same ``pools`` list as the paged
   pools (``model.init_paged_cache(num_blocks, block, num_slots)``), is
@@ -70,9 +72,11 @@ def refuse_migration(server):
 
 
 def pool_bytes(pools) -> dict:
-    """Bytes of the per-slot state and of the latent pages in
-    ``pools``, and the number of state slots."""
-    out = {"state_slots": 0, "state_bytes": 0, "latent_pool_bytes": 0}
+    """Bytes of the per-slot state, of the latent pages and of the K/V
+    pages (scales included) in ``pools``, and the number of state
+    slots."""
+    out = {"state_slots": 0, "state_bytes": 0, "latent_pool_bytes": 0,
+           "kv_pool_bytes": 0}
     for d in pools:
         for k, v in d.items():
             if k in ("state", "conv"):
@@ -80,4 +84,6 @@ def pool_bytes(pools) -> dict:
                 out["state_slots"] = int(v.shape[0])
             elif k == "latent":
                 out["latent_pool_bytes"] += int(v.nbytes)
+            elif k in ("k", "v", "k_scale", "v_scale"):
+                out["kv_pool_bytes"] += int(v.nbytes)
     return out

@@ -39,7 +39,8 @@ from ...framework.core import Tensor, _apply
 from ..initializer import Normal, XavierNormal
 from .layers import Layer, Parameter
 
-__all__ = ["MoELayer", "DroplessMoELayer", "dropless_moe"]
+__all__ = ["MoELayer", "DroplessMoELayer", "dropless_moe",
+           "masked_pass_pays"]
 
 
 def _mark_ep(param, spec):
@@ -195,13 +196,27 @@ class MoELayer(Layer):
 # expert's weights: each expert's group is padded to whole blocks, so a
 # block never straddles two experts and a step is three plain matmuls
 _GROUP_BLOCK = 256
-# under this many tokens every held expert multiplies every token and
-# the result is masked by the combine weights: at a decode step's 128
-# rows that costs what reading the weights costs, and XLA's grouped
-# product took twice as long (v5e, PERF.md PR 27: 1.96 against 3.91 ms
-# a layer of 64 experts 2304 x 1024).  Under the smallest prefill
-# shape a server is given (256 x 1): a prefill never takes it.
-_DENSE_BELOW = 256
+# FLOPs a byte of weights at which the MXU and the HBM of the chip this
+# layer is served on take equal time (TPU v5e: 197 TFLOP/s over
+# 819 GB/s).  A bf16 expert multiplies one row with one FLOP a byte it
+# streams, so T rows take T / _RIDGE of the time its weights take.
+_RIDGE = 197e12 / 819e9
+
+
+def masked_pass_pays(n_tokens: int, top_k: int, num_experts: int) -> bool:
+    """Whether ``n_tokens`` tokens take the masked dense pass: every
+    held expert multiplies every token, and ``1 - top_k / num_experts``
+    of those row-products are in vain.  While the MXU time of the rows
+    multiplied in vain hides under the time the expert's weights take
+    to stream, the pass costs what ANY form costs (the weights read
+    once, the chosen rows multiplied) and no dropless form can win;
+    past that the sorted/grouped dispatch multiplies fewer rows.  The
+    line follows the router's shape and the chip, not a model: 128 rows
+    of 8-of-256 experts take the pass and 256 rows do not (124 and 248
+    rows in vain against a ridge of 240.5; PERF.md PR 27 measured 1.96
+    ms against the grouped product's 3.91 at 128), 256 rows of 4-of-32
+    do (224 in vain; PR 33)."""
+    return n_tokens * (1.0 - top_k / num_experts) <= _RIDGE
 
 
 def _swiglu(x, wg, wu, wd):
@@ -211,16 +226,17 @@ def _swiglu(x, wg, wu, wd):
                    preferred_element_type=jnp.float32)
 
 
-def _route(x, router_w, router_b, top_k, scale, held):
+def _route(x, router_w, router_b, top_k, scale, held, norm_eps=1e-20):
     """Sigmoid router in float32 over ALL experts: (local [T, k] the
     picks' index among the held experts, w [T, k] their combine
-    weights, here [T, k] whether the pick is held here)."""
+    weights, here [T, k] whether the pick is held here).  ``norm_eps``
+    is the constant the model adds to the chosen scores' sum."""
     first, count = held
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                router_w.astype(jnp.float32)))
     _, idx = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    w = w / (w.sum(-1, keepdims=True) + norm_eps) * scale
     local = (idx - first).astype(jnp.int32)
     return local, w, (local >= 0) & (local < count)
 
@@ -228,7 +244,8 @@ def _route(x, router_w, router_b, top_k, scale, held):
 def _experts_masked(x, local, w, wg, wu, wd):
     """Every held expert over every token, the result weighed by the
     combine weights (nought where the token did not choose the
-    expert): for few tokens."""
+    expert): for few tokens.  Returns ``(y, picks here, the largest
+    load, row-products done)``."""
     count = wg.shape[0]
     onehot = jax.nn.one_hot(local, count, dtype=jnp.float32)   # [T,k,E]
     wt = (onehot * w[..., None]).sum(1)                        # [T, E]
@@ -239,7 +256,8 @@ def _experts_masked(x, local, w, wg, wu, wd):
     out = jnp.einsum("etf,efd->etd", h.astype(x.dtype), wd,
                      preferred_element_type=jnp.float32)
     load = onehot.sum((0, 1)).astype(jnp.int32)
-    return jnp.einsum("etd,te->td", out, wt), load.sum(), load.max()
+    return (jnp.einsum("etd,te->td", out, wt), load.sum(), load.max(),
+            x.shape[0] * count)
 
 
 def _experts_grouped(x, local, w, here, wg, wu, wd):
@@ -255,7 +273,8 @@ def _experts_grouped(x, local, w, here, wg, wu, wd):
     count is computed on the device: with ``lax.ragged_dot`` (on TPU a
     megablox-style kernel of XLA's own) or the megablox kernel here,
     prefill traffic stopped a v5e; with this form the same traffic ran
-    clean (PERF.md, PR 27).
+    clean (PERF.md, PR 27).  Returns ``(y, picks here, the largest
+    load, (blocks in use, rows a block))``.
     """
     (T, d), top_k, count = x.shape, local.shape[1], wg.shape[0]
     P_ = T * top_k
@@ -290,8 +309,9 @@ def _experts_grouped(x, local, w, here, wg, wu, wd):
             return _swiglu(x[tb], ds(wg), ds(wu), ds(wd)).astype(x.dtype)
         return jax.lax.cond(used, run,
                             lambda: jnp.zeros((blk, d), x.dtype))
-    out = jax.lax.map(one_block,
-                      (tok, b_exp, jnp.arange(nb, dtype=i32) < b_end[-1]))
+    steps = jnp.arange(nb, dtype=i32)
+    n_used = b_end[-1]                                   # blocks in use
+    out = jax.lax.map(one_block, (tok, b_exp, steps < n_used))
     # where each pick sits in the sorted order, then in the blocks
     inv = jnp.zeros((P_,), i32).at[order].set(jnp.arange(P_, dtype=i32))
     e_of = jnp.minimum(eid, count - 1)
@@ -300,33 +320,45 @@ def _experts_grouped(x, local, w, here, wg, wu, wd):
     picked = out.reshape(nb * blk, d)[jnp.clip(at, 0, nb * blk - 1)]
     picked = picked.reshape(T, top_k, d).astype(jnp.float32)
     y = jnp.where(here[..., None], picked * w[..., None], 0.0).sum(1)
-    return y, n_here, sizes.max()
+    return y, n_here, sizes.max(), (n_used, blk)
 
 
 def dropless_moe(x, router_w, router_b, wg, wu, wd, *, top_k: int,
-                 scale: float, held):
+                 scale: float, held, norm_eps: float = 1e-20,
+                 count_rows: bool = False):
     """The held experts' part of a sigmoid-routed expert layer.
 
     ``x`` [T, d]; ``router_w`` [d, E] and ``router_b`` [E] over ALL E
     experts of the layer; ``wg``/``wu`` [count, d, f] and ``wd``
     [count, f, d] of the experts ``held = (first, count)``.  Router in
     float32: scores ``sigmoid(x W_r)``, the ``top_k`` chosen by score +
-    bias, weights the chosen scores over their sum, times ``scale``.
+    bias, weights the chosen scores over their sum + ``norm_eps``,
+    times ``scale``.
 
     No token is dropped, no weight is gathered per pick, and what the
-    absent experts would have added is left out.  ``_DENSE_BELOW``
-    tokens or more (prefill) take the sorted/grouped dispatch, in
-    which no expert multiplies a token that did not choose it (but for
-    the padding of each group to whole blocks); fewer
-    (a decode step) take the masked dense pass, the same sum, which
-    costs what reading the weights costs.
+    absent experts would have added is left out.  Few tokens (a decode
+    step; :func:`masked_pass_pays` draws the line) take the masked
+    dense pass, which costs what reading the weights costs; more take
+    the sorted/grouped dispatch, the same sum, in which no expert
+    multiplies a token that did not choose it (but for the padding of
+    each group to whole blocks).
 
-    Returns ``(y [T, d] float32, picks_here, max_expert_load)``.
+    Returns ``(y [T, d] float32, picks_here, max_expert_load)`` and,
+    with ``count_rows``, a fourth value: the row-products done (rows x
+    the experts each was multiplied by).
     """
-    local, w, here = _route(x, router_w, router_b, top_k, scale, held)
-    if x.shape[0] < _DENSE_BELOW:
-        return _experts_masked(x, local, w, wg, wu, wd)
-    return _experts_grouped(x, local, w, here, wg, wu, wd)
+    # (six positional arguments: perfbench/tools/kimi_gap_study.py wraps
+    # ``_route`` by them to record a model's picks)
+    other_eps = {} if norm_eps == 1e-20 else {"norm_eps": norm_eps}
+    local, w, here = _route(x, router_w, router_b, top_k, scale, held,
+                            **other_eps)
+    if masked_pass_pays(x.shape[0], top_k, router_w.shape[1]):
+        *out, rows = _experts_masked(x, local, w, wg, wu, wd)
+    else:
+        *out, (n_used, blk) = _experts_grouped(x, local, w, here, wg, wu,
+                                               wd)
+        rows = n_used * blk if count_rows else None
+    return (*out, rows) if count_rows else tuple(out)
 
 
 class DroplessMoELayer(Layer):
@@ -353,7 +385,8 @@ class DroplessMoELayer(Layer):
                  top_k: int = 8, held_experts=None,
                  shared_hidden: Optional[int] = None,
                  routed_scaling_factor: float = 1.0,
-                 initializer_range: float = 0.02):
+                 initializer_range: float = 0.02,
+                 norm_eps: float = 1e-20):
         super().__init__()
         first, count = held_experts or (0, num_experts)
         if not (0 <= first and count >= 1
@@ -366,6 +399,7 @@ class DroplessMoELayer(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.held_experts = (int(first), int(count))
         self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_eps = float(norm_eps)
         init = Normal(0.0, initializer_range)
 
         def mk(*shape):
@@ -382,28 +416,29 @@ class DroplessMoELayer(Layer):
             self.shared_down = mk(shared_hidden, d_model)
         self.last_counts = None
 
-    def apply_values(self, xv):
+    def apply_values(self, xv, count_rows: bool = False):
         """``xv`` [..., d] (a raw array) -> (y like xv, picks_here,
-        max_expert_load): what :meth:`forward` computes, for callers
-        that are themselves inside a traced program."""
+        max_expert_load[, row-products]): what :meth:`forward`
+        computes, for callers that are themselves inside a traced
+        program."""
         tok = xv.reshape(-1, self.d_model)
-        y, n_here, load = dropless_moe(
+        y, *counts = dropless_moe(
             tok, self.router._value, self.router_bias._value,
             self.gate_w._value, self.up_w._value, self.down_w._value,
             top_k=self.top_k, scale=self.routed_scaling_factor,
-            held=self.held_experts)
+            held=self.held_experts, norm_eps=self.norm_eps,
+            count_rows=count_rows)
         if self.shared:
             y = y + _swiglu(tok, self.shared_gate._value,
                             self.shared_up._value,
                             self.shared_down._value)
-        return y.astype(xv.dtype).reshape(xv.shape), n_here, load
+        return (y.astype(xv.dtype).reshape(xv.shape), *counts)
 
-    @staticmethod
-    def loops_on_device(n_tokens: int) -> bool:
+    def loops_on_device(self, n_tokens: int) -> bool:
         """Whether ``n_tokens`` tokens take the grouped dispatch, which
         lowers to a device loop whose steps branch (a ``while`` of
         ``conditional``s)."""
-        return n_tokens >= _DENSE_BELOW
+        return not masked_pass_pays(n_tokens, self.top_k, self.num_experts)
 
     def forward(self, x):
         y, n_here, load = self.apply_values(x._value)

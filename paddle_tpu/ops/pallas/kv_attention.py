@@ -37,6 +37,19 @@ not the query's; a head-major pool ``[nb, KH, bs, D]`` would avoid that
 but turns the write of a token into ``KH`` rows of 256 B, and the
 measured kernel is DMA-bound as it is.
 
+A head narrower than a lane tile (``D`` = 64, PR 33) takes the ``wide``
+page: the pool is ``[nb, bs, KH*D]`` (or ``[nb, bs, KH, D]`` viewed so),
+a page ``(bs, KH*D)`` = (16, 512) bf16, whole lane tiles with every kv
+head's 64 lanes side by side, so HBM pads nothing and a head's row is
+read once.  Rows are tokens alone; each query head is laid into its own
+kv head's lanes of an ``(H, KH*D)`` row, zero elsewhere, and one
+product with the block gives every head its own logits: the same MXU
+work as the narrow page's masked product, an eighth of its logits.
+``p . v`` sums into ``(H, KH*D)`` and each head's own lanes are taken
+at the end.  A model that keeps such a pool allocates it 3-D: XLA lays
+out a ``[.., KH, 64]`` array as it likes, 64 lanes padded or the
+dimensions transposed, and a Mosaic operand would be re-laid each call.
+
 ``int8_paged_attention`` (kernel ``int8_kv_attention``, PR 13) fuses the
 dequant of int8 pools into the gather for decode/verify: grid
 ``(B, G, M)``, M innermost so the running (max, denom, acc) scratch
@@ -114,11 +127,13 @@ def paged_attention_ref(qh, kpool, vpool, kscale, vscale, tbl, pos,
 _PAGES_PER_BLOCK = 16
 
 
-def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale,
+def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale, wide,
                        len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
                        kbuf, vbuf, sem, slot_ref):
     b = pl.program_id(0)
-    rows = bs * kh                    # rows of one page, (t, g) order
+    # rows of one page: (t, g) order, or t alone with the kv heads side
+    # by side in the lanes (``wide``, module doc)
+    rows = bs if wide else bs * kh
     n = P * rows
     h = kh * r
 
@@ -153,10 +168,27 @@ def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale,
     length = len_ref[b]
     nblk = (n_pages(b) + P - 1) // P
     q = q_ref[0]                                       # (H, D)
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
-    own_head = (col % kh) == (row // r)
-    t_loc = col // kh
+    d = q.shape[-1]
+    iota = jax.lax.broadcasted_iota
+    col = iota(jnp.int32, (h, n), 1)
+    if wide:
+        # each query head into its own kv head's lanes of an (H, KH*D)
+        # row, zero elsewhere (module doc); q . (0/1 matrix) moves
+        # values and rounds none
+        w = kh * d
+        own_lanes = (iota(jnp.int32, (h, w), 1) // d
+                     == iota(jnp.int32, (h, w), 0) // r)
+        spread = (iota(jnp.int32, (d, w), 0)
+                  == iota(jnp.int32, (d, w), 1) % d).astype(q.dtype)
+        q = jnp.where(own_lanes, jnp.dot(
+            q, spread, preferred_element_type=jnp.float32),
+            0.0).astype(q.dtype)
+        t_loc = col
+    else:
+        w = d
+        row = iota(jnp.int32, (h, n), 0)
+        own_head = (col % kh) == (row // r)
+        t_loc = col // kh
 
     def body(i, carry):
         m, l, acc = carry
@@ -180,8 +212,9 @@ def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        valid = jnp.logical_and(own_head,
-                                t_loc < length - i * (P * bs))
+        valid = t_loc < length - i * (P * bs)
+        if not wide:
+            valid = jnp.logical_and(own_head, valid)
         s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         # masked slots contribute EXACT zeros, whatever the buffer holds
@@ -203,13 +236,34 @@ def _paged_attn_kernel(P, bs, kh, r, m_tbl, n_rows, scale,
                          preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv
 
-    d = q.shape[-1]
     m, l, acc = jax.lax.fori_loop(
         0, nblk, body,
         (jnp.full((h, 1), _NEG, jnp.float32),
          jnp.zeros((h, 1), jnp.float32),
-         jnp.zeros((h, d), jnp.float32)))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+         jnp.zeros((h, w), jnp.float32)))
+    out = acc / l
+    if wide:
+        # each head's own D lanes of the (H, KH*D) sum, moved to the
+        # front by a 0/1 matrix; for a bf16 result in hi + lo halves,
+        # as p . v above
+        out = jnp.where(own_lanes, out, 0.0)
+        gather = (iota(jnp.int32, (w, d), 0) % d
+                  == iota(jnp.int32, (w, d), 1))
+        if o_ref.dtype == jnp.bfloat16:
+            hi = out.astype(jnp.bfloat16)
+            lo = (out - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            out = jnp.dot(jnp.concatenate([hi, lo], axis=0),
+                          gather.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+            out = out[:h] + out[h:]
+        else:
+            out = jnp.dot(out, gather.astype(jnp.float32),
+                          preferred_element_type=jnp.float32)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _wide_page(kv_heads: int, D: int) -> bool:
+    return D % 128 != 0 and (kv_heads * D) % 128 == 0
 
 
 @functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
@@ -225,11 +279,15 @@ def paged_attention(qh, kpool, vpool, kscale, vscale, tbl, pos,
     if S != 1 or kscale is not None or vscale is not None:
         raise ValueError("paged_attention is the S == 1 kernel for "
                          "unquantized pools")
-    nb, bs, KH, _ = kpool.shape
+    nb, bs = kpool.shape[:2]
+    KH = kv_heads
     R = H // KH
     M = tbl.shape[1]
     P = min(_PAGES_PER_BLOCK, M)
-    rows = bs * KH
+    # a head narrower than a lane tile: the page is read (bs, KH*D),
+    # whole lane tiles (module doc); else (bs*KH, D)
+    wide = _wide_page(KH, D)
+    rows, width = (bs, KH * D) if wide else (bs * KH, D)
     # the step's own K/V are scattered before the attention reads; a
     # length of at least 1 keeps every row's first block in the chain
     # of copies (position 0 of an inactive slot is the trash block's)
@@ -244,15 +302,15 @@ def paged_attention(qh, kpool, vpool, kscale, vscale, tbl, pos,
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, P * rows, D), kpool.dtype),
-            pltpu.VMEM((2, P * rows, D), vpool.dtype),
+            pltpu.VMEM((2, P * rows, width), kpool.dtype),
+            pltpu.VMEM((2, P * rows, width), vpool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, P, bs, KH, R, M, B,
-                          1.0 / math.sqrt(D)),
+                          1.0 / math.sqrt(D), wide),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), qh.dtype),
         # rows run in order: each starts the next one's first copies
@@ -261,7 +319,7 @@ def paged_attention(qh, kpool, vpool, kscale, vscale, tbl, pos,
         interpret=interpret,
         name="paged_attention",
     )(lengths, tbl.astype(jnp.int32).reshape(-1), qh.reshape(B, H, D),
-      kpool.reshape(nb, rows, D), vpool.reshape(nb, rows, D))
+      kpool.reshape(nb, rows, width), vpool.reshape(nb, rows, width))
     return out.reshape(B, 1, KH, R, D)
 
 
@@ -273,11 +331,14 @@ def _paged_eligible(qh, kpool, vpool, kscale, vscale, tbl, pos,
     # calls cannot be partitioned; the reference can)
     from ...distributed import mesh as mesh_mod
     mesh = mesh_mod.get_mesh(create=False)
-    D = qh.shape[-1]
+    D, bs = qh.shape[-1], kpool.shape[1]
+    # (bs*KH, D) pages of whole tiles, or, for a head narrower than a
+    # lane tile, (bs, KH*D) pages of whole tiles
+    tiled = ((D % 128 == 0 and (bs * kv_heads) % 16 == 0)
+             or (_wide_page(kv_heads, D) and bs % 16 == 0))
     return (qh.shape[1] == 1 and kscale is None
             and kpool.dtype == jnp.bfloat16 and qh.dtype == kpool.dtype
-            and D % 128 == 0 and (kpool.shape[1] * kv_heads) % 16 == 0
-            and (mesh is None or mesh.size == 1))
+            and tiled and (mesh is None or mesh.size == 1))
 
 
 def _int8_kv_attn_kernel(bs, sr, d, scale, tbl_ref, qpos_ref, q_ref, k_ref, v_ref,

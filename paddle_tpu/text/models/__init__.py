@@ -5,6 +5,9 @@ from .bert import (  # noqa: F401
 from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM, kimi_linear_tiny,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny,
+)
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, RMSNorm,
     llama_tiny, llama_7b, llama_13b,
@@ -19,6 +22,7 @@ __all__ = [
     "BertPretrainingCriterion", "bert_base", "bert_large", "bert_tiny",
     "ernie_base",
     "KimiLinearConfig", "KimiLinearForCausalLM", "kimi_linear_tiny",
+    "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
     "LlamaConfig", "LlamaForCausalLM", "LlamaModel", "RMSNorm",
     "llama_tiny", "llama_7b", "llama_13b",
     "CrossEntropyCriterion", "TransformerConfig", "TransformerModel",

@@ -155,6 +155,112 @@ def _rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
+def paged_write_attend(qh, kh, vh, pos, wm, kpool, vpool, tbl,
+                       kscale=None, vscale=None, *, kv_kernel, kv_mode,
+                       verify_mode=False):
+    """What every attention layer with block-paged K/V does once its
+    queries and keys are ready (projected, normalised, rotated, as the
+    model has it): write this call's K/V into the pools through the
+    block table, then attend -- a fresh block over itself (flash or
+    XLA), a decode step or a verify call over the pools.  ``qh``
+    [B, S, H, D], ``kh``/``vh`` [B, S, KH, D]; pools
+    ``[num_blocks, block, KH, D]`` (or with the last two dims as one,
+    as a model with a head narrower than a lane tile keeps them);
+    int8 pools come with ``kscale``/``vscale``.  ``kv_kernel`` and
+    ``kv_mode`` are the registry's kernel and its mode, resolved by
+    the caller outside any trace.  Returns ``(o [B, S, H*D], kpool,
+    vpool[, kscale, vscale])``.  :meth:`LlamaAttention.forward_paged`
+    documents the contracts (trash block, verify mode, int8 pools)."""
+    from ...ops.pallas import registry as _kreg
+    B, S, H, D = qh.shape
+    KH = kh.shape[2]
+    bs = kpool.shape[1]
+    quant = kscale is not None
+    # scatter this call's K/V into the pools: physical block =
+    # table[logical block], offset = pos % block_size; masked
+    # writes divert to the trash block (0, 0)
+    blk_log = (pos // bs).astype(jnp.int32)
+    blk_phys = jnp.take_along_axis(tbl, blk_log, axis=1)
+    off = (pos % bs).astype(jnp.int32)
+    blk_phys = jnp.where(wm, blk_phys, 0)
+    off = jnp.where(wm, off, 0)
+    fb = blk_phys.reshape(-1)
+    fo = off.reshape(-1)
+    kfl = kh.reshape(B * S, KH, D)
+    vfl = vh.reshape(B * S, KH, D)
+    page_row = kpool.shape[2:]          # (KH, D), or (KH*D,)
+    if quant:
+        # symmetric per-token int8: one f32 scale per written
+        # (block, slot), stored beside the rows so dequant is a
+        # gather of exactly what the write saw (replay-stable)
+        ksc = jnp.maximum(jnp.max(jnp.abs(
+            kfl.astype(jnp.float32)), axis=(1, 2)) / 127.0, 1e-8)
+        vsc = jnp.maximum(jnp.max(jnp.abs(
+            vfl.astype(jnp.float32)), axis=(1, 2)) / 127.0, 1e-8)
+        kpool = kpool.at[fb, fo].set(jnp.clip(jnp.round(
+            kfl.astype(jnp.float32) / ksc[:, None, None]),
+            -127, 127).astype(jnp.int8))
+        vpool = vpool.at[fb, fo].set(jnp.clip(jnp.round(
+            vfl.astype(jnp.float32) / vsc[:, None, None]),
+            -127, 127).astype(jnp.int8))
+        kscale = kscale.at[fb, fo].set(ksc)
+        vscale = vscale.at[fb, fo].set(vsc)
+    else:
+        kpool = kpool.at[fb, fo].set(
+            kfl.astype(kpool.dtype).reshape((B * S,) + page_row))
+        vpool = vpool.at[fb, fo].set(
+            vfl.astype(vpool.dtype).reshape((B * S,) + page_row))
+
+    def ret(o):
+        out = (o.reshape(B, S, H * D), kpool, vpool)
+        return out + (kscale, vscale) if quant else out
+
+    if S > 1 and not verify_mode:
+        # PREFILL: causal attention over the fresh block equals
+        # attention against the just-written cache (contiguous
+        # positions from 0) — use the flash/sdpa path; the
+        # scattered K/V stay behind for decode.  Right-padding
+        # is causal-safe: a real token never attends forward.
+        kh2, vh2 = kh, vh
+        if KH != H:
+            rep = H // KH
+            kh2 = jnp.repeat(kh, rep, axis=2)
+            vh2 = jnp.repeat(vh, rep, axis=2)
+        from ...nn.functional.attention import _sdpa_ref
+        from ...ops.flash_attention import (flash_attention as
+                                            _fa_t, flash_eligible)
+        if flash_eligible(S, D):
+            o = _fa_t(qh, kh2, vh2, causal=True)
+        else:
+            o = _sdpa_ref(qh, kh2, vh2, None, 0.0, True, None)
+        return ret(o)
+    # DECODE / VERIFY: gather the sequence's cache through its
+    # block table — [B, M, bs, KH, D] -> [B, M*bs, KH, D] in
+    # logical position order — then the same grouped-query
+    # masked attention as :meth:`_forward_cached` (slot index
+    # == absolute position, valid iff slot <= query position).
+    # In verify mode the queries' own K/V were written above,
+    # so slot <= pos is simultaneously the causal mask within
+    # the block and the prefix mask against the cache.
+    #
+    # The gather/attend math lives in ops/pallas/kv_attention.
+    # paged_attention_ref (lifted verbatim, so the non-pallas
+    # serving contracts — replay, prefix sharing, eviction — are
+    # pinned by the SAME ops); the registry picks, per traced
+    # program and from what this call sees, a kernel that reads
+    # the pools through the table instead: ``paged_attention``
+    # for a decode step (S == 1, bf16 pools, TPU target),
+    # ``int8_kv_attention`` for int8 pools (xla_ref on TPU
+    # until it lowers).  Verify and suffix-prefill calls keep
+    # the reference.
+    mode = kv_mode
+    if not quant and (S > 1 or verify_mode):
+        mode = "xla_ref"
+    o = _kreg.dispatch(kv_kernel, qh, kpool, vpool, kscale,
+                       vscale, tbl, pos, KH, mode=mode)
+    return ret(o)
+
+
 class LlamaAttention(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -341,95 +447,16 @@ class LlamaAttention(Layer):
         def attn_paged(qv, kv, vv, pos, wm, kpool, vpool, tbl,
                        kscale=None, vscale=None):
             B, S = qv.shape[0], qv.shape[1]
-            bs = kpool.shape[1]
             qh = qv.reshape(B, S, c.num_attention_heads, c.head_dim)
             kh = kv.reshape(B, S, c.kv_heads, c.head_dim)
             vh = vv.reshape(B, S, c.kv_heads, c.head_dim)
             qh = _rope(qh, pos, c.rope_theta)
             kh = _rope(kh, pos, c.rope_theta)
             qh = mesh_mod.constrain_dim(qh, 2, _layout().act_axis("attn_heads"))  # heads stay sharded
-            # scatter this call's K/V into the pools: physical block =
-            # table[logical block], offset = pos % block_size; masked
-            # writes divert to the trash block (0, 0)
-            blk_log = (pos // bs).astype(jnp.int32)
-            blk_phys = jnp.take_along_axis(tbl, blk_log, axis=1)
-            off = (pos % bs).astype(jnp.int32)
-            blk_phys = jnp.where(wm, blk_phys, 0)
-            off = jnp.where(wm, off, 0)
-            fb = blk_phys.reshape(-1)
-            fo = off.reshape(-1)
-            kfl = kh.reshape(B * S, c.kv_heads, c.head_dim)
-            vfl = vh.reshape(B * S, c.kv_heads, c.head_dim)
-            if quant:
-                # symmetric per-token int8: one f32 scale per written
-                # (block, slot), stored beside the rows so dequant is a
-                # gather of exactly what the write saw (replay-stable)
-                ksc = jnp.maximum(jnp.max(jnp.abs(
-                    kfl.astype(jnp.float32)), axis=(1, 2)) / 127.0, 1e-8)
-                vsc = jnp.maximum(jnp.max(jnp.abs(
-                    vfl.astype(jnp.float32)), axis=(1, 2)) / 127.0, 1e-8)
-                kpool = kpool.at[fb, fo].set(jnp.clip(jnp.round(
-                    kfl.astype(jnp.float32) / ksc[:, None, None]),
-                    -127, 127).astype(jnp.int8))
-                vpool = vpool.at[fb, fo].set(jnp.clip(jnp.round(
-                    vfl.astype(jnp.float32) / vsc[:, None, None]),
-                    -127, 127).astype(jnp.int8))
-                kscale = kscale.at[fb, fo].set(ksc)
-                vscale = vscale.at[fb, fo].set(vsc)
-            else:
-                kpool = kpool.at[fb, fo].set(kfl.astype(kpool.dtype))
-                vpool = vpool.at[fb, fo].set(vfl.astype(vpool.dtype))
-
-            def ret(o):
-                out = (o.reshape(B, S,
-                                 c.num_attention_heads * c.head_dim),
-                       kpool, vpool)
-                return out + (kscale, vscale) if quant else out
-
-            if S > 1 and not verify_mode:
-                # PREFILL: causal attention over the fresh block equals
-                # attention against the just-written cache (contiguous
-                # positions from 0) — use the flash/sdpa path; the
-                # scattered K/V stay behind for decode.  Right-padding
-                # is causal-safe: a real token never attends forward.
-                kh2, vh2 = kh, vh
-                if c.kv_heads != c.num_attention_heads:
-                    rep = c.num_attention_heads // c.kv_heads
-                    kh2 = jnp.repeat(kh, rep, axis=2)
-                    vh2 = jnp.repeat(vh, rep, axis=2)
-                from ...nn.functional.attention import _sdpa_ref
-                from ...ops.flash_attention import (flash_attention as
-                                                    _fa_t, flash_eligible)
-                if flash_eligible(S, c.head_dim):
-                    o = _fa_t(qh, kh2, vh2, causal=True)
-                else:
-                    o = _sdpa_ref(qh, kh2, vh2, None, 0.0, True, None)
-                return ret(o)
-            # DECODE / VERIFY: gather the sequence's cache through its
-            # block table — [B, M, bs, KH, D] -> [B, M*bs, KH, D] in
-            # logical position order — then the same grouped-query
-            # masked attention as :meth:`_forward_cached` (slot index
-            # == absolute position, valid iff slot <= query position).
-            # In verify mode the queries' own K/V were written above,
-            # so slot <= pos is simultaneously the causal mask within
-            # the block and the prefix mask against the cache.
-            #
-            # The gather/attend math lives in ops/pallas/kv_attention.
-            # paged_attention_ref (lifted verbatim, so the non-pallas
-            # serving contracts — replay, prefix sharing, eviction — are
-            # pinned by the SAME ops); the registry picks, per traced
-            # program and from what this call sees, a kernel that reads
-            # the pools through the table instead: ``paged_attention``
-            # for a decode step (S == 1, bf16 pools, TPU target),
-            # ``int8_kv_attention`` for int8 pools (xla_ref on TPU
-            # until it lowers).  Verify and suffix-prefill calls keep
-            # the reference.
-            mode = kv_mode
-            if not quant and (S > 1 or verify_mode):
-                mode = "xla_ref"
-            o = _kreg.dispatch(kv_kernel, qh, kpool, vpool, kscale,
-                               vscale, tbl, pos, c.kv_heads, mode=mode)
-            return ret(o)
+            return paged_write_attend(
+                qh, kh, vh, pos, wm, kpool, vpool, tbl, kscale, vscale,
+                kv_kernel=kv_kernel, kv_mode=kv_mode,
+                verify_mode=verify_mode)
 
         if quant:
             ctx, kpool, vpool, ksc, vsc = _apply(

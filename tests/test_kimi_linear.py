@@ -168,6 +168,12 @@ def _dense_moe(x, m):
     return y
 
 
+def _masked_below(monkeypatch, n):
+    """Move the line between the two expert forms for a test: the
+    masked pass under ``n`` tokens, whatever the router's shape."""
+    monkeypatch.setattr(MOE, "masked_pass_pays", lambda T, k, E: T < n)
+
+
 @pytest.mark.parametrize("block,dense_below", [(4, 0), (16, 0),
                                                (256, 0), (256, 256)])
 def test_dropless_dispatch_equals_masked_dense(monkeypatch, block,
@@ -178,7 +184,7 @@ def test_dropless_dispatch_equals_masked_dense(monkeypatch, block,
     decode step's few tokens): one sum."""
     import paddle_tpu as paddle
     monkeypatch.setattr(MOE, "_GROUP_BLOCK", block)
-    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)
+    _masked_below(monkeypatch, dense_below)
     paddle.seed(0)
     m = DroplessMoELayer(32, 16, 16, top_k=4, held_experts=(4, 8),
                          routed_scaling_factor=2.0)
@@ -208,7 +214,7 @@ def test_four_shares_add_up_to_the_uncut_layer(bench, monkeypatch):
     got_ref, got_prog = shared, shared
     for first in (0, 2, 4, 6):
         # two shares by the grouped dispatch, two by the dense pass
-        monkeypatch.setattr(MOE, "_DENSE_BELOW", 0 if first < 4 else 256)
+        _masked_below(monkeypatch, 0 if first < 4 else 256)
         part = dict(whole, num_experts=2,
                     assumed=dict(whole["assumed"],
                                  held_experts=[first, 2]))
@@ -264,7 +270,7 @@ def test_prefill_then_decode_agree_with_the_reference(
         bench, monkeypatch, dt_bias, chunk, dense_below):
     cfg = toy_cfg()
     monkeypatch.setattr(KL, "KDA_CHUNK", chunk)
-    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)   # 0: grouped
+    _masked_below(monkeypatch, dense_below)   # 0: grouped
     model, params = build(bench, cfg, dt_bias)
     ids = np.random.RandomState(0).randint(1, 256, size=43).astype(np.int32)
     want = bench["ref"].forward_logits(cfg, params, jnp.asarray(ids))
@@ -406,7 +412,7 @@ def test_the_sampler_branches_only_behind_a_program_without_the_expert_loop(
     a ``conditional`` behind it stopped a v5e (PERF.md section 7, X), so
     a program that holds it sorts outside any ``cond``."""
     from test_sampler import _program_args, _sorts
-    monkeypatch.setattr(MOE, "_DENSE_BELOW", dense_below)
+    _masked_below(monkeypatch, dense_below)
     model, _ = build(bench, toy_cfg())
     srv = GenerationServer(model, num_slots=4, block_size=4,
                            max_model_len=64, prompt_buckets=[16],
@@ -423,6 +429,30 @@ def test_the_sampler_branches_only_behind_a_program_without_the_expert_loop(
         *experts, sampler = _sorts(traced.jaxpr.jaxpr)
         assert sampler == in_cond[which] and not any(experts)
         assert bool(experts) != in_cond[which]
+
+
+def test_what_must_not_move_for_the_kimi_cell():
+    """PR 33 drew the line between the expert forms by the router's
+    shape: at the published router (8 of 256) the cell's programs are
+    what they were.  Its 128-row decode step holds no device loop (the
+    masked pass), every prefill shape from 256 tokens holds the expert
+    blocks, the router's constant is 1e-20 and the model names its two
+    counters, not the layer's third."""
+    from paddle_tpu.text.models import kimi_linear_tiny
+    model = KimiLinearForCausalLM(kimi_linear_tiny(
+        num_experts=256, num_experts_per_token=8, held_experts=(0, 64),
+        moe_intermediate_size=8))
+    assert [model.loops_on_device(n) for n in (128, 256, 2048)] == [
+        False, True, True]
+    assert model.step_counters() == ("moe_picks_here",
+                                     "moe_max_expert_load")
+    moe_layers = [lyr.mlp for lyr in model.model.layers if lyr.is_moe]
+    assert moe_layers and all(m.norm_eps == 1e-20 for m in moe_layers)
+    pools = model.init_paged_cache(9, 4, 2)
+    _, _, counts = model.forward_paged(
+        jnp.zeros((2, 1), jnp.int32), jnp.zeros((2, 1), jnp.int32), pools,
+        jnp.zeros((2, 8), jnp.int32), jnp.ones((2, 1), bool))
+    assert counts.shape == (2,)
 
 
 def test_a_reused_slot_starts_from_zero_state(bench):

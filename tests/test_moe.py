@@ -140,3 +140,58 @@ def test_expert_parallel_matches_single_device():
         mark_sharding(p, spec)
     y_ep = moe(x).numpy()
     np.testing.assert_allclose(y_ep, y_single, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------
+# DroplessMoELayer: the line between its two forms (PR 33)
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("top_k,num_experts,tokens,masked", [
+    # the Kimi cell's router, 8 of 256: its 128-row decode step keeps
+    # the masked pass, every prefill shape (256 tokens up) the blocks
+    (8, 256, 128, True), (8, 256, 256, False), (8, 256, 2048, False),
+    # the LFM2 cell's, 4 of 32: its 256-row step takes the pass too
+    (4, 32, 128, True), (4, 32, 256, True), (4, 32, 384, False),
+    # every expert chosen by every token: nothing is multiplied in vain
+    (4, 4, 100000, True),
+])
+def test_the_masked_pass_follows_the_routers_shape_and_the_ridge(
+        top_k, num_experts, tokens, masked):
+    from paddle_tpu.nn.layer import moe as MOE
+    assert MOE._RIDGE == pytest.approx(240.5, abs=0.1)     # TPU v5e
+    assert MOE.masked_pass_pays(tokens, top_k, num_experts) is masked
+    layer = MOE.DroplessMoELayer(8, 4, num_experts, top_k=top_k,
+                                 held_experts=(0, min(num_experts, 4)))
+    assert layer.loops_on_device(tokens) is not masked
+
+
+def test_the_routers_normalising_constant_is_the_models():
+    """``w = s / (sum(s) + eps)``: 1e-20 unless the model says
+    otherwise (Kimi-Linear's; LFM2's published constant is 1e-6), and
+    the constant given is the one used."""
+    import jax.numpy as jnp
+    from paddle_tpu.nn.layer import moe as MOE
+    assert MOE.DroplessMoELayer(8, 4, 4, top_k=2).norm_eps == 1e-20
+    x = jnp.ones((3, 8), jnp.float32)
+    router = jnp.zeros((8, 4), jnp.float32)        # every score is 0.5
+    rb = jnp.asarray([3.0, 2.0, 1.0, 0.0])
+    for eps in (1e-20, 1e-6, 1.0):
+        _, w, here = MOE._route(x, router, rb, 2, 1.0, (0, 4), eps)
+        np.testing.assert_allclose(w, 0.5 / (1.0 + eps), rtol=1e-6)
+        assert bool(here.all())
+    paddle.seed(0)
+    m = MOE.DroplessMoELayer(8, 4, 4, top_k=2, norm_eps=1.0)
+    m.router._value = jnp.zeros_like(m.router._value)
+    halved = m.apply_values(x)[0]
+    m.norm_eps = 1e-20
+    np.testing.assert_allclose(halved * 2, m.apply_values(x)[0], rtol=1e-5)
+
+
+def test_row_products_are_counted_only_when_asked():
+    import jax.numpy as jnp
+    from paddle_tpu.nn.layer import moe as MOE
+    paddle.seed(0)
+    m = MOE.DroplessMoELayer(8, 4, 8, top_k=2, held_experts=(0, 4))
+    x = jnp.ones((5, 8), jnp.float32)
+    assert len(m.apply_values(x)) == 3
+    y, picks, load, rows = m.apply_values(x, count_rows=True)
+    assert rows == 5 * 4 and int(picks) <= 5 * 2

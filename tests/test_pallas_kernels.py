@@ -577,7 +577,7 @@ def test_llama_int8_default_route_is_xla_ref_on_cpu():
 # ---------------------------------------------------------------------
 
 def _paged_case(rng, lengths, G=2, R=2, D=16, bs=8, M=5, dtype=np.float32,
-                tables=None):
+                tables=None, flat_pool=False):
     """A decode batch: row i holds ``lengths[i]`` positions on its own
     physical blocks, its table padded with the trash block (0); the
     trash block and every unused block hold noise a correct kernel
@@ -587,6 +587,8 @@ def _paged_case(rng, lengths, G=2, R=2, D=16, bs=8, M=5, dtype=np.float32,
     qh = _rand(rng, B, 1, G * R, D)
     kpool = _rand(rng, nb, bs, G, D)
     vpool = _rand(rng, nb, bs, G, D)
+    if flat_pool:           # a narrow head's pool as its model keeps it
+        kpool, vpool = (x.reshape(nb, bs, G * D) for x in (kpool, vpool))
     tbl = np.zeros((B, M), np.int32)
     for i, n in enumerate(lengths):
         used = -(-n // bs)
@@ -611,6 +613,16 @@ _PAGED_CASES = {
     # more pages than one grid-step block holds, the last block partial
     "three_blocks_of_pages": dict(lengths=[160, 67, 129, 1], bs=4, M=40),
     "mha_tiny_head": dict(lengths=[5, 12], G=4, R=1, D=8, bs=4, M=3),
+    # GQA 32/8 x 64 (PR 33): the wide page, (16, 512), pool kept 3-D
+    "gqa32x8x64_block16_bf16": dict(
+        lengths=[1, 15, 16, 17, 64], G=8, R=4, D=64, bs=16, M=4,
+        dtype=jnp.bfloat16, flat_pool=True),
+    "gqa32x8x64_block16_f32": dict(
+        lengths=[33, 64, 2], G=8, R=4, D=64, bs=16, M=4),
+    # wide pages, more of them than one grid-step block holds
+    "wide_three_blocks_of_pages": dict(
+        lengths=[160, 67, 129, 1], G=2, R=2, D=64, bs=4, M=40,
+        flat_pool=True),
     # slots with the write mask off: position 0, an all-zero table
     "inactive_rows_zero_table": dict(
         lengths=[1, 11, 1], bs=8, M=2,
@@ -638,8 +650,9 @@ def test_paged_attention_interpret_parity(case):
         np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_attention_ignores_block_ids_and_neighbours(dtype):
+@pytest.mark.parametrize("dtype,D", [("float32", 16), ("bfloat16", 16),
+                                     ("bfloat16", 64)])
+def test_paged_attention_ignores_block_ids_and_neighbours(dtype, D):
     """The eviction / replay contract: the same logical sequence gives
     BIT-identical output on permuted physical blocks, in another slot,
     beside other rows (blocks are reduced in logical order; what a
@@ -648,7 +661,7 @@ def test_paged_attention_ignores_block_ids_and_neighbours(dtype):
     rng = np.random.default_rng(22)
     bs, M, n = 4, 40, 150                 # three blocks of pages
     (qh, kp, vp, _, _, tbl, pos), G = _paged_case(
-        rng, [n, 9, 160], bs=bs, M=M, dtype=jnp.dtype(dtype))
+        rng, [n, 9, 160], bs=bs, M=M, D=D, dtype=jnp.dtype(dtype))
     a = np.asarray(paged_attention(qh, kp, vp, None, None, tbl, pos, G,
                                    interpret=True).astype(jnp.float32))
     # row 0's sequence moves to permuted blocks of a fresh pool (the

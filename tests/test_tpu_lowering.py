@@ -117,6 +117,14 @@ def _kernel_cases():
                 a for b, mt in ((128, 64), (32, 256)) for a in (
                     np.zeros((b, 1, 32, 128), jnp.bfloat16),
                     np.zeros((b, mt), i32), np.zeros((b, 1), i32))]),
+        # the same kernel at head 64 (PR 33: the lfm2 cell, 256 slots x
+        # 112 blocks of 16): a pool of (16, 512) pages kept 3-D
+        "paged_attention@head64": (
+            lambda kp, vp, q, tbl, pos: kreg.dispatch(
+                "paged_attention", q, kp, vp, None, None, tbl, pos, 8),
+            [np.zeros((8192, 16, 512), jnp.bfloat16)] * 2 + [
+                np.zeros((256, 1, 32, 64), jnp.bfloat16),
+                np.zeros((256, 112), i32), np.zeros((256, 1), i32)]),
         "segment_sum": (
             lambda gr, inv: kreg.dispatch("segment_sum", gr, inv,
                                           num_segments=256),
@@ -133,12 +141,14 @@ def _kernel_cases():
 
 
 def test_every_registered_kernel_has_a_case():
-    assert sorted(_kernel_cases()) == sorted(kreg.kernels())
+    assert sorted({n.split("@")[0] for n in _kernel_cases()}) \
+        == sorted(kreg.kernels())
 
 
 @pytest.mark.parametrize("name", sorted(_kernel_cases()))
 def test_kernel_compiles_for_v5e_through_dispatch(topo_devices, name):
     fn, args = _kernel_cases()[name]
+    name = name.split("@")[0]           # a second shape of one kernel
     mesh_mod.init_mesh({"dp": -1}, devices=topo_devices[:1])
     assert mesh_mod.target_platform() == "tpu"
     hlo = _compile_on(topo_devices[0], fn, *args)
