@@ -107,23 +107,39 @@ eviction, ``stop()``) reads the step in flight first; speculative mode
 reads every iteration at once.  ``stats()["decode_steps_overlapped"]``
 counts the steps dispatched over an unread one.
 
+A prefill call joins that pipeline (ISSUE 34): its first tokens stay on
+the device too.  One tiny jitted scatter (``feed_fn``) writes them into
+the token feed at their rows' slots, the next decode step is dispatched
+with those rows riding in it, and only then are the results read: the
+prefill calls in the order dispatched (counters, the prefix index, the
+first tokens out), then the decode step that was in flight, as the
+synchronous loop delivered them.  A first token that ends its request
+by length is known by counting and its row rides no step; one that is
+its ``eos`` rides the step already dispatched, and that token is
+dropped.  Whatever reads the step in flight first reads the unread
+prefill calls too; speculative mode reads a call at once.
+``stats()["prefills_overlapped"]`` counts the prefill calls read behind
+a decode dispatch that followed them.
+
 The scheduler loop itself is on the profiler's clock (ISSUE 25): one
 ``StepTimeline("serve")`` step per iteration with work, its phases
-``serve.admit`` -> ``serve.prefill.stage|dispatch|fetch|post`` (per
-batch) -> ``serve.decode.grow|stage|dispatch|fetch|emit`` (or one
-``serve.spec``), and ``serve.idle`` while nothing is in flight; the
-``fetch`` and ``emit`` of an iteration are those of the step
-dispatched one iteration earlier.  Each is a
+``serve.admit`` -> ``serve.prefill.stage|dispatch`` (per batch) ->
+``serve.decode.grow|stage|dispatch`` -> ``serve.prefill.fetch|post``
+(per batch) -> ``serve.decode.fetch|emit`` (or, in speculative mode,
+each batch's four phases and then one ``serve.spec``), and
+``serve.idle`` while nothing is in flight; the decode ``fetch`` and
+``emit`` of an iteration are those of the step dispatched one
+iteration earlier.  Each is a
 ``jax.profiler.TraceAnnotation`` on this thread's line of a profiler
 trace and a row of ``observability.timeline.spans("serve")``; the rows
 of ``serve.admit`` carry ``queue_wait_ms`` (one value per admitted
 sequence), those of ``serve.prefill.stage`` the padded ``batch`` x
 ``bucket`` and the useful ``tokens``, those of
-``serve.decode.dispatch`` ``overlapped`` (0 or 1).  ``decode_ms`` /
-``prefill_ms`` are read off the dispatch and fetch spans' own clock
-reads and count no instant twice: a decode step runs from its
-dispatch, or from the end of the fetch before it where that is later,
-to the end of its own fetch.
+``serve.decode.dispatch`` and ``serve.prefill.fetch`` ``overlapped``
+(0 or 1).  ``decode_ms`` / ``prefill_ms`` are read off the dispatch and
+fetch spans' own clock reads and count no instant twice: a decode step
+or a prefill call runs from its dispatch, or from the end of the fetch
+before it where that is later, to the end of its own fetch.
 
 ISSUE 12 (fleet observatory) adds the REQUEST dimension:
 ``submit(tenant=...)`` tags a request for usage accounting (always-on
@@ -294,11 +310,21 @@ class _GenSeq:
 
 
 class _Unread(NamedTuple):
-    """A decode step that is dispatched and not yet read: what it
-    returned is the server's ``_prev``, still on the device."""
+    """A decode step that is dispatched and not yet read."""
     rows: List[_GenSeq]    # the sequences that ride in it
     t0: float              # the clock at the start of its dispatch
     sampled: bool          # a row of it samples
+    out: object            # what it returned, still on the device
+
+
+class _UnreadPrefill(NamedTuple):
+    """A prefill call that is dispatched and not yet read."""
+    seqs: List[_GenSeq]    # its rows' sequences, in row order
+    t0: float              # the clock at the start of its dispatch
+    bucket: int
+    tokens: int            # real prompt tokens in it
+    sampled: bool          # a row of it samples
+    first: object          # each row's first token, still on the device
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -531,6 +557,9 @@ class GenerationServer:
             "decode_steps_overlapped": 0,
             "decode_ms": 0.0, "prefill_ms": 0.0,
             "prefill_batches": 0, "prefill_tokens": 0,
+            # prefill calls read behind a decode dispatch that followed
+            # them
+            "prefills_overlapped": 0,
             # decode, verify and prefill dispatches that held a
             # sampling row (a decode or verify dispatch that held none
             # ran the sampler's argmax alone)
@@ -558,9 +587,12 @@ class GenerationServer:
         self._draft_decode_fn = None
         self._verify_fn = None
         self._fork_fn = None
+        self._feed_fn = None
         self._prev = None
-        # the decode step that is dispatched and not yet read
+        # the decode step that is dispatched and not yet read, and the
+        # prefill calls dispatched behind it
         self._inflight: Optional[_Unread] = None
+        self._prefills: List[_UnreadPrefill] = []
         # decode_ms and prefill_ms count no instant twice: the end of
         # the last fetch either of them counted
         self._timed_until = 0.0
@@ -640,8 +672,9 @@ class GenerationServer:
                 self._num_blocks, self._bs)
         else:
             self._dpools = []
-        # what the last decode step returned, on the device: the next
-        # one's token feed, and what :meth:`_read_inflight` fetches
+        # the next decode step's token feed, on the device: what the
+        # last one returned, and since then the first tokens of the
+        # prefill calls behind it at their rows' slots (``feed_fn``)
         self._prev = np.zeros(
             (self._num_slots + len(self._step_counters),), np.int32)
 
@@ -713,6 +746,14 @@ class GenerationServer:
                 rep(temp), rep(top_k), rep(top_p), rep(do_sample))
             return sampled.reshape(B, S), pools
 
+        def feed_fn(prev, first, slots):
+            """A prefill call's first tokens into the decode program's
+            token feed, each at its row's slot; a row that holds no
+            sequence names a slot past the end and is dropped."""
+            server._compiles += 1
+            server._note_compile("feed", 1, first.shape[0])
+            return prev.at[slots].set(first, mode="drop")
+
         def fork_fn(pools, dpools, src, dst):
             """Copy-on-write fork: duplicate one physical block across
             every pool tensor (target + draft, K/V + int8 scales).
@@ -735,6 +776,8 @@ class GenerationServer:
         self._prefill_fn = jax.jit(
             make_prefill(call_model, sample, "prefill"),
             donate_argnums=donate)
+        if not self._spec:
+            self._feed_fn = jax.jit(feed_fn)
         if self._prefix_on:
             dfork = () if on_cpu else (0, 1)
             self._fork_fn = jax.jit(fork_fn, donate_argnums=dfork)
@@ -794,6 +837,7 @@ class GenerationServer:
         write only to the trash block (write masks all False), so the
         pools' live contents are untouched by construction."""
         W = int(np.asarray(self._seq_key_data(0)).shape[-1])
+        firsts = {}
         for b in self._buckets:
             for pb in self._pbatches:
                 args = (np.zeros((pb, b), np.int32),
@@ -805,7 +849,7 @@ class GenerationServer:
                         np.zeros((pb,), np.int32),
                         np.ones((pb,), np.float32),
                         np.zeros((pb,), bool))
-                _, self._pools = self._prefill_fn(
+                firsts[pb], self._pools = self._prefill_fn(
                     self._pvals, self._pools, *args,
                     **self._row_slots([], pb))
                 if self._spec:
@@ -828,6 +872,12 @@ class GenerationServer:
         for _ in range(2):
             self._prev, self._pools = self._decode_fn(
                 self._pvals, self._pools, self._prev, *dec_args)
+        if self._feed_fn is not None:
+            # as traffic passes them: the feed and a prefill's result,
+            # both on the device; every row dropped
+            for pb, first in firsts.items():
+                self._prev = self._feed_fn(
+                    self._prev, first, self._feed_slots([], pb))
         nxt = self._prev
         if self._spec:
             dn, self._dpools = self._draft_decode_fn(
@@ -1147,9 +1197,18 @@ class GenerationServer:
         reaches).  Nothing for a K/V model."""
         if not self._stateful:
             return {}
-        return {"slots": np.asarray(
-            [s.slot for s in seqs] + [self._num_slots] * (B - len(seqs)),
-            np.int32)}
+        return {"slots": self._slots_of(seqs, B, self._num_slots)}
+
+    def _feed_slots(self, seqs, B: int):
+        """Where :attr:`_feed_fn` writes a prefill call's ``B`` first
+        tokens: each sequence's slot, and past the end of the feed for
+        a row that holds none."""
+        return self._slots_of(seqs, B, self._prev.shape[0])
+
+    @staticmethod
+    def _slots_of(seqs, B: int, pad: int):
+        return np.asarray([s.slot for s in seqs] + [pad] * (B - len(seqs)),
+                          np.int32)
 
     # -- scheduler ---------------------------------------------------
     def _loop(self):
@@ -1166,11 +1225,13 @@ class GenerationServer:
                             self._cond.wait(timeout=0.05)
                         continue
                 if not running:
-                    # stop(): what was dispatched is delivered; commands
-                    # still queued are run by stop()
+                    # stop(): what was dispatched is delivered (a
+                    # prefill call is read by the iteration that
+                    # dispatched it); commands still queued are run by
+                    # stop()
                     if self._inflight is not None:
                         with tl.step(step_i):
-                            self._read_inflight()
+                            self._read_unread()
                     return
                 with tl.step(step_i):
                     step_i += 1
@@ -1178,7 +1239,7 @@ class GenerationServer:
                         # a command (cancel, migration) reads and
                         # rewrites sequence state as if no step were in
                         # flight: read it first
-                        self._read_inflight()
+                        self._read_unread()
                     with tl.phase("admit") as ph:
                         if self._inflight is None:
                             self._drain_cmds()
@@ -1201,6 +1262,7 @@ class GenerationServer:
                 self._active.clear()
                 self._running = False
             self._inflight = None    # its tokens go with the streams
+            self._prefills = []
             for seq in victims:
                 if seq.rt is not None:
                     seq.rt.finish("scheduler_error")
@@ -1399,7 +1461,12 @@ class GenerationServer:
 
     def _prefill_batch(self, seqs: List[_GenSeq], bucket: int):
         """One prefill dispatch for up to max_prefill_batch sequences
-        sharing a bucket; padding rows (length 0) write only trash."""
+        sharing a bucket; padding rows (length 0) write only trash.
+        Its first tokens go into the decode program's token feed on the
+        device and the call is left unread (:meth:`_read_unread`), so
+        the next decode step can be dispatched behind it; speculative
+        mode, which drafts from the first token on the host, reads it
+        at once."""
         tl = self._tl
         with tl.phase("prefill.stage") as ph:
             B = self._pbatch_for(len(seqs))
@@ -1424,6 +1491,10 @@ class GenerationServer:
                 top_k[i] = seq.top_k
                 top_p[i] = seq.top_p
                 do_sample[i] = seq.do_sample
+                # the decode step behind this call is staged before the
+                # call is read: a re-admitted row replays from its start
+                seq.decoded = 0
+                seq.draft_decoded = 0
                 if seq.rt is not None:
                     seq.rt.begin("prefill")
             tokens = int(length.sum())
@@ -1441,21 +1512,37 @@ class GenerationServer:
                 _, self._dpools = self._draft_prefill_fn(
                     self._dvals, self._dpools, prompt, start, length,
                     tables, kd, temp, top_k, top_p, do_sample)
-        with tl.phase("prefill.fetch") as fetch:
-            first = np.asarray(first)
-        # prefill_ms keeps its meaning (dispatch through fetch), read
-        # off the two spans' clocks
-        dt_ms = (fetch.t1 - disp.t0) * 1e3
+            else:
+                self._prev = self._feed_fn(self._prev, first,
+                                           self._feed_slots(seqs, B))
+        self._prefills.append(_UnreadPrefill(
+            seqs, disp.t0, bucket, tokens, bool(do_sample.any()), first))
+        if self._spec:
+            self._read_unread()
+
+    def _read_prefill(self, call: _UnreadPrefill, overlapped: int):
+        """Fetch a prefill call's first tokens and do what follows from
+        them: counters, the prefix index, the tokens out.
+        ``overlapped``: a decode step was dispatched behind the call."""
+        tl = self._tl
+        seqs, bucket = call.seqs, call.bucket
+        with tl.phase("prefill.fetch", overlapped=overlapped) as fetch:
+            first = np.asarray(call.first)
+        # like a decode step's: from its dispatch, or from the end of
+        # the fetch before it where that is later, to its fetch's end
+        # (the rest of the step in flight ahead of it included)
+        dt_ms = (fetch.t1 - max(call.t0, self._timed_until)) * 1e3
         self._timed_until = fetch.t1
         with tl.phase("prefill.post"):
             with self._lock:
                 self._stats["prefill_ms"] += dt_ms
                 self._stats["prefill_batches"] += 1
-                self._stats["sampled_steps"] += bool(do_sample.any())
+                self._stats["prefills_overlapped"] += overlapped
+                self._stats["sampled_steps"] += call.sampled
                 self._stats["prefill_bucket_hits"][bucket] = \
                     self._stats["prefill_bucket_hits"].get(bucket, 0) \
                     + len(seqs)
-                self._stats["prefill_tokens"] += tokens
+                self._stats["prefill_tokens"] += call.tokens
                 self._stats["prefill_tokens_skipped"] += int(
                     sum(s.cached for s in seqs))
                 if self._stateful:   # each started from zero state
@@ -1499,8 +1586,6 @@ class GenerationServer:
             _monitor.gauge_set("serve_prefix_hit_rate",
                                st["hit_tokens"]
                                / max(st["query_tokens"], 1))
-        seq.decoded = 0
-        seq.draft_decoded = 0
         if readmit:
             # replay: prefill re-derives t1 from the identical program
             # + inputs; the stored token is authoritative either way
@@ -1671,16 +1756,20 @@ class GenerationServer:
         ran while the host staged n+1.  All that n+1 needs of n is each
         row's token, and that stays on the device (``decode_fn``'s
         ``prev``); positions, tables, keys and lengths the host knows
-        one step early.  With nothing in flight the step is dispatched
-        and left unread.  What must see sequence state as if no step
-        were in flight reads it first, and this iteration then runs at
-        depth zero: an eviction here, a command in :meth:`_loop`."""
+        one step early.  The rows of this iteration's prefill calls ride
+        in n+1 as well: their first tokens are in ``prev`` already
+        (:meth:`_prefill_batch`), and the calls are read ahead of n.  With
+        nothing in flight the step is dispatched and left unread.  What
+        must see sequence state as if nothing were in flight reads it
+        first, and this iteration then runs at depth zero: an eviction
+        here, a command in :meth:`_loop`."""
         tl = self._tl
         rows, fits = self._riders()
-        if self._inflight is not None and not (rows and fits):
+        if (self._inflight is not None or self._prefills) \
+                and not (rows and fits):
             # nothing rides on, or the pool is dry: the read may finish
             # sequences and free their blocks before anything is evicted
-            self._read_inflight()
+            self._read_unread()
             rows, fits = self._riders()
         if not rows:
             return
@@ -1706,8 +1795,8 @@ class GenerationServer:
             for seq in rows:
                 s = seq.slot
                 d = seq.decoded + seq.unread      # the token it feeds
-                # -1: the token the step in flight is producing, taken
-                # on the device
+                # -1: the token the step in flight, or the prefill
+                # call behind it, is producing, taken on the device
                 tokens[s, 0] = seq.generated[d] \
                     if d < len(seq.generated) else -1
                 positions[s, 0] = seq.L + d
@@ -1727,9 +1816,25 @@ class GenerationServer:
                 tables, wm, kd, rng_steps, temp, top_k, top_p, do_sample)
         with self._lock:
             self._stats["decode_steps_overlapped"] += overlapped
-        self._read_inflight()         # step n, which ran meanwhile
+        # this iteration's prefill calls, then step n: both ran meanwhile
+        self._read_unread(overlapped=1)
         self._prev = nxt
-        self._inflight = _Unread(rows, disp.t0, bool(do_sample.any()))
+        self._inflight = _Unread(rows, disp.t0, bool(do_sample.any()), nxt)
+
+    def _read_unread(self, overlapped: int = 0):
+        """Read what is dispatched and unread: the prefill calls, in the
+        order dispatched, then the decode step in flight, which the
+        device ran ahead of them.  A request's first token waits for
+        nothing but its own call, and the step's tokens go out behind
+        it, as from the synchronous loop: a client whose request ends
+        in that step and who sends the next one at once finds the
+        scheduler about to admit, not blocked in a call's fetch.
+        ``overlapped``: the next decode step went out first."""
+        if self._prefills:
+            calls, self._prefills = self._prefills, []
+            for call in calls:
+                self._read_prefill(call, overlapped)
+        self._read_inflight()
 
     def _read_inflight(self):
         """Fetch the tokens of the decode step in flight, if there is
@@ -1747,7 +1852,7 @@ class GenerationServer:
             return
         tl = self._tl
         with tl.phase("decode.fetch") as fetch:
-            nxt = np.asarray(self._prev)
+            nxt = np.asarray(step.out)
         # a step's decode_ms runs to the end of its fetch from its
         # dispatch, or from the end of the fetch before it (the step's
         # before it, a prefill's) where that is later: decode_ms and

@@ -17,13 +17,19 @@ Acceptance contracts, tested directly:
 - the scan_layers stacked decoder raises the typed
   ``KVCacheUnsupportedError`` naming the workaround.
 """
+import threading
+import time
+
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import (GenerationServer, RequestTimeout,
                                   ServerClosed, ServerOverloaded)
-from paddle_tpu.text.models import LlamaForCausalLM, llama_tiny
+from paddle_tpu.text.models import (KimiLinearForCausalLM,
+                                    Lfm2MoeForCausalLM, LlamaForCausalLM,
+                                    kimi_linear_tiny, lfm2_moe_tiny,
+                                    llama_tiny)
 from paddle_tpu.text.models.llama import KVCacheUnsupportedError
 
 
@@ -437,17 +443,53 @@ def test_eviction_deadline_epoch_is_submit_time(lm):
 
 # -- ISSUE 32: the decode loop is a pipeline of depth one --------------
 # Step n+1 is dispatched before step n is read; each row's last token
-# stays on the device.  The reference below reads every step at once,
-# as the loop did before: the streams have to be the same token for
-# token whatever ends, joins, is evicted or cancelled meanwhile.
+# stays on the device.  Since ISSUE 34 a prefill call's first tokens stay
+# there too: the step behind the call is dispatched before the call is
+# read.  The reference below reads every call and every step at once, as
+# the loop did before: the streams have to be the same token for token
+# whatever ends, joins, is evicted or cancelled meanwhile.
 
 class _ReadAtOnce(GenerationServer):
-    """The synchronous reference: every decode step is read before
-    anything else happens."""
+    """The synchronous reference: every prefill call and every decode
+    step is read before anything else happens."""
+
+    def _prefill_batch(self, seqs, bucket):
+        super()._prefill_batch(seqs, bucket)
+        self._read_unread()
 
     def _decode_once(self):
         super()._decode_once()
-        self._read_inflight()
+        self._read_unread()
+
+
+def _wait_until(cond, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "waited too long"
+        time.sleep(0.0005)
+
+
+def _gated(cls, at_prefill=None):
+    """``cls`` with a scheduler that admits nothing until ``gate`` is
+    set: what was submitted before is admitted together, and from there
+    on the schedule is the scheduler's own, the same in every run.
+    ``at_prefill(server, n, seqs)`` runs on the scheduler thread right
+    behind the dispatch of prefill call ``n``: in the pipelined server
+    the call is unread then."""
+    class Gated(cls):
+        gate = threading.Event()
+        calls = 0
+
+        def _admit(self):
+            self.gate.wait(60)
+            return super()._admit()
+
+        def _prefill_batch(self, seqs, bucket):
+            super()._prefill_batch(seqs, bucket)
+            self.calls += 1
+            if at_prefill is not None:
+                at_prefill(self, self.calls, seqs)
+    return Gated
 
 
 class _TalliedLM(LlamaForCausalLM):
@@ -672,10 +714,238 @@ def _case_drain(cls, lm, tallied):
     return outs, srv.stats()
 
 
+def _case_first_token_is_eos(cls, lm, tallied):
+    """A first token that is its request's ``eos`` cannot be counted:
+    the row rides the step dispatched behind its prefill call and that
+    step's token for it is dropped; slot and blocks go to a waiting
+    request behind that step."""
+    prompts = _prompts(seed=31, lens=(6, 5, 7, 9, 5, 8))
+    kw = dict(num_slots=2, block_size=4, max_model_len=24,
+              max_prefill_batch=1, check_replay=True,
+              request_timeout_s=120.0)
+    with _ReadAtOnce(tallied, **kw) as probe:
+        free = [probe.submit(p, max_new_tokens=8).result(timeout=120)
+                for p in prompts]
+    ends = {1, 2, 4}           # these end on their first token
+    with cls(tallied, **kw) as srv:
+        streams = [srv.submit(p, max_new_tokens=8,
+                              eos_token_id=s[0] if i in ends else None)
+                   for i, (p, s) in enumerate(zip(prompts, free))]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    for i, (out, s, stream) in enumerate(zip(outs, free, streams)):
+        assert out == (s[:1] if i in ends else s)
+        assert stream.finish_reason == ("eos" if i in ends else "length")
+    assert st["free_blocks"] == st["total_blocks"]
+    assert st["toy_steps"] == st["decode_steps"]
+    # a row whose first token ended it rode one step all the same
+    extra = st["toy_rows"] - sum(len(o) - 1 for o in outs)
+    assert extra == (len(ends) if cls is GenerationServer else 0)
+    return outs, st
+
+
+def _case_one_token_requests(cls, lm, tallied):
+    """``max_new_tokens == 1`` is known by counting: the row rides no
+    step, and a call of such rows alone is read with no step behind
+    it."""
+    prompts = _prompts(seed=32, lens=(6, 5, 7, 9, 5, 8, 4))
+    new = (1, 6, 1, 1, 5, 1, 7)
+    with cls(tallied, num_slots=3, block_size=4, max_model_len=24,
+             max_prefill_batch=2, check_replay=True,
+             request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, new)]
+        outs = [s.result(timeout=120) for s in streams]
+        lone = srv.submit(prompts[0], max_new_tokens=1).result(120)
+    st = srv.stats()   # after stop(): the last step is read
+    assert [len(o) for o in outs] == list(new) and lone == outs[0]
+    assert st["toy_steps"] == st["decode_steps"]
+    # every token but a request's first came from a decode step's row
+    assert st["toy_rows"] == sum(len(o) - 1 for o in outs)
+    assert st["free_blocks"] == st["total_blocks"]
+    return outs + [lone], st
+
+
+def _case_evict_behind_prefill(cls, lm, tallied):
+    """The pool runs dry in the iteration that dispatched a prefill
+    call: the call is read before anything is evicted, its row can be
+    the victim, and a re-admitted row replays tokens the host holds
+    beside rows that take theirs on the device."""
+    prompts = _prompts(seed=33, lens=(6, 7, 5, 6, 7, 5))
+    Gated = _gated(cls)
+    with Gated(lm, num_slots=3, block_size=4, max_model_len=24,
+               num_blocks=9, check_replay=True,
+               request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=10 + i, seed=70 + i,
+                              priority=i % 3, do_sample=True,
+                              temperature=0.9, top_k=8)
+                   for i, p in enumerate(prompts)]
+        Gated.gate.set()
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    assert st["evicted"] > 0 and st["replay_steps"] > 0
+    assert st["readmitted"] > 0
+    assert st["free_blocks"] == st["total_blocks"]
+    if cls is GenerationServer:
+        # every request asks for more than one token, so a call read
+        # with no step behind it was read because the pool was dry
+        assert 0 < st["prefills_overlapped"] < st["prefill_batches"]
+    return outs, st
+
+
+def _case_replay_submit(cls, lm, tallied):
+    """A request submitted with tokens its stream already emitted
+    elsewhere (failover) joins mid-flight: its row stages the tokens
+    the host holds and the feed's value for it goes unused."""
+    prompts = _prompts(seed=34, lens=(6, 9, 5, 7))
+    kw = dict(do_sample=True, temperature=0.8, top_k=12)
+    with cls(tallied, num_slots=3, block_size=4, max_model_len=32,
+             check_replay=True, request_timeout_s=120.0) as srv:
+        whole = srv.submit(prompts[0], max_new_tokens=12, seed=5,
+                           **kw).result(timeout=120)
+        streams = [srv.submit(p, max_new_tokens=14, seed=80 + i, **kw)
+                   for i, p in enumerate(prompts[1:])]
+        it = iter(streams[0])
+        [next(it) for _ in range(2)]
+        moved = srv.submit(prompts[0], max_new_tokens=12, seed=5,
+                           replay_tokens=whole[:5], **kw)
+        outs = [s.result(timeout=120) for s in streams]
+        rest = moved.result(timeout=120)
+    st = srv.stats()   # after stop(): the last step is read
+    assert rest == whole[5:] and st["replay_steps"] >= 4
+    assert st["toy_steps"] == st["decode_steps"]
+    return [whole, rest] + outs, st
+
+
+def _case_shared_prefix_joins(cls, lm, tallied):
+    """Prefix sharing on, more requests than slots, one system prompt:
+    a prompt is indexed when its call is read, behind the step
+    dispatched after it and before the next admission, so later
+    requests alias it and prefill their suffix (with a copy-on-write
+    fork where the shared tail block is written) while others decode.
+    (``max_new`` and the lengths keep every answer's last token off a
+    block's last position: ROADMAP D22.)"""
+    rng = np.random.RandomState(35)
+    system = rng.randint(1, 64, (10,)).astype("int32")
+    prompts = [np.concatenate([system, rng.randint(1, 64, (n,))
+                               .astype("int32")]) for n in (3, 5, 2, 4, 3)]
+    new = (5, 4, 7, 4, 6)
+    assert all((len(p) + n) % 4 for p, n in zip(prompts, new))
+    with cls(lm, num_slots=2, block_size=4, max_model_len=48,
+             prefix_cache=True, max_prefill_batch=1, check_replay=True,
+             request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, new)]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    assert st["prefix_hits"] >= 3 and st["prefix_hit_tokens"] >= 3 * 8
+    assert st["cow_forks"] >= 1
+    return outs, st
+
+
+def _case_cancel_behind_prefill(cls, lm, tallied):
+    """``cancel()`` is queued while a prefill call is unread: the
+    iteration goes on (the step behind the call, then the reads), the
+    next one reads the step in flight and only then runs the command.
+    The cancelled request is the one that call prefilled."""
+    prompts = _prompts(seed=36, lens=(5, 8, 6, 7))
+    box = {}
+
+    def at_prefill(srv, n, seqs):
+        if n == 2:            # the late request's call
+            rid = seqs[0].rid
+            t = threading.Thread(
+                target=lambda: box.update(ok=srv.cancel(rid)))
+            t.start()
+            box["thread"] = t
+            _wait_until(lambda: not srv._cmds.empty())
+            box["unread"] = len(srv._prefills)
+
+    Gated = _gated(cls, at_prefill)
+    with Gated(lm, num_slots=3, block_size=4, max_model_len=40,
+               check_replay=True, request_timeout_s=120.0) as srv:
+        streams = [srv.submit(p, max_new_tokens=30) for p in prompts[:2]]
+        Gated.gate.set()
+        it = iter(streams[0])
+        [next(it) for _ in range(3)]
+        late = srv.submit(prompts[2], max_new_tokens=12)
+        got = late.result(timeout=120)
+        box["thread"].join(60)
+        after = srv.submit(prompts[3], max_new_tokens=8)
+        outs = [s.result(timeout=120) for s in streams + [after]]
+    st = srv.stats()   # after stop(): the last step is read
+    assert box["ok"] and late.finish_reason == "cancelled"
+    assert box["unread"] == (1 if cls is GenerationServer else 0)
+    # its first token, and the token of the step behind its call
+    assert len(got) == 2
+    assert st["cancelled"] == 1 and st["finished"] == 3
+    assert st["free_blocks"] == st["total_blocks"]
+    return [got] + outs, st
+
+
+def _case_stop_behind_prefill(cls, lm, tallied, drain=True):
+    """``stop()`` arrives while a prefill call is unread.  With
+    ``drain`` every stream runs to its end; without, what was
+    dispatched is delivered (the call's first tokens and the step
+    behind it) and the streams then fail typed."""
+    prompts = _prompts(seed=37, lens=(5, 8, 6, 7))
+    box = {}
+
+    def at_prefill(srv, n, seqs):
+        if n == 2:
+            box["went"] = threading.Event()
+
+            def stop():
+                box["went"].set()
+                srv.stop(drain=drain, timeout=120)
+            t = threading.Thread(target=stop)
+            t.start()
+            box["thread"] = t
+            box["went"].wait(60)
+            _wait_until(lambda: drain or not srv._running)
+            box["unread"] = len(srv._prefills)
+
+    Gated = _gated(cls, at_prefill)
+    srv = Gated(lm, num_slots=2, block_size=4, max_model_len=32,
+                check_replay=True, request_timeout_s=120.0).start()
+    try:
+        streams = [srv.submit(p, max_new_tokens=9 + i)
+                   for i, p in enumerate(prompts)]
+        Gated.gate.set()
+        outs = []
+        for s in streams:
+            try:
+                outs.append(s.result(timeout=120))
+            except ServerClosed:
+                outs.append(list(s.tokens))
+        box["thread"].join(120)
+    finally:
+        srv.stop()
+    st = srv.stats()
+    assert box["unread"] == (1 if cls is GenerationServer else 0)
+    if drain:
+        assert [s.finish_reason for s in streams] == ["length"] * 4
+    else:
+        # the call's request: its first token and the token of the
+        # step behind the call; the one still waiting: nothing
+        assert [len(o) for o in outs[2:]] == [2, 0]
+        assert streams[2].tokens == outs[2]
+        assert st["free_blocks"] == st["total_blocks"]
+    return outs, st
+
+
+def _case_stop_at_once_behind_prefill(cls, lm, tallied):
+    return _case_stop_behind_prefill(cls, lm, tallied, drain=False)
+
+
 @pytest.mark.parametrize("case", [
     _case_eos_reuse, _case_evict_replay, _case_sampled_beside_greedy,
     _case_state_and_counters, _case_prefix_cache, _case_cancel,
-    _case_drain], ids=lambda f: f.__name__[6:])
+    _case_drain, _case_first_token_is_eos, _case_one_token_requests,
+    _case_evict_behind_prefill, _case_replay_submit,
+    _case_shared_prefix_joins, _case_cancel_behind_prefill,
+    _case_stop_behind_prefill, _case_stop_at_once_behind_prefill],
+    ids=lambda f: f.__name__[6:])
 def test_pipelined_streams_equal_the_synchronous_reference(
         case, lm, tallied):
     want, ref, *cut_ref = case(_ReadAtOnce, lm, tallied)
@@ -683,6 +953,8 @@ def test_pipelined_streams_equal_the_synchronous_reference(
     assert got == want
     assert ref["decode_steps_overlapped"] == 0 < ref["decode_steps"]
     assert 0 < st["decode_steps_overlapped"] < st["decode_steps"]
+    assert ref["prefills_overlapped"] == 0 < ref["prefill_batches"]
+    assert 0 < st["prefills_overlapped"] <= st["prefill_batches"]
     assert st["traffic_compiles"] == ref["traffic_compiles"] == 0
     if cut:       # a cancelled stream: as far as both got, the same
         n = min(len(cut[0]), len(cut_ref[0]))
@@ -711,3 +983,100 @@ def test_overlap_counter_says_whether_the_pipeline_engaged(lm, spec):
         # 29 steps a request; one that was admitted alone starts early
         assert 29 <= st["decode_steps"] <= 29 + 3
         assert st["decode_steps_overlapped"] >= 0.9 * st["decode_steps"]
+
+
+# -- ISSUE 34: a prefill call joins the pipeline ------------------------
+
+def _tiny(name, lm):
+    if name == "llama":
+        return lm
+    paddle.seed(0)
+    if name == "kimi":
+        m = KimiLinearForCausalLM(kimi_linear_tiny())
+    else:
+        m = Lfm2MoeForCausalLM(lfm2_moe_tiny())
+        for _, p in m.named_parameters():     # or every greedy stream
+            if p._value.ndim >= 2:            # repeats one token
+                p._value = p._value * 3.0
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module", params=["llama", "kimi", "lfm2"])
+def joined(request, lm):
+    """More requests than slots on a K/V model and on the two models
+    with per-slot state, so that requests join while others decode:
+    greedy and sampled traffic through the synchronous reference and
+    through the pipelined server."""
+    model = _tiny(request.param, lm)
+    rng = np.random.RandomState(41)
+    V = model.config.vocab_size
+    prompts = [rng.randint(1, V, (n,)).astype("int32")
+               for n in (5, 9, 14, 7, 20, 11, 6, 13)]
+    sampled = dict(do_sample=True, temperature=0.8, top_k=20)
+    got = {}
+    for cls in (_ReadAtOnce, GenerationServer):
+        with cls(model, num_slots=3, block_size=4, max_model_len=64,
+                 prompt_buckets=[16, 32], max_prefill_batch=2,
+                 check_replay=True, request_timeout_s=300.0) as srv:
+            for mode, kw in (("greedy", {}), ("sampled", sampled)):
+                before = srv.stats()
+                streams = [srv.submit(p, max_new_tokens=4 + 2 * i,
+                                      seed=40 + i, **kw)
+                           for i, p in enumerate(prompts)]
+                outs = [s.result(timeout=300) for s in streams]
+                # the step in flight is read once the loop idles
+                _wait_until(lambda: not srv.stats()["active"]
+                            and srv._inflight is None)
+                after = srv.stats()
+                got[cls, mode] = outs, {
+                    k: after[k] - before[k] for k in (
+                        "prefill_batches", "prefills_overlapped",
+                        "traffic_compiles", "state_resets")}
+    return got
+
+
+@pytest.mark.parametrize("mode", ["greedy", "sampled"])
+def test_rows_that_join_mid_flight_stream_what_the_reference_streams(
+        joined, mode):
+    want, ref = joined[_ReadAtOnce, mode]
+    outs, st = joined[GenerationServer, mode]
+    assert [len(o) for o in outs] == [4 + 2 * i for i in range(8)]
+    assert outs == want
+    assert len({tuple(o[:4]) for o in outs}) == 8    # not one stream
+    # every call had a decode step dispatched behind it before it was
+    # read; the reference read every call at once
+    assert st["prefills_overlapped"] == st["prefill_batches"] >= 4
+    assert ref["prefills_overlapped"] == 0 < ref["prefill_batches"]
+    assert st["traffic_compiles"] == ref["traffic_compiles"] == 0
+    assert st["state_resets"] == ref["state_resets"]
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_prefill_counter_says_whether_the_calls_joined_the_pipeline(
+        lm, spec):
+    """Under steady joins every prefill call of a plain server is read
+    behind the decode step dispatched after it; a server in
+    speculative mode drafts from the first token on the host and reads
+    every call at once.  Both stream the same tokens."""
+    kw = dict(draft_model=lm, spec_k=3) if spec else {}
+    with GenerationServer(lm, num_slots=2, block_size=4, max_model_len=48,
+                          max_prefill_batch=1, check_replay=True,
+                          request_timeout_s=120.0, **kw) as srv:
+        n = srv.num_compiles()
+        streams = [srv.submit(p, max_new_tokens=6 + i)
+                   for i, p in enumerate(_prompts(
+                       seed=42, lens=(5, 9, 3, 12, 7, 4, 10)))]
+        outs = [s.result(timeout=120) for s in streams]
+    st = srv.stats()   # after stop(): the last step is read
+    assert srv.num_compiles() == n and st["traffic_compiles"] == 0
+    assert st["prefill_batches"] == 7
+    assert st["prefills_overlapped"] == (0 if spec else 7)
+    progs = {k.split(":")[0] for k in st["bucket_compiles"]}
+    assert ("feed" in progs) is not spec
+    with _ReadAtOnce(lm, num_slots=2, block_size=4, max_model_len=48,
+                     max_prefill_batch=1,
+                     request_timeout_s=120.0) as ref:
+        assert outs == [ref.submit(p, max_new_tokens=6 + i).result(120)
+                        for i, p in enumerate(_prompts(
+                            seed=42, lens=(5, 9, 3, 12, 7, 4, 10)))]

@@ -381,13 +381,15 @@ def test_served_tokens_are_the_references_best(bench, dt_bias):
 def test_a_sampling_request_costs_no_program_and_is_counted(bench):
     """ISSUE 30: the sampler's work sits in a ``cond`` inside the
     programs, so this server compiles the 7 programs the commit before
-    compiled for these arguments (6 prefill shapes + decode), none
-    when a request samples, and counts the dispatches that held it."""
+    compiled for these arguments (6 prefill shapes + decode; since
+    ISSUE 34 the first tokens' scatter too, once a prefill batch
+    width), none when a request samples, and counts the dispatches
+    that held it."""
     model, _ = build(bench, toy_cfg())
     with GenerationServer(model, num_slots=4, block_size=4,
                           max_model_len=64, prompt_buckets=[16, 32],
                           max_prefill_batch=2, check_replay=True) as srv:
-        assert srv.num_compiles() == 7
+        assert srv.num_compiles() == 7 + 2
         a, b = _prompts(2, seed=3)
         greedy = srv.submit(a, max_new_tokens=6).result(timeout=300)
         assert srv.stats()["sampled_steps"] == 0
@@ -398,7 +400,7 @@ def test_a_sampling_request_costs_no_program_and_is_counted(bench):
         st = srv.stats()
         assert len(greedy) == 6 and len(drawn) == 5
         assert st["sampled_steps"] == 2 * 5     # prefill + 4 decodes
-        assert st["num_compiles"] == 7 and st["traffic_compiles"] == 0
+        assert st["num_compiles"] == 9 and st["traffic_compiles"] == 0
 
 
 @pytest.mark.parametrize("dense_below, in_cond", [

@@ -242,24 +242,30 @@ def test_no_cond_behind_a_model_that_loops_on_the_device(lm, monkeypatch):
         assert _sorts(traced.jaxpr.jaxpr) == [tokens < 16]
 
 
-# what the parent commit's server counted for the same arguments (read
+# what ISSUE 30's parent commit counted for the same arguments (read
 # there): 3 buckets x the prefill batches, + decode; with speculation
 # the draft's prefills, draft decode and verify too; + the fork with
-# prefix sharing
-@pytest.mark.parametrize("kw, parent_count", [
-    ({}, 7),
-    ({"max_prefill_batch": 1}, 4),
-    ({"draft": True, "spec_k": 3}, 15),
-    ({"prefix_cache": True}, 8),
+# prefix sharing.  Since ISSUE 34 a plain server adds the scatter that
+# hands a prefill call's first tokens to the decode program, traced
+# once for each prefill batch width (a call's result has that width);
+# a speculative server reads every call at once and has none.
+@pytest.mark.parametrize("kw, parent_count, feeds", [
+    ({}, 7, 2),
+    ({"max_prefill_batch": 1}, 4, 1),
+    ({"draft": True, "spec_k": 3}, 15, 0),
+    ({"prefix_cache": True}, 8, 2),
 ])
-def test_start_compiles_what_the_parent_compiled(lm, kw, parent_count):
+def test_start_compiles_what_the_parent_compiled(lm, kw, parent_count,
+                                                 feeds):
     kw = dict(kw)
     if kw.pop("draft", False):
         kw["draft_model"] = lm
     with _server(lm, **kw) as srv:
-        assert srv.num_compiles() == parent_count
+        assert srv.num_compiles() == parent_count + feeds
         st = srv.stats()
-    assert st["prewarm_compiles"] == parent_count
+    assert st["prewarm_compiles"] == parent_count + feeds
+    assert sum(k.startswith("feed:") for k in st["bucket_compiles"]) \
+        == feeds
     assert st["traffic_compiles"] == 0 and st["sampled_steps"] == 0
 
 

@@ -105,7 +105,8 @@ def test_every_span_of_the_plain_loop_is_in_the_ring(session):
     assert all(r.step is None for r in _named(rows, "serve.idle"))
     # only the rows a reader needs carry counts
     assert {r.name for r in rows if r.args} == \
-        {"serve.admit", "serve.prefill.stage", "serve.decode.dispatch"}
+        {"serve.admit", "serve.prefill.stage", "serve.prefill.fetch",
+         "serve.decode.dispatch"}
 
 
 def test_spec_loop_is_one_span_and_leaves_no_hole(lm):
@@ -211,6 +212,54 @@ def test_overlapped_is_an_arg_of_every_decode_dispatch(session):
     for before, d, f in zip(disp, disp[1:], fetch):
         if d.args["overlapped"]:
             assert before.t_end <= d.t_start and d.t_end <= f.t_start
+
+
+def test_a_prefill_call_is_read_behind_the_decode_dispatch_after_it(
+        session, lm):
+    """ISSUE 34: an iteration dispatches its prefill calls, then the
+    next decode step with their rows in it, and only then reads: the
+    calls, in the order dispatched, then the step that was in flight.
+    ``overlapped`` on ``serve.prefill.fetch`` says a decode dispatch
+    came between, and ``stats()["prefills_overlapped"]`` counts it."""
+    rows, st = session
+    fetch = _named(rows, "serve.prefill.fetch")
+    assert len(fetch) == st["prefill_batches"] > 0
+    assert all(set(r.args) == {"overlapped"} for r in fetch)
+    assert sum(r.args["overlapped"] for r in fetch) == \
+        st["prefills_overlapped"] > 0
+    by_step = {}
+    for r in rows:
+        if r.step is not None and "." in r.name:
+            by_step.setdefault(r.step, []).append(r)
+    for f in fetch:
+        phases = sorted(by_step[f.step], key=lambda r: r.t_start)
+        names = [r.name for r in phases]
+        disp = [r for r in phases if r.name == "serve.decode.dispatch"]
+        # every request here asks for more than one token and the pool
+        # is ample: a step is dispatched behind every call
+        assert f.args["overlapped"] == 1 and len(disp) == 1
+        assert disp[0].t_end <= f.t_start
+        # the iteration's own calls, all dispatched before the step
+        calls = [r for r in phases if r.name == "serve.prefill.dispatch"]
+        assert calls and all(c.t_end <= disp[0].t_start for c in calls)
+        # the step that was in flight is read behind the calls
+        if "serve.decode.fetch" in names:
+            assert names.index("serve.decode.fetch") \
+                > len(names) - 1 - names[::-1].index("serve.prefill.post")
+        assert names.count("serve.prefill.fetch") == len(calls) \
+            == names.count("serve.prefill.post")
+    # a server in speculative mode reads every call at once
+    rows, st = _serve(lm, draft_model=lm, spec_k=3)
+    fetch = _named(rows, "serve.prefill.fetch")
+    assert len(fetch) == st["prefill_batches"] > 0
+    assert st["prefills_overlapped"] == 0
+    assert not any(r.args["overlapped"] for r in fetch)
+    for f in fetch:
+        d = max((r for r in _named(rows, "serve.prefill.dispatch")
+                 if r.t_end <= f.t_start), key=lambda r: r.t_end)
+        assert d.step == f.step
+        assert not [r for r in rows if r.name != "serve"
+                    and d.t_end <= r.t_start and r.t_end <= f.t_start]
 
 
 def test_a_readmission_waits_once_more(lm):

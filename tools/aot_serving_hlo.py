@@ -2,7 +2,8 @@
 """Are the device programs of the benchmark's serving cells the same in
 two trees, and did compiling them get slower?  Without a chip: builds
 each serving cell's server from <tree>, lowers and compiles
-``decode_fn`` and the smallest and largest ``prefill_fn`` for a
+``decode_fn``, the smallest and largest ``prefill_fn`` and, where the
+tree has it (ISSUE 34), ``feed_fn`` at every prefill batch width for a
 described v5e, prints lowering and compile seconds per program, and
 writes them with digests of the StableHLO and of the optimized HLO
 (whole, and with what carries file paths and line numbers taken out:
@@ -93,9 +94,14 @@ for cellname in CELLS:
     pools = [{k: sds(v) for k, v in d.items()} for d in srv._pools]
     B, Mx, W = sv["num_slots"], srv._M, 2
     bk = sv["prompt_buckets"]
-    for w in ["decode", f"{bk[0]}x1", f"{bk[-1]}x{sv['max_prefill_batch']}"]:
+    feeds = [f"feed{pb}" for pb in srv._pbatches] if getattr(srv, "_feed_fn", None) else []
+    for w in ["decode", f"{bk[0]}x1", f"{bk[-1]}x{sv['max_prefill_batch']}"] + feeds:
         t = time.time()
-        if w == "decode":
+        if w.startswith("feed"):
+            # a prefill call's first tokens into the decode program's token feed
+            pb = int(w[4:])
+            low = srv._feed_fn.lower(S(srv._prev.shape, jnp.int32), S((pb,), jnp.int32), S((pb,), jnp.int32))
+        elif w == "decode":
             # the step before's result (tokens, then the model's step counters) comes first
             args = (S(srv._prev.shape, jnp.int32), S((B, 1), jnp.int32), S((B, 1), jnp.int32), S((B, Mx), jnp.int32), S((B, 1), jnp.bool_), S((B, W), jnp.uint32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.bool_))
             low = srv._decode_fn.lower(pv, pools, *args)
@@ -131,6 +137,7 @@ for cellname in CELLS:
             "stablehlo_less_kernel_payload_sha": hashlib.sha256(nolines.encode()).hexdigest()[:16],
             "stablehlo_bytes": len(shlo), "optimized_sha": hashlib.sha256(opt.encode()).hexdigest()[:16],
             "optimized_less_metadata_sha": hashlib.sha256(opt_nometa.encode()).hexdigest()[:16],
+            "optimized_bytes": len(opt_nometa), "branches_or_loops": len(re.findall(r"\b(conditional|while)\(", opt_nometa)),
             "custom_calls": opt.count("tpu_custom_call"), "temp_bytes": ma.temp_size_in_bytes, "arg_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes, "code_bytes": ma.generated_code_size_in_bytes,
             "flops": c.cost_analysis().get("flops", 0)}
