@@ -24,6 +24,16 @@ layers that attend).
 For a model without such state none of this runs: the server builds,
 stages and dispatches exactly what it did.
 
+A latent-attention model with no per-slot state at all (every layer
+pages a latent row a token) is served as a K/V model is, pools and
+migration included, with one limit of its own: a multi-token step
+expands K and V from its own block and attends over that alone, so it
+has to start its sequence.  Such a model says so
+(``prefill_starts_sequences_only()``), and prefix sharing and
+speculation, which run a multi-token step from the middle of a
+sequence, raise :class:`MidSequenceStepUnsupported` at construction:
+under a trace that step would serve NaN, it cannot raise.
+
 Apart from the state: a model whose ``step_counters()`` names int32
 counters returns them as ``forward_paged``'s third value; the decode
 program hands them back in the same vector as the sampled tokens (one
@@ -34,7 +44,8 @@ those (``GenerationServer._build_programs``).
 """
 from __future__ import annotations
 
-__all__ = ["RecurrentStateUnsupported", "is_stateful", "check_features",
+__all__ = ["RecurrentStateUnsupported", "MidSequenceStepUnsupported",
+           "is_stateful", "starts_sequences_only", "check_features",
            "refuse_migration", "pool_bytes"]
 
 
@@ -43,14 +54,40 @@ class RecurrentStateUnsupported(NotImplementedError):
     per-slot recurrent state."""
 
 
+class MidSequenceStepUnsupported(NotImplementedError):
+    """A feature that runs a multi-token step from the middle of a
+    sequence was asked of a model whose multi-token steps attend over
+    their own block only."""
+
+
 def is_stateful(model) -> bool:
     return bool(getattr(model, "has_recurrent_state", lambda: False)())
+
+
+def starts_sequences_only(model) -> bool:
+    return bool(getattr(model, "prefill_starts_sequences_only",
+                        lambda: False)())
 
 
 def check_features(model, prefix_cache, draft_model) -> bool:
     """Refuse what cannot hold for ``model``; returns whether it keeps
     recurrent state."""
     if not is_stateful(model):
+        for m in (model, draft_model):
+            if m is None or not starts_sequences_only(m):
+                continue
+            if prefix_cache:
+                raise MidSequenceStepUnsupported(
+                    "prefix_cache=True: a warm admission prefills the "
+                    "suffix behind a shared prefix, and this model's "
+                    "latent layers attend over the fresh block only "
+                    "(needs latent prefill over earlier pages)")
+            if draft_model is not None:
+                raise MidSequenceStepUnsupported(
+                    "speculative decoding: verification scores several "
+                    "tokens from the middle of a sequence, and this "
+                    "model's latent layers attend over the fresh block "
+                    "only (needs latent prefill over earlier pages)")
         return False
     if prefix_cache:
         raise RecurrentStateUnsupported(

@@ -12,6 +12,9 @@ from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, RMSNorm,
     llama_tiny, llama_7b, llama_13b,
 )
+from .pangu_ultra_moe import (  # noqa: F401
+    PanguUltraMoEConfig, PanguUltraMoEForCausalLM, pangu_ultra_moe_tiny,
+)
 from .transformer import (  # noqa: F401
     CrossEntropyCriterion, TransformerConfig, TransformerModel,
     greedy_translate, transformer_base, transformer_big, transformer_tiny,
@@ -25,6 +28,8 @@ __all__ = [
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
     "LlamaConfig", "LlamaForCausalLM", "LlamaModel", "RMSNorm",
     "llama_tiny", "llama_7b", "llama_13b",
+    "PanguUltraMoEConfig", "PanguUltraMoEForCausalLM",
+    "pangu_ultra_moe_tiny",
     "CrossEntropyCriterion", "TransformerConfig", "TransformerModel",
     "greedy_translate", "transformer_base", "transformer_big",
     "transformer_tiny",

@@ -15,7 +15,9 @@ Per layer (pre-norm RMSNorm blocks, untied head):
   values: a recurrent state ``[slots, H, d_k, d_v]`` float32 and the
   convolutions' last three inputs ``[slots, 3, 3, H*d_k]``, fixed in
   size whatever the context.
-- **MLA** (latent attention, no rotary) in the fourth: the cache holds
+- **MLA** (latent attention, no rotary: :class:`LatentAttention`, which
+  the Pangu Ultra MoE model shares with a low-rank query and rotary)
+  in the fourth: the cache holds
   one latent row ``[c | k_pe]`` per token for ALL heads, in pages
   ``[num_blocks, block, 1, 640]`` (576 values padded to whole lane
   tiles, so that a page is the ``paged_attention`` kernel's page).
@@ -55,7 +57,7 @@ from ...nn.layer.layers import Layer
 from ...nn.layer.moe import DroplessMoELayer, _swiglu
 
 __all__ = ["KimiLinearConfig", "KimiLinearForCausalLM", "kimi_linear_tiny",
-           "kda_chunked", "kda_step", "short_conv"]
+           "LatentAttention", "kda_chunked", "kda_step", "short_conv"]
 
 F32 = jnp.float32
 _HP = jax.lax.Precision.HIGHEST
@@ -97,14 +99,6 @@ class KimiLinearConfig:
 
     def is_moe(self, l: int) -> bool:
         return l >= self.first_k_dense_replace
-
-    @property
-    def latent_width(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def latent_page_width(self) -> int:
-        return -(-self.latent_width // 128) * 128
 
 
 def kimi_linear_tiny(**kw) -> KimiLinearConfig:
@@ -256,12 +250,35 @@ def kda_chunked(S, q, k, v, g, beta, chunk: Optional[int] = None):
     return S, O.reshape((B, nc * chunk) + O.shape[3:])[:, :L]
 
 
-def _attend(q, k, v, scale, chunk: int = 512):
+# the largest float32 score block ``[B, H, chunk, S]`` of ``_attend``:
+# 4 rows x 32 heads x 512 queries x 3,072 keys
+_SCORE_BLOCK_BYTES = 3 * 2 ** 28
+
+
+def _rope_half(x, positions, theta: float):
+    """Rotary embedding over the whole head, rotate-half pairing (dim i
+    with dim i + D/2), on ``x`` [B, S, H, D] in float32."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = positions[:, :, None].astype(F32) * freq            # [B, S, D/2]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _attend(q, k, v, scale, chunk: Optional[int] = None):
     """Causal attention of a fresh block, float32 scores, in plain XLA.
     ``q, k`` [B, S, H, Dk], ``v`` [B, S, H, Dv]: the two head sizes may
     differ.  Queries go ``chunk`` at a time (``lax.map`` serialises
-    them), so that one ``[B, H, chunk, S]`` score block is live."""
+    them), so that one ``[B, H, chunk, S]`` score block is live: 512
+    queries, halved while that block is over ``_SCORE_BLOCK_BYTES``
+    (at 128 heads, 2 rows and 3,072 keys: 256 queries at a time)."""
     B, S, H, _ = q.shape
+    if chunk is None:
+        chunk = 512
+        while chunk > 16 and 4 * B * H * chunk * S > _SCORE_BLOCK_BYTES:
+            chunk //= 2
     k_pos = jnp.arange(S)[None, :]
 
     def block(qc, q0):
@@ -390,26 +407,104 @@ class KimiDeltaAttention(_Params):
         return jnp.dot(o.reshape(B, S, H * dk), v_(self.o_proj)), cache
 
 
-class KimiLatentAttention(_Params):
-    """MLA without rotary (module doc): expanded for a fresh block,
-    absorbed over the latent pages for a decode step."""
+class LatentAttention(_Params):
+    """Latent attention (MLA): expanded for a fresh block, absorbed
+    over the latent pages for a decode step.  One module for the
+    models that cache a latent row ``[c | k_pe]`` a token, all heads
+    over that one row; ``c`` names the widths (``hidden_size``,
+    ``num_attention_heads``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``kv_lora_rank``, ``rms_norm_eps``,
+    ``initializer_range``).
 
-    def __init__(self, c: KimiLinearConfig):
+    ``q_lora_rank`` None: a full-rank ``q_proj`` (Kimi-Linear).  An
+    int: ``q = RMSNorm(x q_a_proj; q_a_norm) q_b_proj`` (DeepSeek-V3,
+    Pangu Ultra MoE).  ``rope_theta`` None: the ``qk_rope_head_dim``
+    dims are plain dims (Kimi-Linear's ``mla_use_nope``).  A float:
+    they take rotary at the token's position (rotate-half), ``q_pe``
+    per head and ``k_pe`` once a token, BEFORE the page write and the
+    absorb, so that the page holds the rotated ``k_pe`` and a decode
+    step rotates its query alone."""
+
+    def __init__(self, c, q_lora_rank: Optional[int] = None,
+                 rope_theta: Optional[float] = None):
         super().__init__()
         self.config = c
         self._std = c.initializer_range
+        self.q_lora_rank, self.rope_theta = q_lora_rank, rope_theta
         h, nh = c.hidden_size, c.num_attention_heads
         dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
-        self.q_proj = self._mk(h, nh * (dn + dr))
-        self.kv_a_proj = self._mk(h, c.latent_width)
+        self.latent_width = c.kv_lora_rank + dr
+        self.page_width = -(-self.latent_width // 128) * 128
+        if q_lora_rank is None:
+            self.q_proj = self._mk(h, nh * (dn + dr))
+        else:
+            self.q_a_proj = self._mk(h, q_lora_rank)
+            self.q_a_norm = self._mk(q_lora_rank, one=True)
+            self.q_b_proj = self._mk(q_lora_rank, nh * (dn + dr))
+        self.kv_a_proj = self._mk(h, self.latent_width)
         self.kv_a_norm = self._mk(c.kv_lora_rank, one=True)
         self.kv_b_proj = self._mk(c.kv_lora_rank, nh * (dn + dv))
         self.o_proj = self._mk(nh * dv, h)
 
     def init_cache(self, num_blocks: int, block_size: int, dtype):
         return {"latent": jnp.zeros(
-            (num_blocks, block_size, 1, self.config.latent_page_width),
-            dtype)}
+            (num_blocks, block_size, 1, self.page_width), dtype)}
+
+    def _project(self, x, positions):
+        """(q [B, S, nh, dn + dr], the normalised latent [B, S, kvr],
+        k_pe [B, S, dr]); with rotary both ``pe`` parts come back
+        rotated."""
+        c = self.config
+        nh, kvr = c.num_attention_heads, c.kv_lora_rank
+        dn, dr = c.qk_nope_head_dim, c.qk_rope_head_dim
+        B, S = x.shape[:2]
+        v_ = lambda p: p._value
+        if self.q_lora_rank is None:
+            q = jnp.dot(x, v_(self.q_proj))
+        else:
+            cq = _rms(jnp.dot(x, v_(self.q_a_proj)), v_(self.q_a_norm),
+                      c.rms_norm_eps).astype(x.dtype)
+            q = jnp.dot(cq, v_(self.q_b_proj))
+        q = q.reshape(B, S, nh, dn + dr)
+        kva = jnp.dot(x, v_(self.kv_a_proj))
+        lat = _rms(kva[..., :kvr], v_(self.kv_a_norm),
+                   c.rms_norm_eps).astype(x.dtype)
+        kpe = kva[..., kvr:]
+        if self.rope_theta is not None:
+            rot = lambda t: _rope_half(t.astype(F32), positions,
+                                       self.rope_theta).astype(x.dtype)
+            q = jnp.concatenate([q[..., :dn], rot(q[..., dn:])], -1)
+            kpe = rot(kpe[:, :, None])[:, :, 0]
+        return q, lat, kpe
+
+    def _kv_b(self):
+        c = self.config
+        return self.kv_b_proj._value.reshape(
+            c.kv_lora_rank, c.num_attention_heads,
+            c.qk_nope_head_dim + c.v_head_dim)
+
+    def _expanded(self, q, lat, kpe):
+        """Causal attention of a fresh block with K and V expanded from
+        the latent: [B, S, nh, dv]."""
+        c = self.config
+        nh, dn, dr = (c.num_attention_heads, c.qk_nope_head_dim,
+                      c.qk_rope_head_dim)
+        B, S = lat.shape[:2]
+        kv = jnp.einsum("bsc,chd->bshd", lat, self._kv_b()).astype(
+            lat.dtype)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            kpe[:, :, None], (B, S, nh, dr))], -1)
+        # not the flash kernel: it takes ONE head size of 64, 128
+        # or 256 for q, k and v, and padded to 256 it hung a v5e
+        # once in ~100k calls (PERF.md, PR 27)
+        return _attend(q, k, kv[..., dn:], (dn + dr) ** -0.5)
+
+    def forward_block(self, x, positions):
+        """A fresh block from position 0 with no cache: what
+        :meth:`forward_paged` gives for it, nothing written."""
+        B, S = x.shape[:2]
+        o = self._expanded(*self._project(x, positions))
+        return jnp.dot(o.reshape(B, S, -1), self.o_proj._value)
 
     def forward_paged(self, x, positions, cache, block_tables, write_mask):
         from ...ops.pallas import registry as _kreg
@@ -417,16 +512,12 @@ class KimiLatentAttention(_Params):
         nh, kvr = c.num_attention_heads, c.kv_lora_rank
         dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         B, S = x.shape[:2]
-        v_ = lambda p: p._value
         pool = cache["latent"]
         bs, wp = pool.shape[1], pool.shape[-1]
-        q = jnp.dot(x, v_(self.q_proj)).reshape(B, S, nh, dn + dr)
-        kva = jnp.dot(x, v_(self.kv_a_proj))
-        lat = _rms(kva[..., :kvr], v_(self.kv_a_norm),
-                   c.rms_norm_eps).astype(x.dtype)
+        q, lat, kpe = self._project(x, positions)
         row = jnp.concatenate(
-            [lat, kva[..., kvr:],
-             jnp.zeros((B, S, wp - c.latent_width), x.dtype)], -1)
+            [lat, kpe,
+             jnp.zeros((B, S, wp - self.latent_width), x.dtype)], -1)
         # one latent row per token into its page; masked writes divert
         # to the trash block (0, 0), as the K/V pools' do
         blk = jnp.take_along_axis(block_tables,
@@ -436,7 +527,6 @@ class KimiLatentAttention(_Params):
         pool = pool.at[blk, off, 0].set(
             row.reshape(B * S, wp).astype(pool.dtype))
         scale = (dn + dr) ** -0.5
-        kvb = v_(self.kv_b_proj).reshape(kvr, nh, dn + dv)
         if S > 1:
             # a fresh block, expanded and causal: it attends over
             # itself only, so it has to start its sequence
@@ -446,14 +536,7 @@ class KimiLatentAttention(_Params):
                 raise ValueError(
                     "latent attention over a block of tokens takes the "
                     "block from position 0 (no suffix prefill)")
-            kv = jnp.einsum("bsc,chd->bshd", lat, kvb).astype(x.dtype)
-            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-                kva[:, :, None, kvr:], (B, S, nh, dr))], -1)
-            v = kv[..., dn:]
-            # not the flash kernel: it takes ONE head size of 64, 128
-            # or 256 for q, k and v, and padded to 256 it hung a v5e
-            # once in ~100k calls (PERF.md, PR 27)
-            o = _attend(q, k, v, scale)
+            o = self._expanded(q, lat, kpe)
             # under a trace nothing can raise: a row that starts
             # mid-sequence reads NaN, not a plausible wrong answer
             o = jnp.where(fresh[:, None, None, None], o, jnp.nan)
@@ -462,11 +545,12 @@ class KimiLatentAttention(_Params):
             # q_pe_h], all heads over the one latent row per token
             # (K = V = the latent pool; the kernel's own 1/sqrt(width)
             # is undone in the query), o_h = W_uv_h sum p c
+            kvb = self._kv_b()
             qa = jnp.einsum("bhd,chd->bhc", q[:, 0, :, :dn],
                             kvb[..., :dn], preferred_element_type=F32)
             qt = jnp.concatenate(
                 [qa, q[:, 0, :, dn:].astype(F32),
-                 jnp.zeros((B, nh, wp - c.latent_width), F32)], -1)
+                 jnp.zeros((B, nh, wp - self.latent_width), F32)], -1)
             qt = (qt * (scale * wp ** 0.5)).astype(pool.dtype)
             ol = _kreg.dispatch("paged_attention", qt[:, None], pool, pool,
                                 None, None, block_tables, positions, 1)
@@ -474,7 +558,7 @@ class KimiLatentAttention(_Params):
             o = jnp.einsum("bhc,chd->bhd", ol, kvb[..., dn:],
                            preferred_element_type=F32
                            ).astype(x.dtype)[:, None]
-        return (jnp.dot(o.reshape(B, S, nh * dv), v_(self.o_proj)),
+        return (jnp.dot(o.reshape(B, S, nh * dv), self.o_proj._value),
                 {"latent": pool})
 
 
@@ -499,7 +583,7 @@ class KimiDecoderLayer(_Params):
         self.input_layernorm = self._mk(c.hidden_size, one=True)
         self.post_attention_layernorm = self._mk(c.hidden_size, one=True)
         self.is_mla, self.is_moe = c.is_mla(l), c.is_moe(l)
-        self.self_attn = (KimiLatentAttention(c) if self.is_mla
+        self.self_attn = (LatentAttention(c, None, None) if self.is_mla
                           else KimiDeltaAttention(c))
         if self.is_moe:
             self.mlp = DroplessMoELayer(
@@ -568,6 +652,15 @@ class KimiLinearForCausalLM(_Params):
         migration)."""
         return not all(self.config.is_mla(l)
                        for l in range(self.config.num_hidden_layers))
+
+    def prefill_starts_sequences_only(self) -> bool:
+        """The latent layers' multi-token step attends over its own
+        block only, so it has to start its sequence: for a model of
+        latent layers alone (no per-slot state, nothing of the above
+        refused on that ground) the server refuses prefix sharing and
+        speculation on this one."""
+        return any(self.config.is_mla(l)
+                   for l in range(self.config.num_hidden_layers))
 
     def step_counters(self) -> Tuple[str, ...]:
         """What ``forward_paged``'s third value counts, summed over the

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from ...framework.core import Tensor
 from ...nn.layer.moe import DroplessMoELayer, _swiglu
-from .kimi_linear import _Params, _rms, short_conv
+from .kimi_linear import _Params, _rms, _rope_half, short_conv
 from .llama import paged_write_attend
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny"]
@@ -108,18 +108,6 @@ def lfm2_moe_tiny(**kw) -> Lfm2MoeConfig:
              max_position_embeddings=128, compute_dtype="float32")
     d.update(kw)
     return Lfm2MoeConfig(**d)
-
-
-def _rope_half(x, positions, theta: float):
-    """Rotary embedding over the whole head, rotate-half pairing (dim i
-    with dim i + D/2), on ``x`` [B, S, H, D] in float32."""
-    d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
-    ang = positions[:, :, None].astype(F32) * freq            # [B, S, D/2]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
 class Lfm2ShortConv(_Params):

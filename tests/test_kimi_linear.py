@@ -527,3 +527,170 @@ def test_chunked_queries_attend_like_one_block():
     np.testing.assert_allclose(KL._attend(q, k, v, 0.2, chunk=16),
                                KL._attend(q, k, v, 0.2, chunk=64),
                                atol=1e-6)
+
+
+# ---------------------------------------------------------------------
+# the latent module is shared with Pangu Ultra MoE since PR 35: what
+# must not move for the Kimi cell
+# ---------------------------------------------------------------------
+class _ParentLatentAttention(KL._Params):
+    """``KimiLatentAttention`` as the parent of PR 35 had it, kept here
+    verbatim: what the shared module must equal."""
+
+    def __init__(self, c):
+        super().__init__()
+        self.config = c
+        self._std = c.initializer_range
+        h, nh = c.hidden_size, c.num_attention_heads
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        self.q_proj = self._mk(h, nh * (dn + dr))
+        self.kv_a_proj = self._mk(h, (c.kv_lora_rank + c.qk_rope_head_dim))
+        self.kv_a_norm = self._mk(c.kv_lora_rank, one=True)
+        self.kv_b_proj = self._mk(c.kv_lora_rank, nh * (dn + dv))
+        self.o_proj = self._mk(nh * dv, h)
+
+    def init_cache(self, num_blocks: int, block_size: int, dtype):
+        return {"latent": jnp.zeros(
+            (num_blocks, block_size, 1, 640 if self.config.kv_lora_rank == 512 else 128),
+            dtype)}
+
+    def forward_paged(self, x, positions, cache, block_tables, write_mask):
+        from paddle_tpu.ops.pallas import registry as _kreg
+        c = self.config
+        nh, kvr = c.num_attention_heads, c.kv_lora_rank
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        B, S = x.shape[:2]
+        v_ = lambda p: p._value
+        pool = cache["latent"]
+        bs, wp = pool.shape[1], pool.shape[-1]
+        q = jnp.dot(x, v_(self.q_proj)).reshape(B, S, nh, dn + dr)
+        kva = jnp.dot(x, v_(self.kv_a_proj))
+        lat = KL._rms(kva[..., :kvr], v_(self.kv_a_norm),
+                   c.rms_norm_eps).astype(x.dtype)
+        row = jnp.concatenate(
+            [lat, kva[..., kvr:],
+             jnp.zeros((B, S, wp - (c.kv_lora_rank + c.qk_rope_head_dim)), x.dtype)], -1)
+        # one latent row per token into its page; masked writes divert
+        # to the trash block (0, 0), as the K/V pools' do
+        blk = jnp.take_along_axis(block_tables,
+                                  (positions // bs).astype(jnp.int32), 1)
+        blk = jnp.where(write_mask, blk, 0).reshape(-1)
+        off = jnp.where(write_mask, positions % bs, 0).reshape(-1)
+        pool = pool.at[blk, off, 0].set(
+            row.reshape(B * S, wp).astype(pool.dtype))
+        scale = (dn + dr) ** -0.5
+        kvb = v_(self.kv_b_proj).reshape(kvr, nh, dn + dv)
+        if S > 1:
+            # a fresh block, expanded and causal: it attends over
+            # itself only, so it has to start its sequence
+            fresh = positions[:, 0] == 0
+            if not isinstance(fresh, jax.core.Tracer) \
+                    and not bool(fresh.all()):
+                raise ValueError(
+                    "latent attention over a block of tokens takes the "
+                    "block from position 0 (no suffix prefill)")
+            kv = jnp.einsum("bsc,chd->bshd", lat, kvb).astype(x.dtype)
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                kva[:, :, None, kvr:], (B, S, nh, dr))], -1)
+            v = kv[..., dn:]
+            # not the flash kernel: it takes ONE head size of 64, 128
+            # or 256 for q, k and v, and padded to 256 it hung a v5e
+            # once in ~100k calls (PERF.md, PR 27)
+            o = KL._attend(q, k, v, scale)
+            # under a trace nothing can raise: a row that starts
+            # mid-sequence reads NaN, not a plausible wrong answer
+            o = jnp.where(fresh[:, None, None, None], o, jnp.nan)
+        else:
+            # one query per row, absorbed: q~_h = [W_uk_h^T q_nope_h |
+            # q_pe_h], all heads over the one latent row per token
+            # (K = V = the latent pool; the kernel's own 1/sqrt(width)
+            # is undone in the query), o_h = W_uv_h sum p c
+            qa = jnp.einsum("bhd,chd->bhc", q[:, 0, :, :dn],
+                            kvb[..., :dn], preferred_element_type=jnp.float32)
+            qt = jnp.concatenate(
+                [qa, q[:, 0, :, dn:].astype(jnp.float32),
+                 jnp.zeros((B, nh, wp - (c.kv_lora_rank + c.qk_rope_head_dim)), jnp.float32)], -1)
+            qt = (qt * (scale * wp ** 0.5)).astype(pool.dtype)
+            ol = _kreg.dispatch("paged_attention", qt[:, None], pool, pool,
+                                None, None, block_tables, positions, 1)
+            ol = ol.reshape(B, nh, wp)[..., :kvr]
+            o = jnp.einsum("bhc,chd->bhd", ol, kvb[..., dn:],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)[:, None]
+        return (jnp.dot(o.reshape(B, S, nh * dv), v_(self.o_proj)),
+                {"latent": pool})
+
+
+def test_the_shared_latent_module_is_the_parents(bench):
+    """``LatentAttention(c, None, None)``: the parent's parameters
+    under the parent's names, and for a fresh block, a padded batch
+    and a run of decode steps the parent's pages bit for bit (a write
+    moves data) and the parent's outputs to 1e-5 of their largest value
+    in float32 (eagerly and under ``jit``): a few roundings, so another
+    order of the softmax's sums passes and bfloat16 in place of float32
+    (4e-3 of it) does not.  Today the outputs are bit-equal too."""
+    model, _ = build(bench, toy_cfg())
+    attn = model.model.layers[3].self_attn
+    assert type(attn) is KL.LatentAttention
+    assert (attn.q_lora_rank, attn.rope_theta) == (None, None)
+    old = _ParentLatentAttention(model.config)
+    names = ("q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj", "o_proj")
+    assert [n for n, _ in attn.named_parameters()] == list(names) \
+        == [n for n, _ in old.named_parameters()]
+    for n in names:
+        assert getattr(old, n)._value.shape == getattr(attn, n)._value.shape
+        getattr(old, n)._value = getattr(attn, n)._value
+    r = np.random.RandomState(8)
+    x = jnp.asarray(r.randn(2, 11, 64), jnp.float32)
+    tbl = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    pos = jnp.broadcast_to(jnp.arange(11, dtype=jnp.int32), (2, 11))
+    wm = jnp.asarray(np.arange(11)[None] < np.asarray([11, 7])[:, None])
+    same = lambda a, b: jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda u, v: bool((u == v).all()), a, b))
+
+    def close(got, want):
+        top = float(jnp.abs(want[0]).max())
+        np.testing.assert_allclose(got[0], want[0], rtol=0, atol=1e-5 * top)
+        return top > 0.01 and same(got[1], want[1])
+    for run in (lambda f: f, jax.jit):
+        cache = attn.init_cache(9, 4, jnp.float32)
+        assert same(cache, old.init_cache(9, 4, jnp.float32))
+        got = run(attn.forward_paged)(x, pos, cache, tbl, wm)
+        want = run(old.forward_paged)(x, pos, cache, tbl, wm)
+        assert close(got, want)
+        cache = got[1]
+        for t in (11, 12):
+            step = (x[:, t - 11:t - 10], jnp.full((2, 1), t, jnp.int32),
+                    cache, tbl, jnp.ones((2, 1), bool))
+            got = run(attn.forward_paged)(*step)
+            assert close(got, run(old.forward_paged)(*step))
+            cache = got[1]
+
+
+def test_the_latent_layers_shapes_at_the_published_widths():
+    """The Kimi cell's latent layers keep their parameter names and
+    shapes, their pages keep 640 lanes, and a hybrid still says it
+    keeps per-slot state (so the server refuses on that ground first);
+    a model of latent layers alone says it does not, and that its
+    prefill starts sequences only."""
+    from paddle_tpu.framework.core import abstract_init
+    from paddle_tpu.text.models import KimiLinearConfig, kimi_linear_tiny
+    with abstract_init():
+        attn = KL.LatentAttention(KimiLinearConfig(), None, None)
+    assert {n: tuple(p._value.shape)
+            for n, p in attn.named_parameters()} == {
+        "q_proj": (2304, 32 * 192), "kv_a_proj": (2304, 576),
+        "kv_a_norm": (512,), "kv_b_proj": (512, 32 * 256),
+        "o_proj": (32 * 128, 2304)}
+    assert (attn.latent_width, attn.page_width) == (576, 640)
+    shape = jax.eval_shape(
+        lambda: attn.init_cache(5, 16, jnp.bfloat16))["latent"]
+    assert (shape.shape, shape.dtype) == ((5, 16, 1, 640), jnp.bfloat16)
+    hybrid = KimiLinearForCausalLM(kimi_linear_tiny())
+    assert hybrid.has_recurrent_state()
+    latent = KimiLinearForCausalLM(kimi_linear_tiny(
+        full_attn_layers=(1, 2, 3, 4)))
+    assert not latent.has_recurrent_state()
+    assert latent.prefill_starts_sequences_only()
+    assert [set(d) for d in latent.init_paged_cache(9, 4)] == \
+        [{"latent"}] * 4

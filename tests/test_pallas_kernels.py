@@ -623,6 +623,13 @@ _PAGED_CASES = {
     "wide_three_blocks_of_pages": dict(
         lengths=[160, 67, 129, 1], G=2, R=2, D=64, bs=4, M=40,
         flat_pool=True),
+    # 128 query heads over ONE latent "kv head" of 640 lanes (PR 35:
+    # the Pangu Ultra MoE cell's absorbed decode, K = V = the pool)
+    "latent128x1x640_block16_bf16": dict(
+        lengths=[1, 16, 17, 300], G=1, R=128, D=640, bs=16, M=20,
+        dtype=jnp.bfloat16),
+    "latent128x1x640_block16_f32": dict(
+        lengths=[33, 2], G=1, R=128, D=640, bs=16, M=4),
     # slots with the write mask off: position 0, an all-zero table
     "inactive_rows_zero_table": dict(
         lengths=[1, 11, 1], bs=8, M=2,

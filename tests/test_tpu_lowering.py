@@ -125,6 +125,15 @@ def _kernel_cases():
             [np.zeros((8192, 16, 512), jnp.bfloat16)] * 2 + [
                 np.zeros((256, 1, 32, 64), jnp.bfloat16),
                 np.zeros((256, 112), i32), np.zeros((256, 1), i32)]),
+        # the same kernel at 128 query heads over the one latent "kv
+        # head" of 640 lanes (PR 35: the Pangu Ultra MoE cell, 128
+        # slots x 256 blocks of 16), K and V the SAME pool
+        "paged_attention@latent128": (
+            lambda pool, q, tbl, pos: kreg.dispatch(
+                "paged_attention", q, pool, pool, None, None, tbl, pos, 1),
+            [np.zeros((32769, 16, 1, 640), jnp.bfloat16),
+             np.zeros((128, 1, 128, 640), jnp.bfloat16),
+             np.zeros((128, 256), i32), np.zeros((128, 1), i32)]),
         "segment_sum": (
             lambda gr, inv: kreg.dispatch("segment_sum", gr, inv,
                                           num_segments=256),
