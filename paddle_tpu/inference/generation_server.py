@@ -134,7 +134,13 @@ iteration earlier.  Each is a
 trace and a row of ``observability.timeline.spans("serve")``; the rows
 of ``serve.admit`` carry ``queue_wait_ms`` (one value per admitted
 sequence), those of ``serve.prefill.stage`` the padded ``batch`` x
-``bucket`` and the useful ``tokens``, those of
+``bucket``, the useful ``tokens`` and, for a model that answers
+``prefill_attn_pairs(batch, bucket)`` (the latent models, whose
+prefill attention skips the keys no query of a group may see),
+``attn_pairs_share``: the share of the square's (query, key) pairs
+the call multiplies (``stats()`` adds both counts up as
+``prefill_attn_pairs_multiplied`` and ``prefill_attn_pairs_square``;
+equal: the mechanism does not engage), those of
 ``serve.decode.dispatch`` and ``serve.prefill.fetch`` ``overlapped``
 (0 or 1).  ``decode_ms`` / ``prefill_ms`` are read off the dispatch and
 fetch spans' own clock reads and count no instant twice: a decode step
@@ -160,7 +166,7 @@ from __future__ import annotations
 import queue as _queue
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -325,6 +331,8 @@ class _UnreadPrefill(NamedTuple):
     tokens: int            # real prompt tokens in it
     sampled: bool          # a row of it samples
     first: object          # each row's first token, still on the device
+    attn_pairs: Tuple[int, int]   # (query, key) pairs multiplied, and
+    #                               of the whole square (0, 0: no plan)
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -521,6 +529,10 @@ class GenerationServer:
         # what the model's decode step counts, fetched behind the tokens
         self._step_counters = tuple(
             getattr(self._model, "step_counters", tuple)())
+        # (multiplied, whole square) (query, key) pairs of a prefill
+        # call's attention, where the model can say
+        self._prefill_attn_pairs = getattr(
+            self._model, "prefill_attn_pairs", lambda batch, bucket: (0, 0))
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -560,6 +572,12 @@ class GenerationServer:
             # prefill calls read behind a decode dispatch that followed
             # them
             "prefills_overlapped": 0,
+            # (query, key) pairs the prefill calls' attention
+            # multiplied, and those of the whole squares, of a model
+            # that answers ``prefill_attn_pairs``: equal where no key
+            # is skipped
+            "prefill_attn_pairs_multiplied": 0,
+            "prefill_attn_pairs_square": 0,
             # decode, verify and prefill dispatches that held a
             # sampling row (a decode or verify dispatch that held none
             # ran the sampler's argmax alone)
@@ -1499,6 +1517,9 @@ class GenerationServer:
                     seq.rt.begin("prefill")
             tokens = int(length.sum())
             ph.set(bucket=bucket, batch=B, tokens=tokens)
+            pairs = self._prefill_attn_pairs(B, bucket)
+            if pairs[1]:
+                ph.set(attn_pairs_share=pairs[0] / pairs[1])
         if self._stateful and start.any():
             raise _rs.RecurrentStateUnsupported(
                 "a prefill that starts mid-sequence: the latent layers "
@@ -1516,7 +1537,8 @@ class GenerationServer:
                 self._prev = self._feed_fn(self._prev, first,
                                            self._feed_slots(seqs, B))
         self._prefills.append(_UnreadPrefill(
-            seqs, disp.t0, bucket, tokens, bool(do_sample.any()), first))
+            seqs, disp.t0, bucket, tokens, bool(do_sample.any()), first,
+            pairs))
         if self._spec:
             self._read_unread()
 
@@ -1538,6 +1560,10 @@ class GenerationServer:
                 self._stats["prefill_ms"] += dt_ms
                 self._stats["prefill_batches"] += 1
                 self._stats["prefills_overlapped"] += overlapped
+                self._stats["prefill_attn_pairs_multiplied"] += \
+                    call.attn_pairs[0]
+                self._stats["prefill_attn_pairs_square"] += \
+                    call.attn_pairs[1]
                 self._stats["sampled_steps"] += call.sampled
                 self._stats["prefill_bucket_hits"][bucket] = \
                     self._stats["prefill_bucket_hits"].get(bucket, 0) \

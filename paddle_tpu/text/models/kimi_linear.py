@@ -21,7 +21,9 @@ Per layer (pre-norm RMSNorm blocks, untied head):
   one latent row ``[c | k_pe]`` per token for ALL heads, in pages
   ``[num_blocks, block, 1, 640]`` (576 values padded to whole lane
   tiles, so that a page is the ``paged_attention`` kernel's page).
-  Prefill expands K and V from the latent; decode absorbs the
+  Prefill expands K and V from the latent and attends in plain XLA,
+  query chunks in groups that each take only the keys they may see
+  (:func:`_attend`, :func:`attend_plan`); decode absorbs the
   expansion into the query and the output and attends over the latent
   pages through the block table.
 - the FFN is a dense SwiGLU in the first layer and
@@ -41,7 +43,8 @@ which no write reaches); without it row i is slot i, which is what a
 decode step over every slot is.  And it returns a third value, the
 int32 counters :meth:`KimiLinearForCausalLM.step_counters` names.
 ``loops_on_device(n_tokens)`` tells the server which of its programs
-hold the expert layers' device loop.
+hold the expert layers' device loop, ``prefill_attn_pairs(batch,
+bucket)`` how much of the attention square a prefill call multiplies.
 """
 from __future__ import annotations
 
@@ -267,35 +270,62 @@ def _rope_half(x, positions, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-def _attend(q, k, v, scale, chunk: Optional[int] = None):
-    """Causal attention of a fresh block, float32 scores, in plain XLA.
-    ``q, k`` [B, S, H, Dk], ``v`` [B, S, H, Dv]: the two head sizes may
-    differ.  Queries go ``chunk`` at a time (``lax.map`` serialises
-    them), so that one ``[B, H, chunk, S]`` score block is live: 512
-    queries, halved while that block is over ``_SCORE_BLOCK_BYTES``
-    (at 128 heads, 2 rows and 3,072 keys: 256 queries at a time)."""
-    B, S, H, _ = q.shape
+def _chunk_groups(n: int) -> int:
+    """Groups of consecutive query chunks that ``_attend`` makes of
+    ``n`` chunks: the largest divisor of ``n`` up to twelve (a group is
+    a whole number of chunks; each group lays its prefix of the keys
+    out once more, so past a dozen the copies cost what the skipped
+    products save: PERF.md, PR 36)."""
+    return max(g for g in range(1, 13) if n % g == 0)
+
+
+def attend_plan(B: int, S: int, H: int, chunk: Optional[int] = None):
+    """What :func:`_attend` multiplies for queries ``[B, S, H, *]``:
+    ``(chunk, groups, pairs multiplied, pairs of the whole square)``, a
+    pair being one (query, key) of one head and row.  ``chunk``: 512
+    queries, halved while a float32 ``[B, H, chunk, S]`` score block is
+    over ``_SCORE_BLOCK_BYTES`` (at 128 heads, 2 rows and 3,072 keys:
+    256 queries at a time); all ``S`` where they fit one chunk or do
+    not divide into chunks.  With ``G`` groups ``(G + 1) / 2G`` of the
+    square is multiplied."""
     if chunk is None:
         chunk = 512
         while chunk > 16 and 4 * B * H * chunk * S > _SCORE_BLOCK_BYTES:
             chunk //= 2
-    k_pos = jnp.arange(S)[None, :]
-
-    def block(qc, q0):
-        s = jnp.einsum("bqhd,bkhd->bhqk", qc, k,
-                       preferred_element_type=F32) * scale
-        live = (q0 + jnp.arange(qc.shape[1]))[:, None] >= k_pos
-        p = jax.nn.softmax(jnp.where(live, s, -1e30), -1).astype(v.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v,
-                          preferred_element_type=F32).astype(v.dtype)
     if S <= chunk or S % chunk:
-        return block(q, 0)
-    n = S // chunk
-    out = jax.lax.map(
-        lambda t: block(*t),
-        (jnp.moveaxis(q.reshape(B, n, chunk, H, -1), 1, 0),
-         jnp.arange(n) * chunk))
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, -1)
+        chunk = S
+    groups = _chunk_groups(S // chunk)
+    per = S // groups                       # queries a group
+    seen = sum(per * per * (j + 1) for j in range(groups))
+    return chunk, groups, B * H * seen, B * H * S * S
+
+
+def _attend(q, k, v, scale, chunk: Optional[int] = None):
+    """Causal attention of a fresh block, float32 scores, in plain XLA.
+    ``q, k`` [B, S, H, Dk], ``v`` [B, S, H, Dv]: the two head sizes may
+    differ.  Queries go ``chunk`` at a time, one after another with no
+    device loop, so that one ``[B, H, chunk, keys]`` score block is
+    live, in groups of consecutive chunks (:func:`attend_plan`); a
+    chunk takes the keys up to its group's last query and no others,
+    so the keys none of them may see are never multiplied.  The
+    softmax is normalised behind the weighted sum: the division runs
+    on ``[B, chunk, H, Dv]``, not on the score block."""
+    B, S, H, _ = q.shape
+    chunk, groups, _, _ = attend_plan(B, S, H, chunk)
+    per = S // groups
+    out = []
+    for q0 in range(0, S, chunk):
+        end = (q0 // per + 1) * per
+        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, q0:q0 + chunk], k[:, :end],
+                       preferred_element_type=F32) * scale
+        live = (q0 + jnp.arange(chunk))[:, None] >= jnp.arange(end)[None, :]
+        s = jnp.where(live, s, -1e30)
+        e = jnp.exp(s - s.max(-1, keepdims=True))
+        o = jnp.einsum("bhqk,bkhd->bqhd", e.astype(v.dtype), v[:, :end],
+                       preferred_element_type=F32)
+        out.append((o / jnp.moveaxis(e.sum(-1), 1, 2)[..., None]).astype(
+            v.dtype))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, 1)
 
 
 # ---------------------------------------------------------------------
@@ -677,6 +707,16 @@ class KimiLinearForCausalLM(_Params):
         its own behind such a program (a v5e stopped on one)."""
         return any(lyr.is_moe and lyr.mlp.loops_on_device(n_tokens)
                    for lyr in self.model.layers)
+
+    def prefill_attn_pairs(self, batch: int, bucket: int):
+        """(multiplied, whole square): the (query, key) pairs that a
+        prefill call of ``batch`` rows x ``bucket`` tokens takes in its
+        latent layers; ``GenerationServer`` adds them up in ``stats()``
+        (a share of 1.0: no key is skipped)."""
+        n = sum(lyr.is_mla for lyr in self.model.layers)
+        done, square = attend_plan(
+            batch, bucket, self.config.num_attention_heads)[2:]
+        return n * done, n * square
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          num_slots: Optional[int] = None):

@@ -41,7 +41,8 @@ over its own block only, so it has to start its sequence
 sharing and speculation).  ``forward_paged`` returns the counters
 :meth:`PanguUltraMoEForCausalLM.step_counters` names as a third value;
 ``loops_on_device`` tells the server which of its programs hold the
-expert layers' device loop.
+expert layers' device loop, ``prefill_attn_pairs`` how much of the
+attention square a prefill call multiplies.
 """
 from __future__ import annotations
 
@@ -53,7 +54,8 @@ import jax.numpy as jnp
 
 from ...framework.core import Tensor
 from ...nn.layer.moe import DroplessMoELayer
-from .kimi_linear import KimiMLP, LatentAttention, _Params, _rms
+from .kimi_linear import (KimiMLP, LatentAttention, _Params, _rms,
+                          attend_plan)
 
 __all__ = ["PanguUltraMoEConfig", "PanguUltraMoEForCausalLM",
            "pangu_ultra_moe_tiny"]
@@ -245,6 +247,16 @@ class PanguUltraMoEForCausalLM(_Params):
         own behind such a program."""
         return any(lyr.is_moe and lyr.mlp.loops_on_device(n_tokens)
                    for lyr in self.model.layers)
+
+    def prefill_attn_pairs(self, batch: int, bucket: int):
+        """(multiplied, whole square): the (query, key) pairs that a
+        prefill call of ``batch`` rows x ``bucket`` tokens takes in its
+        attention layers; ``GenerationServer`` adds them up in
+        ``stats()`` (a share of 1.0: no key is skipped)."""
+        done, square = attend_plan(
+            batch, bucket, self.config.num_attention_heads)[2:]
+        n = len(self.model.layers)
+        return n * done, n * square
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          num_slots: Optional[int] = None):

@@ -529,6 +529,35 @@ def test_chunked_queries_attend_like_one_block():
                                atol=1e-6)
 
 
+def test_a_hybrid_server_counts_the_pairs_its_latent_layers_skip(
+        bench, monkeypatch):
+    """``stats()`` adds up ``attend_plan`` over the model's latent
+    layers (the KDA layers take no square): a one-block bucket reads a
+    share of 1.0, a bucket of four chunks 5/8 (the score block's limit
+    is lowered so that a toy bucket holds four), and the tokens are
+    those of the whole square."""
+    model, _ = build(bench, toy_cfg())
+    mla = sum(lyr.is_mla for lyr in model.model.layers)
+    heads = model.config.num_attention_heads
+    assert 0 < mla < len(model.model.layers)
+    short, long = _prompts(2, seed=11, lo=9, hi=14), \
+        _prompts(2, seed=12, lo=40, hi=60)
+    opts = dict(prompt_buckets=[16, 64], max_model_len=96, max_new=5)
+    want, whole = _serve(model, short + long, **opts)
+    assert whole["prefill_attn_pairs_multiplied"] \
+        == whole["prefill_attn_pairs_square"] > 0
+    monkeypatch.setattr(KL, "_SCORE_BLOCK_BYTES", 1)
+    assert model.prefill_attn_pairs(2, 16) == (mla * 2 * heads * 256,) * 2
+    got, st = _serve(model, short, **opts)
+    assert st["prefill_attn_pairs_multiplied"] \
+        == st["prefill_attn_pairs_square"] > 0
+    more, st = _serve(model, long, max_prefill_batch=1, **opts)
+    assert got + more == want
+    assert st["prefill_attn_pairs_square"] == 2 * mla * heads * 64 * 64
+    assert st["prefill_attn_pairs_multiplied"] * 8 \
+        == st["prefill_attn_pairs_square"] * 5
+
+
 # ---------------------------------------------------------------------
 # the latent module is shared with Pangu Ultra MoE since PR 35: what
 # must not move for the Kimi cell

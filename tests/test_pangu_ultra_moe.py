@@ -16,6 +16,7 @@ in bfloat16 misses by four orders (its own test)."""
 import dataclasses
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -286,6 +287,165 @@ def test_query_chunks_attend_like_one_block(chunk, limit, monkeypatch):
         assert f"f32[2,3,{limit // (4 * 2 * 3 * 96)},96]" in jaxpr
     np.testing.assert_allclose(
         kimi_linear._attend(q, k, v, 0.2, chunk=chunk), whole, atol=2e-6)
+
+
+def _qkv(S, dtype=jnp.float32, B=2, H=3, dv=16, seed=3):
+    r = np.random.RandomState(seed)
+    return (jnp.asarray(r.randn(B, S, H, 24), dtype),
+            jnp.asarray(r.randn(B, S, H, 24), dtype),
+            jnp.asarray(r.randn(B, S, H, dv), dtype))
+
+
+def _one_block(q, k, v, scale):
+    """The whole square in one block, float32 throughout: the plain
+    form ``_attend`` is held to."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    S = q.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _dots(jaxpr, times=1):
+    """Every ``dot_general`` of a jaxpr and of the loops in it:
+    (shape of its result, how often it runs)."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "dot_general":
+            out.append((e.outvars[0].aval.shape, times))
+        for name, sub in e.params.items():
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                out += _dots(inner, times * e.params.get("length", 1)
+                             if name == "jaxpr" else times)
+    return out
+
+
+# n chunks -> groups: the largest divisor of n up to twelve
+GROUPS = {1: 1, 2: 2, 3: 3, 4: 4, 6: 6, 8: 8, 12: 12, 16: 8, 32: 8}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n", [2, 3, 4, 6, 8, 12, 16, 32])
+def test_grouped_chunks_attend_like_one_block(n, dtype):
+    """Every number of chunks the serving buckets give, at the rule's
+    groups, against one block over the whole square: float32 to
+    rounding, bfloat16 to one ulp of the output's scale."""
+    from paddle_tpu.text.models import kimi_linear
+    assert kimi_linear._chunk_groups(n) == GROUPS[n]
+    q, k, v = _qkv(16 * n, jnp.dtype(dtype), H=2)
+    assert kimi_linear.attend_plan(2, 16 * n, 2, 16)[:2] == (16, GROUPS[n])
+    got = kimi_linear._attend(q, k, v, 0.2, chunk=16)
+    want = _one_block(q, k, v, 0.2)
+    assert got.dtype == q.dtype
+    atol = 2e-6 if dtype == "float32" else float(jnp.abs(want).max()) * 2 ** -7
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=atol,
+                               rtol=0)
+
+
+@pytest.mark.parametrize("n", [2, 3, 4, 6, 8, 12, 16, 32])
+def test_keys_no_query_of_a_group_may_see_are_never_multiplied(n):
+    """Not merely masked: no ``dot_general`` of group j takes more
+    than the keys up to the group's last query, and what the program
+    multiplies is what the plan counts."""
+    from paddle_tpu.text.models import kimi_linear
+    B, H, S, G = 2, 3, 16 * n, GROUPS[n]
+    q, k, v = _qkv(S, dv=20)     # no count of keys is 20
+    dots = _dots(jax.make_jaxpr(
+        lambda *a: kimi_linear._attend(*a, 0.2, chunk=16))(q, k, v).jaxpr)
+    scores = [(s, t) for s, t in dots if 20 not in s]
+    assert all(s[:3] == (B, H, 16) for s, _ in scores)
+    ends = [S // G * (j + 1) for j in range(G)]
+    # a prefix a group, its chunks one after another, no device loop
+    assert sorted(s[3] for s, _ in scores) == sorted(ends * (n // G))
+    assert len(dots) == 2 * n and all(t == 1 for _, t in dots)
+    chunk, groups, done, square = kimi_linear.attend_plan(B, S, H, 16)
+    assert (chunk, groups, square) == (16, G, B * H * S * S)
+    assert done == sum(B * H * 16 * s[3] * t for s, t in scores)
+    assert done * 2 * G == square * (G + 1)
+
+
+@pytest.mark.parametrize("S,chunk", [(96, None), (96, 96), (16, 16),
+                                     (96, 40), (50, 16), (7, None)])
+def test_one_chunk_or_no_whole_chunks_is_one_block(S, chunk):
+    """``S <= chunk`` is one group of one chunk through the same body,
+    and a length that does not divide into chunks falls back to it:
+    one pair of ``dot_general`` over all keys, no loop."""
+    from paddle_tpu.text.models import kimi_linear
+    q, k, v = _qkv(S, dv=20)
+    f = lambda *a: kimi_linear._attend(*a, 0.2, chunk=chunk)
+    jaxpr = jax.make_jaxpr(f)(q, k, v)
+    scores, summed = _dots(jaxpr.jaxpr)
+    assert scores == ((2, 3, S, S), 1) and summed[1] == 1
+    assert sorted(summed[0]) == sorted((2, 3, S, 20))
+    assert "scan" not in str(jaxpr) and "while" not in str(jaxpr)
+    assert kimi_linear.attend_plan(2, S, 3, chunk) == (
+        S, 1, 2 * 3 * S * S, 2 * 3 * S * S)
+    np.testing.assert_allclose(f(q, k, v), _one_block(q, k, v, 0.2),
+                               atol=2e-6, rtol=0)
+
+
+@pytest.mark.parametrize("B,S,H,chunk", [
+    (1, 64, 2, 16), (2, 96, 3, 16), (1, 96, 1, 32), (2, 384, 1, 32),
+    (1, 512, 2, 16), (3, 50, 2, 16), (1, 2048, 128, None),
+    (2, 3072, 128, None), (4, 2048, 32, None), (4, 512, 32, None)])
+def test_the_plan_counts_what_a_brute_force_count_finds(B, S, H, chunk):
+    """The plan against a count over the square: a pair is multiplied
+    when its key lies under the end of its query's group; every pair
+    the mask lets through is among them."""
+    from paddle_tpu.text.models import kimi_linear
+    c, G, done, square = kimi_linear.attend_plan(B, S, H, chunk)
+    assert S % c == 0 and (S // c) % G == 0
+    per = S // G
+    qs, ks = np.arange(S)[:, None], np.arange(S)[None, :]
+    multiplied = ks < (qs // per + 1) * per
+    assert multiplied[ks <= qs].all()
+    assert done == B * H * int(multiplied.sum())
+    assert square == B * H * S * S
+    if chunk is None:        # the serving shapes: the chunk is derived
+        assert 4 * B * H * c * S <= kimi_linear._SCORE_BLOCK_BYTES \
+            or c == 16
+
+
+def test_a_latent_server_counts_the_pairs_its_prefill_skips(
+        built, monkeypatch):
+    """``stats()`` adds up the plan of every prefill call, and the
+    call's share rides its ``serve.prefill.stage`` span: 1.0 where a
+    bucket is one block, under 1 where it is several chunks (the score
+    block's limit is lowered so that a toy bucket holds four)."""
+    from paddle_tpu.observability import timeline
+    from paddle_tpu.text.models import kimi_linear
+    model, _ = built
+    short, long = _prompts(2, seed=11, lo=9, hi=14), \
+        _prompts(2, seed=12, lo=40, hi=60)
+    want, _ = _serve(model, short + long, max_new=5,
+                     prompt_buckets=[16, 64], max_model_len=96)
+    monkeypatch.setattr(kimi_linear, "_SCORE_BLOCK_BYTES", 1)
+    assert model.prefill_attn_pairs(2, 16) == (5 * 2 * 4 * 16 * 16,) * 2
+    assert model.prefill_attn_pairs(1, 64) == (
+        5 * 4 * 64 * 64 * 5 // 8, 5 * 4 * 64 * 64)
+    with GenerationServer(model, num_slots=4, block_size=4,
+                          max_model_len=96, prompt_buckets=[16, 64],
+                          max_prefill_batch=1, check_replay=True) as srv:
+        t0 = time.perf_counter()     # prewarm traffic is not the session
+        got = [srv.submit(p, max_new_tokens=5).result(timeout=300)
+               for p in short]
+        st = srv.stats()
+        assert st["prefill_attn_pairs_multiplied"] \
+            == st["prefill_attn_pairs_square"] == 2 * 5 * 4 * 16 * 16
+        got += [srv.submit(p, max_new_tokens=5).result(timeout=300)
+                for p in long]
+    end = srv.stats()
+    assert got == want               # the same tokens, chunked or whole
+    done = end["prefill_attn_pairs_multiplied"] \
+        - st["prefill_attn_pairs_multiplied"]
+    square = end["prefill_attn_pairs_square"] \
+        - st["prefill_attn_pairs_square"]
+    assert square == 2 * 5 * 4 * 64 * 64 and done * 8 == square * 5
+    shares = [r.args["attn_pairs_share"]
+              for r in timeline.spans("serve", since=t0)
+              if r.name == "serve.prefill.stage"]
+    assert shares == [1.0, 1.0, 0.625, 0.625]
 
 
 def test_a_latent_block_has_to_start_its_sequence(built):

@@ -166,6 +166,17 @@ def test_counts_at_the_span_boundaries_agree_with_stats(session):
     assert len(_named(rows, "serve.decode.emit")) == st["decode_steps"]
 
 
+def test_a_model_without_an_attention_plan_counts_no_pairs(session):
+    """Only a model that answers ``prefill_attn_pairs`` (the latent
+    models) adds to the two counters or to the span's args."""
+    rows, st = session
+    assert st["prefill_batches"] > 0
+    assert (st["prefill_attn_pairs_multiplied"],
+            st["prefill_attn_pairs_square"]) == (0, 0)
+    assert not [r for r in _named(rows, "serve.prefill.stage")
+                if "attn_pairs_share" in r.args]
+
+
 def test_decode_ms_and_prefill_ms_are_read_off_the_spans(session):
     """The fetch of a decode step comes one iteration after its
     dispatch, behind the next step's dispatch and whatever prefill lay

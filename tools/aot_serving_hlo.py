@@ -14,7 +14,8 @@ beside it.
 
     JAX_PLATFORMS=cpu python3 tools/aot_serving_hlo.py <tree> <tag> <out> [cell ...]
 
-No cell named: the two Mistral cells and the Kimi cell.  Run it once
+No cell named: the two Mistral cells and the Kimi cell.  A cell named
+``cell@3072x2,512x4`` compiles those prefill programs too.  Run it once
 per tree (parent unpacked with ``git archive``, then the change), one
 run at a time so that the seconds compare, and compare the two JSON
 files: equal ``*_less_*`` digests, bytes and operation counts mean the
@@ -83,6 +84,8 @@ def sorts_of(hlo: str):
 
 
 for cellname in CELLS:
+    # "cell@3072x2,4096x1": those prefill programs beside the usual ones
+    cellname, _, more = cellname.partition("@")
     cell = M.Cell(man, cellname)
     cfg, sv = cell.config, cell.spec["server"]
     model = cell.binding().build_serving(cfg, sv["max_model_len"])
@@ -95,7 +98,7 @@ for cellname in CELLS:
     B, Mx, W = sv["num_slots"], srv._M, 2
     bk = sv["prompt_buckets"]
     feeds = [f"feed{pb}" for pb in srv._pbatches] if getattr(srv, "_feed_fn", None) else []
-    for w in ["decode", f"{bk[0]}x1", f"{bk[-1]}x{sv['max_prefill_batch']}"] + feeds:
+    for w in ["decode", f"{bk[0]}x1", f"{bk[-1]}x{sv['max_prefill_batch']}"] + feeds + [p for p in more.split(",") if p]:
         t = time.time()
         if w.startswith("feed"):
             # a prefill call's first tokens into the decode program's token feed
