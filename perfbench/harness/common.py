@@ -2,6 +2,7 @@
 the traced sub-window, the run log and the result line."""
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import shutil
@@ -84,10 +85,16 @@ class SubWindowTrace:
     client times over the part of the window BEFORE the trace
     (``t_start``), and the trace over the rest.  ``on_edge`` is called
     at start and stop (a runner reads its counters there).  Python's
-    own tracer is off: it slows the host it would measure."""
+    own tracer is off: it slows the host it would measure.  Each tracer
+    writes to a directory of its own (cell, process, serial), so that
+    two runs of one cell at once never delete each other's profile."""
+
+    _serial = itertools.count()
 
     def __init__(self, cell_name: str, on_edge=None):
-        self.dir = os.path.join(OUT_DIR, "trace", cell_name)
+        self.dir = os.path.join(
+            OUT_DIR, "trace",
+            f"{cell_name}.{os.getpid()}.{next(SubWindowTrace._serial)}")
         self.on_edge = on_edge or (lambda which: None)
         self.t_start = None      # when the profiler was asked to start
         self._th = None

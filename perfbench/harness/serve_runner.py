@@ -206,9 +206,18 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
            "setup_s": t0 - t_proc0}
     gi, gv = S.longest(itl)
     ti, tv = S.longest(ttft)
+    window = _delta(c0, c1)
+    # the server's own count of the tokens it emitted in the window,
+    # beside the client's stamps: a ratio far from 1 says the client
+    # thread stood still and stamped a backlog on the wrong side of an
+    # edge of the window
+    server_tokens = window["tokens_generated"]
     C.write_run_log(cell.name, seed, {
         "requests_submitted_in_window": len(measured),
         "requests_failed": failed, "out_tokens_in_window": out_tokens,
+        "server_tokens_in_window": server_tokens,
+        "server_to_client_tokens": (server_tokens / out_tokens
+                                    if out_tokens else None),
         "ttft_ms": {q: _ms(S.percentile(ttft, q)) for q in (50, 95)},
         "itl_ms": {q: _ms(S.percentile(itl, q)) for q in (50, 95)},
         "itl_samples": len(itl), "longest_itl_ms": _ms(gv),
@@ -219,7 +228,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
         "generator_late_ms_max": _ms(max(late)) if late else None,
         "drain_s": t_end - t1, "warmup_requests": sum(
             1 for r in reqs if r["t_submit"] < t0),
-        "window": _delta(c0, c1)})
+        "window": window})
 
     # ---- correct: served tokens against the plain reference --------
     sample = K.choose_sample(measured, cell.spec["correct"]["sample"], seed)
@@ -253,7 +262,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     ctx = {"cell": cell, "cfg": cfg, "traffic": tr, "server": srv,
            "seconds": seconds, "end_to_end": e2e, "measured": measured,
            "requests": reqs, "t0": t0, "t1": t1, "ttft": ttft, "itl": itl,
-           "window": _delta(c0, c1), "stats_end": stats_end,
+           "window": window, "stats_end": stats_end,
            "trace": summary, "trace_counters": (
                _delta(tc["start"], tc["stop"])
                if "start" in tc and "stop" in tc else None),

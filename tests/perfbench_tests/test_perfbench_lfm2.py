@@ -83,9 +83,9 @@ def test_the_cell_and_its_entries_are_listed(real_manifest):
         CONFIG, "closed-agent-decode", 1)
     mine = {m["name"]: m for m in real_manifest["per_layer"]
             if CELL in m.get("workloads", ())}
-    assert set(NEW) | set(SHARED) == set(mine)
+    assert set(NEW) | set(SHARED) <= set(mine)
     for name in NEW:
-        assert mine[name]["workloads"] == [CELL]
+        assert CELL in mine[name]["workloads"]
         assert mine[name]["moves"] == "serve_out_tokens_per_s"
     assert mine["moe_rows_per_pick"]["better"] == "lower"
     e2e = {m["name"]: m for m in real_manifest["end_to_end"]}

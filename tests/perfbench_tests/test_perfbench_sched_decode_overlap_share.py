@@ -3,9 +3,9 @@ decode steps that were dispatched while the step before them was still
 unread, read off the ``overlapped`` count of ``serve.decode.dispatch``.
 The reader on a hand-made ring against the hand-computed share: 0 for
 a program that writes no such count, steps cut by the window's edge
-left out, None where there is nothing to read; the entry a
-``benchmark`` PR would add for it; and the metric in the result line
-of a traced toy rehearsal of each of the three serving cells."""
+left out, None where there is nothing to read; its entry in
+``BENCHMARK.json``, in all five serving cells; and the metric in the
+result line of a traced toy rehearsal of three of them."""
 import copy
 import time
 from collections import deque
@@ -18,7 +18,8 @@ from perfbench.harness import manifest as M
 
 NAME = "sched_decode_overlap_share"
 CELLS = ["mistral7b-serve-decode", "mistral7b-serve-prefill",
-         "kimi-linear-serve-decode"]
+         "kimi-linear-serve-decode", "lfm2-8b-a1b-serve-decode",
+         "openpangu-ultra-moe-serve-decode"]
 ENTRY = {"name": NAME, "unit": "%", "better": "higher",
          "source": "program_span", "layer": "scheduler",
          "moves": "serve_out_tokens_per_s", "workloads": CELLS}
@@ -106,14 +107,11 @@ def test_nothing_to_read_is_none_not_an_error(ring, why, monkeypatch):
 
 
 def test_the_entry_a_benchmark_pr_would_add_is_clean(real_manifest):
-    """``BENCHMARK.json`` does not list the metric yet: an appended
-    entry turns two accepted tests red, which pin their own PR's
-    entries as the last of ``per_layer`` (PERF.md section 7, T).  The
-    reader stands ready, and this is the entry as the contract takes
-    it, in all three serving cells."""
-    m = copy.deepcopy(real_manifest)
-    m["per_layer"] = [e for e in m["per_layer"] if e["name"] != NAME]
-    m["per_layer"].append(copy.deepcopy(ENTRY))
+    """``BENCHMARK.json`` lists the metric as this entry, in all five
+    serving cells, each of which reports the rate it moves."""
+    m = real_manifest
+    (listed,) = [e for e in m["per_layer"] if e["name"] == NAME]
+    assert listed == ENTRY
     assert M.lint(m) == []
     cells = {w["name"]: w for w in m["workloads"]}
     rate = [e for e in m["end_to_end"] if e["name"] == ENTRY["moves"]][0]
@@ -124,6 +122,7 @@ def test_the_entry_a_benchmark_pr_would_add_is_clean(real_manifest):
 
 def _with_entry(manifest, workloads):
     m = copy.deepcopy(manifest)
+    m["per_layer"] = [e for e in m["per_layer"] if e["name"] != NAME]
     m["per_layer"].append(dict(ENTRY, workloads=list(workloads)))
     return m
 
@@ -152,7 +151,7 @@ def test_traced_kimi_rehearsal_prints_the_share():
     import os
     from perfbench import run as R
     with open(os.path.join(TOY, "manifest_kimi.json")) as f:
-        m = _with_entry(json.load(f), CELLS[2:])
+        m = _with_entry(json.load(f), CELLS[2:3])
     assert M.lint(m, bench_dir=TOY) == []
     res = R.run_cell(CELLS[2], 2 ** 31 + 33, 2.0, True,
                      t_proc0=time.perf_counter(), require_chip=False,

@@ -109,3 +109,34 @@ def test_no_device_plane_reads_nothing():
     s = TR.summarize(ev)
     assert s["busy_s"] == 0.0 and s["n_devices"] == 0
     assert s["idle_gaps"] == []
+
+
+def test_two_tracers_of_one_cell_keep_their_own_traces():
+    """Two runs of one cell traced at once, as two workers of a test
+    run may: the second starts, traces and is read while the first
+    one's profile still waits to be read, and neither loses its trace
+    or leaves its directory behind."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench.harness.common import SubWindowTrace
+    first, second = SubWindowTrace("one-cell"), SubWindowTrace("one-cell")
+    assert first.dir != second.dir
+    step = jax.jit(lambda x: x @ x)
+    x = jnp.ones((64, 64))
+
+    def trace(tracer):
+        tracer.arm(time.perf_counter(), 0.4)
+        t_end = time.perf_counter() + 0.5
+        while time.perf_counter() < t_end:
+            step(x).block_until_ready()
+        tracer._th.join()
+
+    trace(first)
+    trace(second)
+    assert second.finish() is not None
+    assert first.finish() is not None
+    assert not os.path.exists(first.dir)
+    assert not os.path.exists(second.dir)
