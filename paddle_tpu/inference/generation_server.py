@@ -134,18 +134,18 @@ iteration earlier.  Each is a
 trace and a row of ``observability.timeline.spans("serve")``; the rows
 of ``serve.admit`` carry ``queue_wait_ms`` (one value per admitted
 sequence), those of ``serve.prefill.stage`` the padded ``batch`` x
-``bucket``, the useful ``tokens`` and, for a model that answers
-``prefill_attn_pairs(batch, bucket)`` (the latent models, whose
-prefill attention skips the keys no query of a group may see),
-``attn_pairs_share``: the share of the square's (query, key) pairs
-the call multiplies (``stats()`` adds both counts up as
-``prefill_attn_pairs_multiplied`` and ``prefill_attn_pairs_square``;
-equal: the mechanism does not engage), those of
+``bucket`` and the useful ``tokens``, those of
 ``serve.decode.dispatch`` and ``serve.prefill.fetch`` ``overlapped``
-(0 or 1).  ``decode_ms`` / ``prefill_ms`` are read off the dispatch and
-fetch spans' own clock reads and count no instant twice: a decode step
-or a prefill call runs from its dispatch, or from the end of the fetch
-before it where that is later, to the end of its own fetch.
+(0 or 1).  For a model that answers ``prefill_attn_pairs(batch,
+bucket)`` (the latent models, whose prefill attention skips the keys
+no query of a group may see) ``stats()`` adds up the (query, key)
+pairs each call multiplies and those of its whole square as
+``prefill_attn_pairs_multiplied`` and ``prefill_attn_pairs_square``
+(equal: the mechanism does not engage).  ``decode_ms`` /
+``prefill_ms`` are read off the dispatch and fetch spans' own clock
+reads and count no instant twice: a decode step or a prefill call runs
+from its dispatch, or from the end of the fetch before it where that
+is later, to the end of its own fetch.
 
 ISSUE 12 (fleet observatory) adds the REQUEST dimension:
 ``submit(tenant=...)`` tags a request for usage accounting (always-on
@@ -174,6 +174,7 @@ from ..framework import monitor as _monitor
 from ..observability import flight_recorder as _flight
 from ..observability import trace as _trace
 from ..observability.request_trace import RequestTrace
+from ..observability.scopes import SAMPLE
 from ..observability.timeline import StepTimeline
 from . import recurrent_state as _rs
 from .prefix_cache import PrefixCache
@@ -669,9 +670,10 @@ class GenerationServer:
                 model says its program holds such a loop
                 (``loops_on_device``) the program gets the sampler
                 without the branch."""
-                if loops is not None and loops(n_tokens):
-                    return _sample_rows(*args)
-                return _sample_tokens(*args)
+                with jax.named_scope(SAMPLE):
+                    if loops is not None and loops(n_tokens):
+                        return _sample_rows(*args)
+                    return _sample_tokens(*args)
             return sample
 
         call_model = make_call(self._model)
@@ -1518,8 +1520,6 @@ class GenerationServer:
             tokens = int(length.sum())
             ph.set(bucket=bucket, batch=B, tokens=tokens)
             pairs = self._prefill_attn_pairs(B, bucket)
-            if pairs[1]:
-                ph.set(attn_pairs_share=pairs[0] / pairs[1])
         if self._stateful and start.any():
             raise _rs.RecurrentStateUnsupported(
                 "a prefill that starts mid-sequence: the latent layers "

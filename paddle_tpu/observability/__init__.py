@@ -41,6 +41,11 @@ ISSUE 12 grows the subsystem into a FLEET observatory:
 6. **SLO engine** (:mod:`.slo`): declarative objectives (latency
    percentile, error rate, gauge bound) with multi-window burn
    rates; a breach is a flight-recorder event + postmortem bundle.
+7. **Block scopes** (:mod:`.scopes`): one vocabulary of
+   ``jax.named_scope`` names (``attn``, ``mixer``, ``moe``, ``ffn``,
+   ``embed``, ``head``, ``sample``, ``mtp``) that every served model
+   and the server's sampler write, so that a device trace's ``tf_op``
+   splits a step's device time by block.
 
 Env quick reference::
 
@@ -59,7 +64,7 @@ from ..framework.monitor import (  # noqa: F401
     get_histogram, hist_observe, metrics_enabled, metrics_reset,
     metrics_snapshot, stat_add, stat_get)
 from . import (aggregator, flight_recorder, metrics,  # noqa: F401
-               request_trace, slo, timeline, trace)
+               request_trace, scopes, slo, timeline, trace)
 from .aggregator import FleetAggregator  # noqa: F401
 from .flight_recorder import (  # noqa: F401
     FlightRecorder, Watchdog, compile_log, flight_dump, flight_enabled,
@@ -75,7 +80,7 @@ from .trace import (  # noqa: F401
 
 __all__ = [
     "trace", "metrics", "timeline", "flight_recorder", "aggregator",
-    "slo", "request_trace",
+    "slo", "request_trace", "scopes",
     "Span", "span", "server_span", "propagation_ctx", "record_clock",
     "enable_tracing", "disable_tracing", "tracing_enabled",
     "StepTimeline", "Histogram", "RequestTrace",

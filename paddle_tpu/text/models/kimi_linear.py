@@ -58,6 +58,7 @@ from ...framework.core import Tensor
 from ...nn.initializer import Constant, Normal
 from ...nn.layer.layers import Layer
 from ...nn.layer.moe import DroplessMoELayer, _swiglu
+from ...observability.scopes import ATTN, EMBED, FFN, HEAD, MIXER, MOE
 
 __all__ = ["KimiLinearConfig", "KimiLinearForCausalLM", "kimi_linear_tiny",
            "LatentAttention", "kda_chunked", "kda_step", "short_conv"]
@@ -630,23 +631,27 @@ class KimiDecoderLayer(_Params):
     def forward_paged(self, h, positions, cache, block_tables, write_mask,
                       slots):
         eps = self.config.rms_norm_eps
-        x = _rms(h, self.input_layernorm._value, eps).astype(h.dtype)
-        if self.is_mla:
-            a, cache = self.self_attn.forward_paged(
-                x, positions, cache, block_tables, write_mask)
-        else:
-            a, cache = self.self_attn.forward_paged(
-                x, positions, cache, write_mask, slots)
-        h = h + a.astype(h.dtype)
-        x = _rms(h, self.post_attention_layernorm._value,
-                 eps).astype(h.dtype)
+        with jax.named_scope(ATTN if self.is_mla else MIXER):
+            x = _rms(h, self.input_layernorm._value, eps).astype(h.dtype)
+            if self.is_mla:
+                a, cache = self.self_attn.forward_paged(
+                    x, positions, cache, block_tables, write_mask)
+            else:
+                a, cache = self.self_attn.forward_paged(
+                    x, positions, cache, write_mask, slots)
+            h = h + a.astype(h.dtype)
         counts = None
+        with jax.named_scope(MOE if self.is_moe else FFN):
+            x = _rms(h, self.post_attention_layernorm._value,
+                     eps).astype(h.dtype)
+            if self.is_moe:
+                y, n_here, load = self.mlp.apply_values(x)
+            else:
+                y = self.mlp.apply_values(x)
+            h = h + y
         if self.is_moe:
-            y, n_here, load = self.mlp.apply_values(x)
             counts = jnp.stack([n_here, load]).astype(jnp.int32)
-        else:
-            y = self.mlp.apply_values(x)
-        return h + y, cache, counts
+        return h, cache, counts
 
 
 class KimiLinearModel(_Params):
@@ -751,8 +756,9 @@ class KimiLinearForCausalLM(_Params):
         if slots is not None:
             slots = raw(slots).astype(jnp.int32)
         pos = pos.astype(jnp.int32)
-        h = self.model.embed_tokens._value[ids].astype(
-            jnp.dtype(c.compute_dtype))
+        with jax.named_scope(EMBED):
+            h = self.model.embed_tokens._value[ids].astype(
+                jnp.dtype(c.compute_dtype))
         new_pools, counts = [], jnp.zeros((2,), jnp.int32)
         for lyr, cache in zip(self.model.layers, pools):
             cache = {k: raw(v) for k, v in cache.items()}
@@ -760,10 +766,13 @@ class KimiLinearForCausalLM(_Params):
             new_pools.append(cache)
             if n is not None:
                 counts = counts + n
-        h = _rms(h, self.model.norm._value, c.rms_norm_eps).astype(h.dtype)
-        if gather_at is not None:
-            h = jnp.take_along_axis(
-                h, raw(gather_at)[:, None, None].astype(jnp.int32), axis=1)
-        logits = jnp.dot(h, self.lm_head._value,
-                         preferred_element_type=F32)
+        with jax.named_scope(HEAD):
+            h = _rms(h, self.model.norm._value,
+                     c.rms_norm_eps).astype(h.dtype)
+            if gather_at is not None:
+                h = jnp.take_along_axis(
+                    h, raw(gather_at)[:, None, None].astype(jnp.int32),
+                    axis=1)
+            logits = jnp.dot(h, self.lm_head._value,
+                             preferred_element_type=F32)
         return Tensor(logits), new_pools, counts

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from ...framework.core import Tensor
 from ...nn.layer.moe import DroplessMoELayer, _swiglu
+from ...observability.scopes import ATTN, EMBED, FFN, HEAD, MIXER, MOE
 from .kimi_linear import _Params, _rms, _rope_half, short_conv
 from .llama import paged_write_attend
 
@@ -245,27 +246,27 @@ class Lfm2MoeDecoderLayer(_Params):
     def forward_paged(self, h, positions, cache, block_tables, write_mask,
                       slots):
         eps = self.config.norm_eps
-        x = _rms(h, self.operator_norm._value, eps).astype(h.dtype)
-        if self.is_attention:
-            with jax.named_scope("lfm2.attn"):
+        with jax.named_scope(ATTN if self.is_attention else MIXER):
+            x = _rms(h, self.operator_norm._value, eps).astype(h.dtype)
+            if self.is_attention:
                 a, cache = self.self_attn.forward_paged(
                     x, positions, cache, block_tables, write_mask)
-        else:
-            with jax.named_scope("lfm2.conv"):
+            else:
                 a, cache = self.conv.forward_paged(
                     x, positions, cache, write_mask, slots)
-        h = h + a.astype(h.dtype)
-        x = _rms(h, self.ffn_norm._value, eps).astype(h.dtype)
+            h = h + a.astype(h.dtype)
         counts = None
-        if self.is_moe:
-            with jax.named_scope("lfm2.experts"):
+        with jax.named_scope(MOE if self.is_moe else FFN):
+            x = _rms(h, self.ffn_norm._value, eps).astype(h.dtype)
+            if self.is_moe:
                 y, *counts = self.feed_forward.apply_values(
                     x, count_rows=True)
-            counts = jnp.stack([jnp.asarray(n, jnp.int32) for n in counts])
-        else:
-            with jax.named_scope("lfm2.dense_ffn"):
+            else:
                 y = self.feed_forward.apply_values(x)
-        return h + y, cache, counts
+            h = h + y
+        if counts is not None:
+            counts = jnp.stack([jnp.asarray(n, jnp.int32) for n in counts])
+        return h, cache, counts
 
 
 class Lfm2MoeModel(_Params):
@@ -351,7 +352,8 @@ class Lfm2MoeForCausalLM(_Params):
             slots = raw(slots).astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         embed = self.model.embed_tokens._value
-        h = embed[ids].astype(jnp.dtype(c.compute_dtype))
+        with jax.named_scope(EMBED):
+            h = embed[ids].astype(jnp.dtype(c.compute_dtype))
         new_pools, counts = [], jnp.zeros((3,), jnp.int32)
         for lyr, cache in zip(self.model.layers, pools):
             cache = {k: raw(v) for k, v in cache.items()}
@@ -359,11 +361,13 @@ class Lfm2MoeForCausalLM(_Params):
             new_pools.append(cache)
             if n is not None:
                 counts = counts + n
-        h = _rms(h, self.model.embedding_norm._value,
-                 c.norm_eps).astype(h.dtype)
-        if gather_at is not None:
-            h = jnp.take_along_axis(
-                h, raw(gather_at)[:, None, None].astype(jnp.int32), axis=1)
-        logits = jnp.einsum("bsh,vh->bsv", h, embed,
-                            preferred_element_type=F32)
+        with jax.named_scope(HEAD):
+            h = _rms(h, self.model.embedding_norm._value,
+                     c.norm_eps).astype(h.dtype)
+            if gather_at is not None:
+                h = jnp.take_along_axis(
+                    h, raw(gather_at)[:, None, None].astype(jnp.int32),
+                    axis=1)
+            logits = jnp.einsum("bsh,vh->bsv", h, embed,
+                                preferred_element_type=F32)
         return Tensor(logits), new_pools, counts

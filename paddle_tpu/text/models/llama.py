@@ -39,6 +39,7 @@ from ...distributed.meta_parallel import (
 from ...framework.core import Tensor, _apply
 from ...nn.initializer import Constant, Normal
 from ...nn.layer.layers import Layer, Parameter
+from ...observability.scopes import ATTN, EMBED, FFN, HEAD
 
 __all__ = [
     "KVCacheUnsupportedError",
@@ -514,11 +515,13 @@ class LlamaDecoderLayer(Layer):
 
     def forward_paged(self, hidden, positions, cache, block_tables,
                       write_mask, verify_mode: bool = False):
-        attn_out, cache = self.self_attn.forward_paged(
-            self.input_layernorm(hidden), positions, cache,
-            block_tables, write_mask, verify_mode=verify_mode)
-        h = hidden + attn_out
-        return h + self.mlp(self.post_attention_layernorm(h)), cache
+        with jax.named_scope(ATTN):
+            attn_out, cache = self.self_attn.forward_paged(
+                self.input_layernorm(hidden), positions, cache,
+                block_tables, write_mask, verify_mode=verify_mode)
+            h = hidden + attn_out
+        with jax.named_scope(FFN):
+            return h + self.mlp(self.post_attention_layernorm(h)), cache
 
 
 class StackedLlamaDecoder(Layer):
@@ -685,16 +688,18 @@ class LlamaModel(Layer):
         c = self.config
         if self.decoder is not None:
             raise KVCacheUnsupportedError(_SCAN_LAYERS_KV_MSG)
-        hidden = self.embed_tokens(input_ids)
-        if c.compute_dtype:
-            hidden = hidden.astype(c.compute_dtype)
+        with jax.named_scope(EMBED):
+            hidden = self.embed_tokens(input_ids)
+            if c.compute_dtype:
+                hidden = hidden.astype(c.compute_dtype)
         new_pools = []
         for layer, pool in zip(self.layers, pools):
             hidden, pool = layer.forward_paged(hidden, positions, pool,
                                                block_tables, write_mask,
                                                verify_mode=verify_mode)
             new_pools.append(pool)
-        return self.norm(hidden), new_pools
+        with jax.named_scope(HEAD):
+            return self.norm(hidden), new_pools
 
 
 def _remat_layer(layer: LlamaDecoderLayer, hidden: Tensor, positions):
@@ -889,14 +894,15 @@ class LlamaForCausalLM(Layer):
         hidden, pools = self.model.forward_paged(
             input_ids, positions, pools, block_tables, write_mask,
             verify_mode=verify_mode)
-        if gather_at is not None:
-            hv = hidden._value if isinstance(hidden, Tensor) else hidden
-            ga = gather_at._value if isinstance(gather_at, Tensor) \
-                else gather_at
-            hv = jnp.take_along_axis(
-                hv, ga[:, None, None].astype(jnp.int32), axis=1)
-            hidden = Tensor(hv)
-        return self._logits(hidden), pools
+        with jax.named_scope(HEAD):
+            if gather_at is not None:
+                hv = hidden._value if isinstance(hidden, Tensor) else hidden
+                ga = gather_at._value if isinstance(gather_at, Tensor) \
+                    else gather_at
+                hv = jnp.take_along_axis(
+                    hv, ga[:, None, None].astype(jnp.int32), axis=1)
+                hidden = Tensor(hv)
+            return self._logits(hidden), pools
 
 
 def _causal_lm_loss(logits, labels):

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from ...framework.core import Tensor
 from ...nn.layer.moe import DroplessMoELayer
+from ...observability.scopes import ATTN, EMBED, FFN, HEAD, MOE, MTP
 from .kimi_linear import (KimiMLP, LatentAttention, _Params, _rms,
                           attend_plan)
 
@@ -144,22 +145,24 @@ class PanguDecoderLayer(_Params):
         layer's three counters or None)."""
         c = self.config
         norm = lambda t, w: _rms(t, w._value, c.rms_norm_eps).astype(h.dtype)
-        with jax.named_scope("pangu.mla"):
+        with jax.named_scope(ATTN):
             a, cache = attend(norm(h, self.input_layernorm))
-        a = a.astype(h.dtype)
-        h = h + (norm(a, self.post_attention_layernorm)
-                 if c.sandwich_norm else a)
-        z = norm(h, self.pre_mlp_layernorm)
+            a = a.astype(h.dtype)
+            if c.sandwich_norm:
+                a = norm(a, self.post_attention_layernorm)
+            h = h + a
         counts = None
-        if self.is_moe:
-            with jax.named_scope("pangu.experts"):
+        with jax.named_scope(MOE if self.is_moe else FFN):
+            z = norm(h, self.pre_mlp_layernorm)
+            if self.is_moe:
                 y, *counts = self.mlp.apply_values(z, count_rows=True)
-            counts = jnp.stack([jnp.asarray(n, jnp.int32) for n in counts])
-        else:
-            with jax.named_scope("pangu.dense_ffn"):
+            else:
                 y = self.mlp.apply_values(z)
-        h = h + (norm(y, self.post_mlp_layernorm)
-                 if c.sandwich_norm else y)
+            if c.sandwich_norm:
+                y = norm(y, self.post_mlp_layernorm)
+            h = h + y
+        if counts is not None:
+            counts = jnp.stack([jnp.asarray(n, jnp.int32) for n in counts])
         return h, cache, counts
 
     def forward_paged(self, h, positions, cache, block_tables, write_mask):
@@ -284,8 +287,9 @@ class PanguUltraMoEForCausalLM(_Params):
         ids, pos, wm = raw(input_ids), raw(positions), raw(write_mask)
         tbl = raw(block_tables)
         pos = pos.astype(jnp.int32)
-        h = self.model.embed_tokens._value[ids].astype(
-            jnp.dtype(c.compute_dtype))
+        with jax.named_scope(EMBED):
+            h = self.model.embed_tokens._value[ids].astype(
+                jnp.dtype(c.compute_dtype))
         new_pools, counts = [], jnp.zeros((3,), jnp.int32)
         for lyr, cache in zip(self.model.layers, pools):
             cache = {k: raw(v) for k, v in cache.items()}
@@ -293,12 +297,15 @@ class PanguUltraMoEForCausalLM(_Params):
             new_pools.append(cache)
             if n is not None:
                 counts = counts + n
-        h = _rms(h, self.model.norm._value, c.rms_norm_eps).astype(h.dtype)
-        if gather_at is not None:
-            h = jnp.take_along_axis(
-                h, raw(gather_at)[:, None, None].astype(jnp.int32), axis=1)
-        logits = jnp.dot(h, self.lm_head._value,
-                         preferred_element_type=F32)
+        with jax.named_scope(HEAD):
+            h = _rms(h, self.model.norm._value,
+                     c.rms_norm_eps).astype(h.dtype)
+            if gather_at is not None:
+                h = jnp.take_along_axis(
+                    h, raw(gather_at)[:, None, None].astype(jnp.int32),
+                    axis=1)
+            logits = jnp.dot(h, self.lm_head._value,
+                             preferred_element_type=F32)
         return Tensor(logits), new_pools, counts
 
     def hidden_block(self, input_ids, positions):
@@ -328,7 +335,7 @@ class PanguUltraMoEForCausalLM(_Params):
         hid, pos = raw(hidden), raw(positions).astype(jnp.int32)
         norm = lambda t, w: _rms(t, w._value, c.rms_norm_eps).astype(
             hid.dtype)
-        with jax.named_scope("pangu.mtp"):
+        with jax.named_scope(MTP):
             e = self.model.embed_tokens._value[raw(next_ids)].astype(
                 hid.dtype)
             u = jnp.dot(jnp.concatenate(

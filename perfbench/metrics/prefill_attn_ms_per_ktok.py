@@ -1,0 +1,7 @@
+from perfbench.metrics._scopes import prefill_ms_per_ktok
+
+
+def read(ctx):
+    """Device time under the ``attn`` scope of the prefill programs per
+    thousand prompt tokens."""
+    return prefill_ms_per_ktok(ctx, ("attn",))

@@ -16,7 +16,6 @@ in bfloat16 misses by four orders (its own test)."""
 import dataclasses
 import json
 import os
-import time
 
 import numpy as np
 import pytest
@@ -409,11 +408,10 @@ def test_the_plan_counts_what_a_brute_force_count_finds(B, S, H, chunk):
 
 def test_a_latent_server_counts_the_pairs_its_prefill_skips(
         built, monkeypatch):
-    """``stats()`` adds up the plan of every prefill call, and the
-    call's share rides its ``serve.prefill.stage`` span: 1.0 where a
+    """``stats()`` adds up the plan of every prefill call: the share of
+    a call, read off the two counters' deltas around it, is 1.0 where a
     bucket is one block, under 1 where it is several chunks (the score
     block's limit is lowered so that a toy bucket holds four)."""
-    from paddle_tpu.observability import timeline
     from paddle_tpu.text.models import kimi_linear
     model, _ = built
     short, long = _prompts(2, seed=11, lo=9, hi=14), \
@@ -424,27 +422,26 @@ def test_a_latent_server_counts_the_pairs_its_prefill_skips(
     assert model.prefill_attn_pairs(2, 16) == (5 * 2 * 4 * 16 * 16,) * 2
     assert model.prefill_attn_pairs(1, 64) == (
         5 * 4 * 64 * 64 * 5 // 8, 5 * 4 * 64 * 64)
+    pairs = ("prefill_attn_pairs_multiplied", "prefill_attn_pairs_square")
+    got, shares = [], []
     with GenerationServer(model, num_slots=4, block_size=4,
                           max_model_len=96, prompt_buckets=[16, 64],
                           max_prefill_batch=1, check_replay=True) as srv:
-        t0 = time.perf_counter()     # prewarm traffic is not the session
-        got = [srv.submit(p, max_new_tokens=5).result(timeout=300)
-               for p in short]
-        st = srv.stats()
-        assert st["prefill_attn_pairs_multiplied"] \
-            == st["prefill_attn_pairs_square"] == 2 * 5 * 4 * 16 * 16
-        got += [srv.submit(p, max_new_tokens=5).result(timeout=300)
-                for p in long]
+        for p in short + long:
+            # one call a request: its first token is out once the call
+            # is read, and the counters with it
+            before = [srv.stats()[k] for k in pairs]
+            got.append(srv.submit(p, max_new_tokens=5).result(timeout=300))
+            done, square = (srv.stats()[k] - b for k, b in zip(pairs,
+                                                               before))
+            shares.append(done / square)
+            if len(got) == len(short):
+                st = srv.stats()
+                assert st[pairs[0]] == st[pairs[1]] == 2 * 5 * 4 * 16 * 16
     end = srv.stats()
     assert got == want               # the same tokens, chunked or whole
-    done = end["prefill_attn_pairs_multiplied"] \
-        - st["prefill_attn_pairs_multiplied"]
-    square = end["prefill_attn_pairs_square"] \
-        - st["prefill_attn_pairs_square"]
+    done, square = (end[k] - st[k] for k in pairs)
     assert square == 2 * 5 * 4 * 64 * 64 and done * 8 == square * 5
-    shares = [r.args["attn_pairs_share"]
-              for r in timeline.spans("serve", since=t0)
-              if r.name == "serve.prefill.stage"]
     assert shares == [1.0, 1.0, 0.625, 0.625]
 
 
