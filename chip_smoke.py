@@ -501,6 +501,19 @@ def _kernel_cases(size):
         lambda: kreg.dispatch("paged_attention", *pa, g_,
                               mode="xla_ref"),
         2e-2, 0.0)    # bf16 out (eps 2^-7)
+
+    # paged_latent_attention: 128 heads over one pool of 640-lane rows,
+    # V its first 512 lanes; the same lengths and tables
+    la = [randn(b, s_, 128, 640, dtype=jnp.bfloat16),
+          randn(nb, bs, 1, 640, dtype=jnp.bfloat16),
+          jnp.asarray(ptbl), jnp.asarray(lens[:, None] - 1), 512,
+          192 ** -0.5]
+    cases["paged_latent_attention"] = (
+        lambda mode: kreg.dispatch("paged_latent_attention", *la,
+                                   mode=mode),
+        lambda: kreg.dispatch("paged_latent_attention", *la,
+                              mode="xla_ref"),
+        2e-2, 0.0)    # bf16 out (eps 2^-7)
     return cases
 
 

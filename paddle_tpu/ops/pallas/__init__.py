@@ -18,6 +18,8 @@ Kernels (see each module's docstring for the tolerance contract):
   KV pools once, per-(block, slot) scales applied inside the gather
 - ``paged_attention`` — decode attention over bf16 paged KV pools read
   through the block table up to each row's length (no gather into HBM)
+- ``paged_latent_attention`` — the latent models' absorbed decode over
+  ONE latent pool, each page copied once and read as K and as V
 - ``segment_sum`` — device-side fused sparse-grad merge mirroring
   ``native/ps_core.cc``'s ``ps_segsum_inv``
 - ``pull_dequant`` — on-device reconstruction of int8 PS pull rows
@@ -33,7 +35,9 @@ from .flash_attention import (flash_attention,  # noqa: F401
                               flash_attention_bhsd)
 from .int8_matmul import int8_matmul_pallas, int8_matmul_ref  # noqa: F401
 from .kv_attention import (int8_paged_attention,  # noqa: F401
-                           paged_attention, paged_attention_ref)
+                           paged_attention, paged_attention_ref,
+                           paged_latent_attention,
+                           paged_latent_attention_ref)
 from .opt_apply import opt_apply_pallas, opt_apply_ref  # noqa: F401
 from .pull_dequant import (pull_dequant_pallas,  # noqa: F401
                            pull_dequant_ref)

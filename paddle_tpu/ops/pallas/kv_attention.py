@@ -1,11 +1,11 @@
 """Paged decode attention over the serving KV pools: the XLA gather
-reference and two Pallas kernels that read the pools through the block
-table.
+reference and three Pallas kernels that read the pools through the
+block table.
 
 ``paged_attention_ref`` is the XLA gather/attend path, lifted verbatim
 from ``LlamaAttention.forward_paged`` so the non-pallas serving
 contracts (replay, prefix sharing, eviction) are pinned by the SAME
-function.  It is the fallback and the parity oracle of both kernels.
+function.  It is the fallback and the parity oracle of the kernels.
 What it costs on the chip (PERF.md, PR 26): ``kpool[tbl]`` is
 materialised, and where ``num_slots * max_blocks == num_blocks`` that
 gather is as large as the pool; the einsums then run over every
@@ -50,6 +50,26 @@ at the end.  A model that keeps such a pool allocates it 3-D: XLA lays
 out a ``[.., KH, 64]`` array as it likes, 64 lanes padded or the
 dimensions transposed, and a Mosaic operand would be re-laid each call.
 
+``paged_latent_attention`` (kernel ``paged_latent_attention``) is
+the absorbed decode of the latent-attention models: ``H`` query heads
+over ONE pool ``[nb, bs, 1, W]`` whose row is a token's latent ``[c |
+k_pe]`` padded to whole lane tiles (W = 640). K and V are that same row,
+so the kernel copies each page ``(bs, W)`` once, into one double
+buffered scratch, takes the logits over all ``W`` lanes (the pad is zero
+in pool and query) and ``p . v`` over the first ``value_width`` lanes of
+the same block (``kv_lora_rank`` = 512, a lane-aligned static slice),
+and writes ``[B, 1, H, value_width]``. The chain of copies, the online
+softmax and the hi + lo ``p . v`` are ``paged_attention``'s, with one
+DMA semaphore a slot. What a v5e showed (PERF.md): the copies'
+BYTES overlap the products, but issuing them takes the scalar core's
+time beside the vector work, and a branch a page (copy only what the row
+holds, wait page by page) cost a fifth of the kernel's time. So every
+block copies ``_LATENT_PAGES_PER_BLOCK`` pages with no branch, a page
+past the row's length from the trash block (its weights are exact zeros), and one
+wait on the slot's semaphore takes the whole block; the row after the
+last one copies the trash block, and the last grid step waits for it.
+Its reference is ``paged_attention_ref`` with the pool as K and as V.
+
 ``int8_paged_attention`` (kernel ``int8_kv_attention``, PR 13) fuses the
 dequant of int8 pools into the gather for decode/verify: grid
 ``(B, G, M)``, M innermost so the running (max, denom, acc) scratch
@@ -77,6 +97,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import registry
 
 __all__ = ["paged_attention_ref", "paged_attention",
+           "paged_latent_attention_ref", "paged_latent_attention",
            "int8_paged_attention"]
 
 _NEG = -1e30
@@ -341,6 +362,179 @@ def _paged_eligible(qh, kpool, vpool, kscale, vscale, tbl, pos,
             and tiled and (mesh is None or mesh.size == 1))
 
 
+# ---------------------------------------------------------------------
+# latent decode kernel
+# ---------------------------------------------------------------------
+
+# pages per grid-step block: 32 was the fastest on a v5e at both latent
+# cells' shapes (PERF.md: 16 / 32 / 64 pages read 5.18 / 4.59 /
+# 4.80 ns a position-layer at 128 heads, 3.82 / 3.54 / 3.71 at 32)
+_LATENT_PAGES_PER_BLOCK = 32
+
+
+def _paged_latent_kernel(P, bs, m_tbl, n_rows, vw, scale,
+                         len_ref, tbl_ref, q_ref, pool_hbm, o_ref,
+                         buf, sem, slot_ref):
+    """``paged_attention``'s chain of blocks and online softmax over ONE
+    pool: a page is copied once and its block is K over all its lanes
+    and V over the first ``vw``.  Every block copies ``P`` pages, those
+    past the row's length from the trash page, so that the copies are
+    issued with no branch and waited for at once (module doc)."""
+    b = pl.program_id(0)
+    n = P * bs
+
+    def start_block(bb, i, slot):
+        """Start the ``P`` copies of block ``i`` of row ``bb``: the
+        row's own pages, the trash page past its length, and only the
+        trash page for the row after the last."""
+        live = bb < n_rows
+        bb = jnp.minimum(bb, n_rows - 1)
+        k = jnp.where(live, (len_ref[bb] + bs - 1) // bs - i * P, 0)
+        for p in range(P):
+            at = bb * m_tbl + jnp.minimum(i * P + p, m_tbl - 1)
+            page = jnp.where(p < k, tbl_ref[at], 0)
+            pltpu.make_async_copy(pool_hbm.at[page],
+                                  buf.at[slot, pl.ds(p * bs, bs)],
+                                  sem.at[slot]).start()
+
+    def wait_block(slot):
+        # the slot's semaphore counts bytes: one wait for all P pages
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sem.at[slot]).wait()
+
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start_block(0, 0, 0)
+
+    length = len_ref[b]
+    nblk = ((length + bs - 1) // bs + P - 1) // P
+    q = q_ref[0]                                       # (H, W)
+    h = q.shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+
+    def body(i, carry):
+        m, l, acc = carry
+        slot = slot_ref[0]
+        nxt = 1 - slot
+        # the next block is in flight while this one is computed: the
+        # row's own, or the first of the next row
+        last = i == nblk - 1
+        start_block(jnp.where(last, b + 1, b), jnp.where(last, 0, i + 1),
+                    nxt)
+        slot_ref[0] = nxt
+        wait_block(slot)
+        s = jax.lax.dot_general(
+            q, buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        valid = col < length - i * n
+        s = jnp.where(valid, s, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        # masked slots contribute EXACT zeros, whatever the buffer holds
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + p.sum(axis=1, keepdims=True)
+        v = buf[slot, :, :vw]                          # (P*bs, vw)
+        if v.dtype == jnp.bfloat16:
+            # f32 p in bf16 hi + lo halves, one pass over V (as in
+            # ``_paged_attn_kernel``)
+            hi = p.astype(jnp.bfloat16)
+            lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv = jnp.dot(jnp.concatenate([hi, lo], axis=0), v,
+                         preferred_element_type=jnp.float32)
+            pv = pv[:h] + pv[h:]
+        else:
+            pv = jnp.dot(p, v.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    m, l, acc = jax.lax.fori_loop(
+        0, nblk, body,
+        (jnp.full((h, 1), _NEG, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, vw), jnp.float32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+    @pl.when(b == n_rows - 1)
+    def _():
+        wait_block(slot_ref[0])     # the trash block started past the end
+
+
+def paged_latent_attention_ref(qh, pool, tbl, pos, value_width, scale):
+    """The latent decode as :func:`paged_attention_ref` computes it: K
+    and V are both ``pool`` (one "kv head" of ``W`` lanes), the
+    reference's own ``1/sqrt(W)`` undone in the f32 query, the first
+    ``value_width`` lanes of the result kept.  ``qh`` [B, S, H, W],
+    ``pool`` [nb, bs, 1, W]; returns [B, S, H, value_width] in ``qh``'s
+    dtype."""
+    B, S, H, W = qh.shape
+    q = qh.astype(jnp.float32) * (scale * W ** 0.5)
+    o = paged_attention_ref(q, pool, pool, None, None, tbl, pos, 1)
+    return o.reshape(B, S, H, W)[..., :value_width].astype(qh.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_width", "scale", "interpret"))
+def paged_latent_attention(qh, pool, tbl, pos, value_width, scale, *,
+                           interpret=False):
+    """Decode attention (``S == 1``) of ``H`` query heads over ONE
+    latent pool read through the block table (module doc): same
+    arguments and result as :func:`paged_latent_attention_ref`."""
+    B, S, H, W = qh.shape
+    if S != 1:
+        raise ValueError("paged_latent_attention is the S == 1 kernel")
+    nb, bs = pool.shape[:2]
+    M = tbl.shape[1]
+    P = min(_LATENT_PAGES_PER_BLOCK, M)
+    # a length of at least 1 keeps every row's first block in the chain
+    # of copies (as in ``paged_attention``)
+    lengths = jnp.clip(pos[:, 0].astype(jnp.int32) + 1, 1, M * bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, value_width),
+                               lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P * bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, P, bs, M, B, value_width,
+                          scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_width), qh.dtype),
+        # rows run in order: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # the benchmark's readers find the kernel by "paged_attention"
+        name="paged_attention_latent",
+    )(lengths, tbl.astype(jnp.int32).reshape(-1), qh.reshape(B, H, W),
+      pool.reshape(nb, bs, W))
+    return out.reshape(B, 1, H, value_width)
+
+
+def _latent_eligible(qh, pool, tbl, pos, value_width, scale):
+    # compiled-mode gate, from the call: one query per row, a bf16 pool
+    # of one "kv head" in the queries' dtype, pages of whole (16, 128)
+    # tiles, V a lane-aligned prefix of them, and no multi-device mesh
+    from ...distributed import mesh as mesh_mod
+    mesh = mesh_mod.get_mesh(create=False)
+    W, bs = pool.shape[-1], pool.shape[1]
+    return (qh.shape[1] == 1 and pool.ndim == 4 and pool.shape[2] == 1
+            and pool.dtype == jnp.bfloat16 and qh.dtype == pool.dtype
+            and W % 128 == 0 and value_width % 128 == 0
+            and value_width <= W and bs % 16 == 0
+            and (mesh is None or mesh.size == 1))
+
+
 def _int8_kv_attn_kernel(bs, sr, d, scale, tbl_ref, qpos_ref, q_ref, k_ref, v_ref,
             ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref):
     # tbl_ref (the scalar-prefetched block table) already did its job
@@ -467,4 +661,18 @@ registry.register(
         "block table up to each row's length: whole pages DMA'd from "
         "HBM, double buffered, blockwise online softmax; nothing is "
         "gathered into HBM",
+)
+
+
+registry.register(
+    "paged_latent_attention", paged_latent_attention,
+    paged_latent_attention_ref,
+    tolerance="atol 2e-5 / rtol 1e-4 vs xla_ref on f32 inputs (as "
+              "paged_attention); bit-identical across physical block "
+              "ids and batch neighbours",
+    eligible=_latent_eligible,
+    doc="absorbed latent decode attention: one latent pool read through "
+        "the block table up to each row's length, each page DMA'd once "
+        "and read as K over all its lanes and as V over the first "
+        "value_width",
 )

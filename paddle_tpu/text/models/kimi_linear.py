@@ -20,7 +20,8 @@ Per layer (pre-norm RMSNorm blocks, untied head):
   in the fourth: the cache holds
   one latent row ``[c | k_pe]`` per token for ALL heads, in pages
   ``[num_blocks, block, 1, 640]`` (576 values padded to whole lane
-  tiles, so that a page is the ``paged_attention`` kernel's page).
+  tiles, so that a page is the ``paged_latent_attention`` kernel's
+  page).
   Prefill expands K and V from the latent and attends in plain XLA,
   query chunks in groups that each take only the keys they may see
   (:func:`_attend`, :func:`attend_plan`); decode absorbs the
@@ -574,18 +575,18 @@ class LatentAttention(_Params):
         else:
             # one query per row, absorbed: q~_h = [W_uk_h^T q_nope_h |
             # q_pe_h], all heads over the one latent row per token
-            # (K = V = the latent pool; the kernel's own 1/sqrt(width)
-            # is undone in the query), o_h = W_uv_h sum p c
+            # (K = the whole page row, V = its first kvr lanes: one
+            # pool, each page read once), o_h = W_uv_h sum p c
             kvb = self._kv_b()
             qa = jnp.einsum("bhd,chd->bhc", q[:, 0, :, :dn],
                             kvb[..., :dn], preferred_element_type=F32)
             qt = jnp.concatenate(
                 [qa, q[:, 0, :, dn:].astype(F32),
                  jnp.zeros((B, nh, wp - self.latent_width), F32)], -1)
-            qt = (qt * (scale * wp ** 0.5)).astype(pool.dtype)
-            ol = _kreg.dispatch("paged_attention", qt[:, None], pool, pool,
-                                None, None, block_tables, positions, 1)
-            ol = ol.reshape(B, nh, wp)[..., :kvr]
+            ol = _kreg.dispatch("paged_latent_attention",
+                                qt.astype(pool.dtype)[:, None], pool,
+                                block_tables, positions, kvr, scale)
+            ol = ol.reshape(B, nh, kvr)
             o = jnp.einsum("bhc,chd->bhd", ol, kvb[..., dn:],
                            preferred_element_type=F32
                            ).astype(x.dtype)[:, None]

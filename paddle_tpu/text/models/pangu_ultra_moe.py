@@ -16,7 +16,7 @@ Per layer, with ``x = RMSNorm(h; input_layernorm)``:
   token in pages ``[num_blocks, block, 1, 640]``, whatever the head
   count; prefill expands K and V from the latent, decode absorbs the
   expansion into the query and the output and attends over the pages
-  through the ``paged_attention`` kernel.
+  through the ``paged_latent_attention`` kernel.
 - **sandwich norm**: a norm before AND after each sublayer, the second
   on the sublayer's output before the residual add: ``h += RMSNorm(a;
   post_attention_layernorm)``; ``z = RMSNorm(h; pre_mlp_layernorm)``;

@@ -12,6 +12,7 @@ documented on the registration (and in the README table):
 - ``int8_kv_attention``  atol 2e-5 / rtol 1e-4 (online softmax)
 - ``paged_attention``    atol 2e-5 / rtol 1e-4 on f32 (online softmax);
   bit-identical across physical block ids and batch neighbours
+- ``paged_latent_attention``  the same, over one latent pool
 - ``segment_sum``        bit-exact for integer-valued grads, atol 1e-6
                          for arbitrary floats
 - ``flash_attention``    compat re-export + dispatch counters (numeric
@@ -57,7 +58,8 @@ def _rand(rng, *shape):
 def test_registry_lists_every_kernel_with_tolerance():
     ks = kreg.kernels()
     for name in ("flash_attention", "opt_apply", "int8_matmul",
-                 "int8_kv_attention", "paged_attention", "segment_sum",
+                 "int8_kv_attention", "paged_attention",
+                 "paged_latent_attention", "segment_sum",
                  "pull_dequant"):
         assert name in ks, sorted(ks)
         assert ks[name].tolerance, name
@@ -593,7 +595,9 @@ def _paged_case(rng, lengths, G=2, R=2, D=16, bs=8, M=5, dtype=np.float32,
     for i, n in enumerate(lengths):
         used = -(-n // bs)
         tbl[i, :used] = 1 + i * M + rng.permutation(M)[:used]
-    if tables is not None:
+    if tables == "trash_last":      # the last row an inactive slot's
+        tbl[-1] = 0
+    elif tables is not None:
         tbl = np.asarray(tables, np.int32)
     pos = np.asarray(lengths, np.int32)[:, None] - 1
     return [jnp.asarray(qh, dtype), jnp.asarray(kpool, dtype),
@@ -630,6 +634,23 @@ _PAGED_CASES = {
         dtype=jnp.bfloat16),
     "latent128x1x640_block16_f32": dict(
         lengths=[33, 2], G=1, R=128, D=640, bs=16, M=4),
+    # the latent kernel: one pool, K its 640 lanes and V the
+    # first 512, at the two latent cells' head counts; lengths 1, a page
+    # boundary (16, 32), one past it, more than one grid-step block, and
+    # a row of the trash block (position 0, an all-zero table)
+    "latent_kernel128x640v512_block16_bf16": dict(
+        lengths=[1, 16, 17, 300, 1], G=1, R=128, D=640, bs=16, M=20,
+        dtype=jnp.bfloat16, value_width=512,
+        tables="trash_last"),
+    "latent_kernel128x640v512_block16_f32": dict(
+        lengths=[33, 32, 1, 2], G=1, R=128, D=640, bs=16, M=4,
+        value_width=512, tables="trash_last"),
+    "latent_kernel32x640v512_block16_bf16": dict(
+        lengths=[1, 32, 260, 15, 1], G=1, R=32, D=640, bs=16, M=20,
+        dtype=jnp.bfloat16, value_width=512, tables="trash_last"),
+    "latent_kernel32x640v512_block16_f32": dict(
+        lengths=[48, 1, 17, 1], G=1, R=32, D=640, bs=16, M=4,
+        value_width=512, tables="trash_last"),
     # slots with the write mask off: position 0, an all-zero table
     "inactive_rows_zero_table": dict(
         lengths=[1, 11, 1], bs=8, M=2,
@@ -637,17 +658,34 @@ _PAGED_CASES = {
 }
 
 
+_LATENT_SCALE = (128 + 64) ** -0.5
+
+
+def _paged_decode_pair(args, G, value_width=None, interpret=True):
+    """(kernel, reference) of one decode batch: ``paged_attention``, or
+    with ``value_width`` the latent kernel over ``args``' K pool."""
+    from paddle_tpu.ops.pallas import kv_attention as kv
+    if value_width is None:
+        return (kv.paged_attention(*args, G, interpret=interpret),
+                kv.paged_attention_ref(*args, G))
+    qh, pool, _, _, _, tbl, pos = args
+    call = (qh, pool, tbl, pos, value_width, _LATENT_SCALE)
+    return (kv.paged_latent_attention(*call, interpret=interpret),
+            kv.paged_latent_attention_ref(*call))
+
+
 @pytest.mark.parametrize("case", sorted(_PAGED_CASES))
 def test_paged_attention_interpret_parity(case):
-    """The decode kernel against ``paged_attention_ref``: atol 2e-5 /
-    rtol 1e-4 on f32 inputs (online softmax re-associates the f32
-    sums, as for ``int8_kv_attention``); a bf16 case rounds both
-    results to bf16 last, so they may sit one bf16 ulp apart."""
-    from paddle_tpu.ops.pallas.kv_attention import (paged_attention,
-                                                    paged_attention_ref)
-    args, G = _paged_case(np.random.default_rng(21), **_PAGED_CASES[case])
-    ref = np.asarray(paged_attention_ref(*args, G).astype(jnp.float32))
-    ker = paged_attention(*args, G, interpret=True)
+    """The decode kernels against their references (the latent kernel's
+    is ``paged_attention_ref`` over its one pool): atol 2e-5 / rtol
+    1e-4 on f32 inputs (online softmax re-associates the f32 sums, as
+    for ``int8_kv_attention``); a bf16 case rounds both results to bf16
+    last, so they may sit one bf16 ulp apart."""
+    kw = dict(_PAGED_CASES[case])
+    vw = kw.pop("value_width", None)
+    args, G = _paged_case(np.random.default_rng(21), **kw)
+    ker, ref = _paged_decode_pair(args, G, vw)
+    ref = np.asarray(ref.astype(jnp.float32))
     assert ker.shape == ref.shape and ker.dtype == args[0].dtype
     ker = np.asarray(ker.astype(jnp.float32))
     assert np.isfinite(ker).all()
@@ -657,20 +695,29 @@ def test_paged_attention_interpret_parity(case):
         np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("dtype,D", [("float32", 16), ("bfloat16", 16),
-                                     ("bfloat16", 64)])
-def test_paged_attention_ignores_block_ids_and_neighbours(dtype, D):
+@pytest.mark.parametrize("dtype,D,R", [
+    pytest.param("float32", 16, 2, id="float32-16"),
+    pytest.param("bfloat16", 16, 2, id="bfloat16-16"),
+    pytest.param("bfloat16", 64, 2, id="bfloat16-64"),
+    # the latent kernel: 128 and 32 heads over one pool
+    pytest.param("bfloat16", 640, 128, id="latent-bfloat16-128"),
+    pytest.param("float32", 640, 32, id="latent-float32-32")])
+def test_paged_attention_ignores_block_ids_and_neighbours(dtype, D, R):
     """The eviction / replay contract: the same logical sequence gives
     BIT-identical output on permuted physical blocks, in another slot,
     beside other rows (blocks are reduced in logical order; what a
     buffer holds past a row's length weighs exactly zero)."""
-    from paddle_tpu.ops.pallas.kv_attention import paged_attention
     rng = np.random.default_rng(22)
     bs, M, n = 4, 40, 150                 # three blocks of pages
+    G, vw = (1, 512) if D == 640 else (2, None)
     (qh, kp, vp, _, _, tbl, pos), G = _paged_case(
-        rng, [n, 9, 160], bs=bs, M=M, D=D, dtype=jnp.dtype(dtype))
-    a = np.asarray(paged_attention(qh, kp, vp, None, None, tbl, pos, G,
-                                   interpret=True).astype(jnp.float32))
+        rng, [n, 9, 160], G=G, R=R, bs=bs, M=M, D=D,
+        dtype=jnp.dtype(dtype))
+
+    def kernel(*args):
+        return np.asarray(_paged_decode_pair(args, G, vw)[0]
+                          .astype(jnp.float32))
+    a = kernel(qh, kp, vp, None, None, tbl, pos)
     # row 0's sequence moves to permuted blocks of a fresh pool (the
     # rest of it other noise), into slot 2, beside two other rows
     used = -(-n // bs)
@@ -688,11 +735,11 @@ def test_paged_attention_ignores_block_ids_and_neighbours(dtype, D):
     pos2 = np.asarray([[2], [M * bs - 1], [n - 1]], np.int32)
     qh2 = np.array(_rand(rng, *qh.shape))
     qh2[2] = np.asarray(qh.astype(jnp.float32))[0]
-    b = paged_attention(
+    b = kernel(
         jnp.asarray(qh2, qh.dtype), jnp.asarray(kp2, kp.dtype),
         jnp.asarray(vp2, vp.dtype), None, None, jnp.asarray(tbl2),
-        jnp.asarray(pos2), G, interpret=True)
-    assert np.array_equal(a[0], np.asarray(b.astype(jnp.float32))[2])
+        jnp.asarray(pos2))
+    assert np.array_equal(a[0], b[2])
 
 
 def _tiny_bf16_llama(kv_cache_dtype=None):
@@ -812,6 +859,101 @@ def test_paged_decode_route_is_chosen_from_the_call(what, expect):
     counts = _trace_paged(m, S, target,
                           verify_mode=what.startswith("verify"))
     assert {k: v for k, v in counts.items() if v} == expect
+
+
+def _tiny_bf16_latent(kind, **kw):
+    """A latent-attention decoder whose decode call the latent kernel
+    would take: a bf16 pool of 256-lane rows, V its first 128 lanes."""
+    from paddle_tpu.text.models import kimi_linear as KL
+    from paddle_tpu.text.models import pangu_ultra_moe as PU
+    paddle.seed(5)
+    kw = dict(dict(kv_lora_rank=128, compute_dtype="bfloat16"), **kw)
+    if kind == "kimi":
+        return KL.KimiLinearForCausalLM(KL.kimi_linear_tiny(**kw))
+    return PU.PanguUltraMoEForCausalLM(PU.pangu_ultra_moe_tiny(**kw))
+
+
+@pytest.mark.parametrize("kind", ["kimi", "pangu"])
+@pytest.mark.parametrize("what", ["decode_cpu", "decode_tpu",
+                                  "f32_pool_tpu", "narrow_latent_tpu"])
+def test_latent_decode_route_is_chosen_from_the_call(kind, what):
+    """The two latent models' decode step takes ``paged_latent_attention``
+    (never ``paged_attention``) once a latent layer: the kernel where
+    programs are compiled for a TPU and the reference on the CPU; a
+    float32 pool or a latent width that is not whole lane tiles falls
+    back, counted."""
+    import paddle_tpu.distributed.mesh as mesh_mod
+    from paddle_tpu.text.models.kimi_linear import LatentAttention
+    kw = {"f32_pool_tpu": dict(compute_dtype="float32"),
+          "narrow_latent_tpu": dict(kv_lora_rank=24)}.get(what, {})
+    m = _tiny_bf16_latent(kind, **kw)
+    n = sum(isinstance(lyr.self_attn, LatentAttention)
+            for lyr in m.model.layers)
+    pools = m.init_paged_cache(8, 16, num_slots=2)
+    orig = mesh_mod.target_platform
+    mesh_mod.target_platform = lambda: what.rsplit("_", 1)[1]
+    kreg.reset_dispatch_counts()
+    try:
+        jax.eval_shape(
+            lambda ids, pos, pools, tbl, wm: m.forward_paged(
+                ids, pos, pools, tbl, wm)[0]._value,
+            np.zeros((2, 1), np.int32), np.zeros((2, 1), np.int32), pools,
+            np.zeros((2, 4), np.int32), np.zeros((2, 1), bool))
+    finally:
+        mesh_mod.target_platform = orig
+    path = {"decode_cpu": "xla_ref", "decode_tpu": "pallas"}.get(
+        what, "fallback")
+    counts = {k: v for k, v in kreg.dispatch_counts().items() if v}
+    assert n >= 1 and counts == {"paged_latent_attention": {path: n}}
+
+
+@pytest.mark.parametrize("kind", ["kimi", "pangu"])
+def test_latent_paged_decode_kernel_parity(kind):
+    """End-to-end through ``LatentAttention.forward_paged``: a prefill
+    of 19 tokens over two pages, then decode steps beside an inactive
+    slot, with the latent kernel (interpret) track the reference path
+    and pick the same tokens."""
+    m = _tiny_bf16_latent(kind)
+
+    def decode(mode):
+        kreg.set_mode("paged_latent_attention", mode)
+        try:
+            pools = m.init_paged_cache(8, 16, num_slots=2)
+            tbl = np.array([[3, 5, 2, 0], [0, 0, 0, 0]], np.int32)
+            n = 19
+            ids = np.zeros((2, 32), np.int32)
+            ids[0, :n] = np.random.RandomState(1).randint(1, 64, (n,))
+            pos = np.broadcast_to(np.arange(32, dtype=np.int32), (2, 32))
+            pos = np.where(np.arange(2)[:, None] == 0, pos, 0)
+            wm = np.zeros((2, 32), bool)
+            wm[0, :n] = True
+            lg, pools, _ = m.forward_paged(
+                ids, pos, pools, tbl, wm,
+                gather_at=np.asarray([n - 1, 0], np.int32))
+            outs = [np.asarray(lg._value[0, 0], np.float32)]
+            for j in range(3):
+                tok = int(np.argmax(outs[-1]))
+                lg, pools, _ = m.forward_paged(
+                    np.asarray([[tok], [0]], np.int32),
+                    np.asarray([[n + j], [0]], np.int32), pools, tbl,
+                    np.asarray([[True], [False]]))
+                outs.append(np.asarray(lg._value[0, 0], np.float32))
+            return outs
+        finally:
+            kreg.set_mode("paged_latent_attention", None)
+    ref = decode("xla_ref")
+    kreg.reset_dispatch_counts()
+    got = decode("interpret")
+    from paddle_tpu.text.models.kimi_linear import LatentAttention
+    n = sum(isinstance(lyr.self_attn, LatentAttention)
+            for lyr in m.model.layers)
+    assert kreg.dispatch_counts("paged_latent_attention") == \
+        {"interpret": 3 * n}
+    for r, g in zip(ref, got):
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, r, atol=2e-2, rtol=2e-2)
+    assert [int(np.argmax(o)) for o in got] == \
+        [int(np.argmax(o)) for o in ref]
 
 
 def test_generation_server_decode_program_routes(monkeypatch):

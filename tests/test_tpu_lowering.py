@@ -125,15 +125,18 @@ def _kernel_cases():
             [np.zeros((8192, 16, 512), jnp.bfloat16)] * 2 + [
                 np.zeros((256, 1, 32, 64), jnp.bfloat16),
                 np.zeros((256, 112), i32), np.zeros((256, 1), i32)]),
-        # the same kernel at 128 query heads over the one latent "kv
-        # head" of 640 lanes (PR 35: the Pangu Ultra MoE cell, 128
-        # slots x 256 blocks of 16), K and V the SAME pool
-        "paged_attention@latent128": (
+        # the latent decode kernel at 128 query heads over one
+        # pool of 640-lane rows, V its first 512 lanes (the Pangu Ultra
+        # MoE cell: 128 slots x 256 blocks of 16), and at 32 heads (the
+        # Kimi-Linear cell: 128 slots x 192 blocks of 16)
+        **{f"paged_latent_attention@latent{h}": (
             lambda pool, q, tbl, pos: kreg.dispatch(
-                "paged_attention", q, pool, pool, None, None, tbl, pos, 1),
-            [np.zeros((32769, 16, 1, 640), jnp.bfloat16),
-             np.zeros((128, 1, 128, 640), jnp.bfloat16),
-             np.zeros((128, 256), i32), np.zeros((128, 1), i32)]),
+                "paged_latent_attention", q, pool, tbl, pos, 512,
+                192 ** -0.5),
+            [np.zeros((128 * m + 1, 16, 1, 640), jnp.bfloat16),
+             np.zeros((128, 1, h, 640), jnp.bfloat16),
+             np.zeros((128, m), i32), np.zeros((128, 1), i32)])
+           for h, m in ((128, 256), (32, 192))},
         "segment_sum": (
             lambda gr, inv: kreg.dispatch("segment_sum", gr, inv,
                                           num_segments=256),
