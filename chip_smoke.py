@@ -514,6 +514,36 @@ def _kernel_cases(size):
         lambda: kreg.dispatch("paged_latent_attention", *la,
                               mode="xla_ref"),
         2e-2, 0.0)    # bf16 out (eps 2^-7)
+
+    # paged_sparse_attention: 64 query heads on 4 kv heads of 128 over
+    # head-major pools of 128-token pages, each (row, kv head) reading
+    # page 0, its own and the one before, and up to 16 others at random
+    # (the MiniMax cell's shapes); lists padded with the trash page
+    sb, sp, sn = 128, 40, 19
+    slen = np.array([1, sb, sb + 1, sb * 30 + 7], np.int32)
+    spg = np.zeros((len(slen), 4, sn), np.int32)
+    slg = np.zeros((len(slen), 4, sn), np.int32)
+    scnt = np.zeros((len(slen), 4), np.int32)
+    for i, n_ in enumerate(slen):
+        bt = (int(n_) - 1) // sb
+        tbl_ = 1 + rng.permutation(sp - 1)[:bt + 1]
+        for h_ in range(4):
+            far = rng.permutation(np.arange(1, max(bt - 1, 1)))[:sn - 3]
+            sel = sorted({0, max(bt - 1, 0), bt} | set(far.tolist()))
+            scnt[i, h_] = len(sel)
+            slg[i, h_, :len(sel)] = sel
+            spg[i, h_, :len(sel)] = tbl_[sel]
+    sa = [randn(len(slen), 1, 64, 128, dtype=jnp.bfloat16),
+          randn(sp, 4, sb, 128, dtype=jnp.bfloat16),
+          randn(sp, 4, sb, 128, dtype=jnp.bfloat16), jnp.asarray(spg),
+          jnp.asarray(slg), jnp.asarray(scnt),
+          jnp.asarray(slen[:, None] - 1)]
+    cases["paged_sparse_attention"] = (
+        lambda mode: kreg.dispatch("paged_sparse_attention", *sa,
+                                   mode=mode),
+        lambda: kreg.dispatch("paged_sparse_attention", *sa,
+                              mode="xla_ref"),
+        2e-2, 0.0)    # bf16 out (eps 2^-7)
     return cases
 
 

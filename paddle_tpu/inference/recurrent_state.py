@@ -110,8 +110,8 @@ def refuse_migration(server):
 
 def pool_bytes(pools) -> dict:
     """Bytes of the per-slot state, of the latent pages and of the K/V
-    pages (scales included) in ``pools``, and the number of state
-    slots."""
+    pages (scales and pooled keys included) in ``pools``, and the number
+    of state slots."""
     out = {"state_slots": 0, "state_bytes": 0, "latent_pool_bytes": 0,
            "kv_pool_bytes": 0}
     for d in pools:
@@ -121,6 +121,6 @@ def pool_bytes(pools) -> dict:
                 out["state_slots"] = int(v.shape[0])
             elif k == "latent":
                 out["latent_pool_bytes"] += int(v.nbytes)
-            elif k in ("k", "v", "k_scale", "v_scale"):
+            elif k in ("k", "v", "k_scale", "v_scale", "kmax"):
                 out["kv_pool_bytes"] += int(v.nbytes)
     return out

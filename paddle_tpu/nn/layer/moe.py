@@ -219,9 +219,32 @@ def masked_pass_pays(n_tokens: int, top_k: int, num_experts: int) -> bool:
     return n_tokens * (1.0 - top_k / num_experts) <= _RIDGE
 
 
-def _swiglu(x, wg, wu, wd):
-    h = jax.nn.silu(jnp.dot(x, wg, preferred_element_type=jnp.float32)) \
-        * jnp.dot(x, wu, preferred_element_type=jnp.float32)
+# the gated expert's activation, on the float32 gate and up products:
+# ``swiglu`` is silu(gate) * up; ``swigluoai`` (MiniMax-M3, gpt-oss) is
+# the clamped form (up + 1) * gate * sigmoid(1.702 gate), the gate
+# clipped above at 7 and the up product to [-7, 7]
+SWIGLU_OAI_ALPHA = 1.702
+SWIGLU_OAI_LIMIT = 7.0
+GLU_ACTS = ("swiglu", "swigluoai")
+
+
+def _glu(g, u, act="swiglu"):
+    if act == "swiglu":
+        return jax.nn.silu(g) * u
+    if act == "swigluoai":
+        # the clamps as selects: XLA's CPU backend cannot run the bf16
+        # product behind a min/clip fusion ("Unsupported element type
+        # for DotThunk"); the values are the same
+        lim = SWIGLU_OAI_LIMIT
+        g = jnp.where(g > lim, lim, g)
+        u = jnp.where(u > lim, lim, jnp.where(u < -lim, -lim, u))
+        return (u + 1.0) * g * jax.nn.sigmoid(SWIGLU_OAI_ALPHA * g)
+    raise ValueError(f"unknown expert activation {act!r}")
+
+
+def _swiglu(x, wg, wu, wd, act="swiglu"):
+    h = _glu(jnp.dot(x, wg, preferred_element_type=jnp.float32),
+             jnp.dot(x, wu, preferred_element_type=jnp.float32), act)
     return jnp.dot(h.astype(x.dtype), wd,
                    preferred_element_type=jnp.float32)
 
@@ -241,7 +264,7 @@ def _route(x, router_w, router_b, top_k, scale, held, norm_eps=1e-20):
     return local, w, (local >= 0) & (local < count)
 
 
-def _experts_masked(x, local, w, wg, wu, wd):
+def _experts_masked(x, local, w, wg, wu, wd, act="swiglu"):
     """Every held expert over every token, the result weighed by the
     combine weights (nought where the token did not choose the
     expert): for few tokens.  Returns ``(y, picks here, the largest
@@ -249,10 +272,10 @@ def _experts_masked(x, local, w, wg, wu, wd):
     count = wg.shape[0]
     onehot = jax.nn.one_hot(local, count, dtype=jnp.float32)   # [T,k,E]
     wt = (onehot * w[..., None]).sum(1)                        # [T, E]
-    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, wg,
-                               preferred_element_type=jnp.float32)) \
-        * jnp.einsum("td,edf->etf", x, wu,
-                     preferred_element_type=jnp.float32)
+    h = _glu(jnp.einsum("td,edf->etf", x, wg,
+                        preferred_element_type=jnp.float32),
+             jnp.einsum("td,edf->etf", x, wu,
+                        preferred_element_type=jnp.float32), act)
     out = jnp.einsum("etf,efd->etd", h.astype(x.dtype), wd,
                      preferred_element_type=jnp.float32)
     load = onehot.sum((0, 1)).astype(jnp.int32)
@@ -260,7 +283,7 @@ def _experts_masked(x, local, w, wg, wu, wd):
             x.shape[0] * count)
 
 
-def _experts_grouped(x, local, w, here, wg, wu, wd):
+def _experts_grouped(x, local, w, here, wg, wu, wd, act="swiglu"):
     """Sorted/grouped dispatch in plain XLA: the picks sorted by expert
     (picks on experts that live elsewhere sort last and are never
     touched), each held expert's group laid out in whole blocks of
@@ -306,7 +329,8 @@ def _experts_grouped(x, local, w, here, wg, wu, wd):
 
         def run():
             ds = lambda m: jax.lax.dynamic_index_in_dim(m, e, 0, False)
-            return _swiglu(x[tb], ds(wg), ds(wu), ds(wd)).astype(x.dtype)
+            return _swiglu(x[tb], ds(wg), ds(wu), ds(wd),
+                           act).astype(x.dtype)
         return jax.lax.cond(used, run,
                             lambda: jnp.zeros((blk, d), x.dtype))
     steps = jnp.arange(nb, dtype=i32)
@@ -325,7 +349,7 @@ def _experts_grouped(x, local, w, here, wg, wu, wd):
 
 def dropless_moe(x, router_w, router_b, wg, wu, wd, *, top_k: int,
                  scale: float, held, norm_eps: float = 1e-20,
-                 count_rows: bool = False):
+                 count_rows: bool = False, act: str = "swiglu"):
     """The held experts' part of a sigmoid-routed expert layer.
 
     ``x`` [T, d]; ``router_w`` [d, E] and ``router_b`` [E] over ALL E
@@ -334,6 +358,9 @@ def dropless_moe(x, router_w, router_b, wg, wu, wd, *, top_k: int,
     float32: scores ``sigmoid(x W_r)``, the ``top_k`` chosen by score +
     bias, weights the chosen scores over their sum + ``norm_eps``,
     times ``scale``.
+
+    ``act`` is the experts' activation (:func:`_glu`: ``swiglu`` or
+    the clamped ``swigluoai``).
 
     No token is dropped, no weight is gathered per pick, and what the
     absent experts would have added is left out.  Few tokens (a decode
@@ -353,10 +380,10 @@ def dropless_moe(x, router_w, router_b, wg, wu, wd, *, top_k: int,
     local, w, here = _route(x, router_w, router_b, top_k, scale, held,
                             **other_eps)
     if masked_pass_pays(x.shape[0], top_k, router_w.shape[1]):
-        *out, rows = _experts_masked(x, local, w, wg, wu, wd)
+        *out, rows = _experts_masked(x, local, w, wg, wu, wd, act)
     else:
         *out, (n_used, blk) = _experts_grouped(x, local, w, here, wg, wu,
-                                               wd)
+                                               wd, act)
         rows = n_used * blk if count_rows else None
     return (*out, rows) if count_rows else tuple(out)
 
@@ -371,8 +398,9 @@ class DroplessMoELayer(Layer):
     expert-parallel layer: the router keeps its full width, only the
     ``count`` experts from ``first`` have weights here, and the result
     is THEIR part of the layer's output plus the shared expert's (which
-    every chip computes alike).  The exchange between chips is not this
-    layer's; on one chip it runs without it.
+    every chip computes alike).  ``act`` is every expert's activation,
+    the shared one's too (:func:`_glu`).  The exchange between chips is
+    not this layer's; on one chip it runs without it.
 
     Serving only: written for inference (``lax.cond`` per block of the
     sorted picks; no auxiliary loss, no gradient tests).
@@ -386,8 +414,10 @@ class DroplessMoELayer(Layer):
                  shared_hidden: Optional[int] = None,
                  routed_scaling_factor: float = 1.0,
                  initializer_range: float = 0.02,
-                 norm_eps: float = 1e-20):
+                 norm_eps: float = 1e-20, act: str = "swiglu"):
         super().__init__()
+        if act not in GLU_ACTS:
+            raise ValueError(f"unknown expert activation {act!r}")
         first, count = held_experts or (0, num_experts)
         if not (0 <= first and count >= 1
                 and first + count <= num_experts):
@@ -400,6 +430,7 @@ class DroplessMoELayer(Layer):
         self.held_experts = (int(first), int(count))
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.norm_eps = float(norm_eps)
+        self.act = act
         init = Normal(0.0, initializer_range)
 
         def mk(*shape):
@@ -427,11 +458,11 @@ class DroplessMoELayer(Layer):
             self.gate_w._value, self.up_w._value, self.down_w._value,
             top_k=self.top_k, scale=self.routed_scaling_factor,
             held=self.held_experts, norm_eps=self.norm_eps,
-            count_rows=count_rows)
+            count_rows=count_rows, act=self.act)
         if self.shared:
             y = y + _swiglu(tok, self.shared_gate._value,
                             self.shared_up._value,
-                            self.shared_down._value)
+                            self.shared_down._value, self.act)
         return (y.astype(xv.dtype).reshape(xv.shape), *counts)
 
     def loops_on_device(self, n_tokens: int) -> bool:

@@ -70,6 +70,24 @@ wait on the slot's semaphore takes the whole block; the row after the
 last one copies the trash block, and the last grid step waits for it.
 Its reference is ``paged_attention_ref`` with the pool as K and as V.
 
+``paged_sparse_attention`` (kernel ``paged_sparse_attention``) is the
+decode of block-sparse attention (MiniMax-M3's MSA): each (row, kv
+head) reads only the pages its indexer selected, up to ``NS`` of them in
+logical order, not pages ``0 .. pos // bs``.  The four kv heads of a
+row select different pages, so the pool is HEAD-major, ``[nb, KH, bs,
+D]``: a (page, head) is one ``(bs, D)`` tile, (128, 128) bf16 at the
+served shapes, 32 KB of K and 32 KB of V a copy.  Grid ``(B, KH)``; the
+physical page ids and, for each selected page, the last offset in it
+that the row may see (``pos - logical * bs``, -1 for the padding) are
+scalar-prefetched.  A grid step copies its ``NS`` tiles of K and of V,
+the trash page where the list is shorter, into one double-buffered
+block with no branch (the latent kernel's lesson), the next step's
+copies in flight while this one is computed, and takes ONE softmax over
+the block's ``NS * bs`` keys in f32 (a block is the whole selection, so
+the online softmax has one step); positions past the limits weigh an
+exact zero.  The indexer's scores and its top-k are XLA, before the
+call.  Its reference is :func:`paged_sparse_attention_ref`.
+
 ``int8_paged_attention`` (kernel ``int8_kv_attention``, PR 13) fuses the
 dequant of int8 pools into the gather for decode/verify: grid
 ``(B, G, M)``, M innermost so the running (max, denom, acc) scratch
@@ -98,6 +116,7 @@ from . import registry
 
 __all__ = ["paged_attention_ref", "paged_attention",
            "paged_latent_attention_ref", "paged_latent_attention",
+           "paged_sparse_attention_ref", "paged_sparse_attention",
            "int8_paged_attention"]
 
 _NEG = -1e30
@@ -535,6 +554,169 @@ def _latent_eligible(qh, pool, tbl, pos, value_width, scale):
             and (mesh is None or mesh.size == 1))
 
 
+# ---------------------------------------------------------------------
+# block-sparse decode kernel (MSA)
+# ---------------------------------------------------------------------
+
+def sparse_limits(logical, counts, pos, block_size: int):
+    """For each selected page, the last offset in it that a row at
+    ``pos`` [B, 1] may see: ``pos - logical * bs`` clipped to the page,
+    -1 past ``counts`` (the padding of the list).  ``logical`` [B, KH,
+    NS], ``counts`` [B, KH]; returns int32 [B, KH, NS]."""
+    ns = logical.shape[-1]
+    lim = jnp.clip(pos[:, :1, None].astype(jnp.int32)
+                   - logical.astype(jnp.int32) * block_size,
+                   -1, block_size - 1)
+    live = jnp.arange(ns, dtype=jnp.int32) < counts[..., None]
+    return jnp.where(live, lim, -1)
+
+
+def paged_sparse_attention_ref(qh, kpool, vpool, pages, logical, counts,
+                               pos):
+    """Block-sparse decode attention in XLA: each (row, kv head) over
+    the ``counts`` pages its list names.  ``qh`` [B, 1, H, D]; head-major
+    pools ``[nb, KH, bs, D]``; ``pages`` (physical) and ``logical``
+    [B, KH, NS] int32, padded past ``counts`` [B, KH]; ``pos`` [B, 1].
+    A key is seen when its page is in the list and its position is at
+    most ``pos``.  Returns [B, 1, H, D] in ``qh``'s dtype."""
+    B, S, H, D = qh.shape
+    KH, bs = kpool.shape[1], kpool.shape[2]
+    R, ns = H // KH, pages.shape[-1]
+    heads = jnp.arange(KH)[None, :, None]
+    kg = kpool[pages, heads].astype(jnp.float32)       # [B,KH,NS,bs,D]
+    vg = vpool[pages, heads].astype(jnp.float32)
+    q = qh.reshape(B, KH, R, D).astype(jnp.float32)
+    s = jnp.einsum("bgrd,bgnsd->bgrns", q, kg) / (D ** 0.5)
+    lim = sparse_limits(logical, counts, pos, bs)
+    valid = jnp.arange(bs)[None, None, None, :] <= lim[..., None]
+    s = jnp.where(valid[:, :, None], s, -jnp.inf).reshape(B, KH, R,
+                                                          ns * bs)
+    w = jax.nn.softmax(s, axis=-1).reshape(B, KH, R, ns, bs)
+    o = jnp.einsum("bgrns,bgnsd->bgrd", w, vg)
+    return o.reshape(B, 1, H, D).astype(qh.dtype)
+
+
+def _paged_sparse_kernel(ns, bs, kh, scale, page_ref, lim_ref, q_ref,
+                         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem):
+    """One (row, kv head): its ``ns`` selected tiles of K and V in one
+    block, one softmax (module doc)."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    step = b * kh + g
+    total = pl.num_programs(0) * kh
+    slot = step % 2
+    n = ns * bs
+
+    def start(at, slot):
+        """Start the ``ns`` K and V copies of grid step ``at``."""
+        gg = at % kh
+        for p in range(ns):
+            page = page_ref[at * ns + p]
+            dst = pl.ds(p * bs, bs)
+            pltpu.make_async_copy(k_hbm.at[page, gg], kbuf.at[slot, dst],
+                                  sem.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[page, gg], vbuf.at[slot, dst],
+                                  sem.at[1, slot]).start()
+
+    @pl.when(step == 0)
+    def _():
+        start(0, 0)
+
+    @pl.when(step + 1 < total)
+    def _():
+        start(step + 1, 1 - slot)
+
+    # each slot's semaphore counts bytes: one wait takes all its copies
+    pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot], sem.at[0, slot]
+                          ).wait()
+    pltpu.make_async_copy(vbuf.at[slot], vbuf.at[slot], sem.at[1, slot]
+                          ).wait()
+    # the block's last visible column, page by page (-1: none of it)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    last = jnp.full((1, n), -1, jnp.int32)
+    for p in range(ns):
+        last = jnp.where(col // bs == p, lim_ref[step * ns + p] + p * bs,
+                         last)
+    valid = col <= last
+    q = q_ref[0, 0]                                    # (R, D)
+    r = q.shape[0]
+    s = jax.lax.dot_general(
+        q, kbuf[slot], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale    # (R, ns*bs)
+    s = jnp.where(valid, s, _NEG)
+    m = s.max(axis=1, keepdims=True)
+    # masked columns contribute EXACT zeros, whatever the buffer holds
+    p_ = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = p_.sum(axis=1, keepdims=True)
+    v = vbuf[slot]
+    if v.dtype == jnp.bfloat16:
+        # f32 p in bf16 hi + lo halves, one pass over V (as in
+        # ``_paged_attn_kernel``)
+        hi = p_.astype(jnp.bfloat16)
+        lo = (p_ - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        pv = jnp.dot(jnp.concatenate([hi, lo], axis=0), v,
+                     preferred_element_type=jnp.float32)
+        pv = pv[:r] + pv[r:]
+    else:
+        pv = jnp.dot(p_, v.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    o_ref[0, 0] = (pv / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_sparse_attention(qh, kpool, vpool, pages, logical, counts, pos,
+                           *, interpret=False):
+    """Block-sparse decode attention (``S == 1``) reading only the
+    selected (page, kv head) tiles of head-major pools (module doc):
+    same arguments and result as :func:`paged_sparse_attention_ref`."""
+    B, S, H, D = qh.shape
+    if S != 1:
+        raise ValueError("paged_sparse_attention is the S == 1 kernel")
+    nb, KH, bs = kpool.shape[:3]
+    R, ns = H // KH, pages.shape[-1]
+    lim = sparse_limits(logical, counts, pos, bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KH),
+        in_specs=[
+            pl.BlockSpec((1, 1, R, D), lambda b, g, *_: (b, g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, R, D), lambda b, g, *_: (b, g, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ns * bs, D), kpool.dtype),
+            pltpu.VMEM((2, ns * bs, D), vpool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_sparse_kernel, ns, bs, KH,
+                          1.0 / math.sqrt(D)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, R, D), qh.dtype),
+        # steps run in order: each starts the next one's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_sparse_attention",
+    )(pages.astype(jnp.int32).reshape(-1), lim.reshape(-1),
+      qh.reshape(B, KH, R, D), kpool, vpool)
+    return out.reshape(B, 1, H, D)
+
+
+def _sparse_eligible(qh, kpool, vpool, pages, logical, counts, pos):
+    # compiled-mode gate, from the call: one query per row, bf16
+    # head-major pools in the queries' dtype, (bs, D) tiles of whole
+    # (16, 128) bf16 tiles, and no multi-device mesh
+    from ...distributed import mesh as mesh_mod
+    mesh = mesh_mod.get_mesh(create=False)
+    D, bs = kpool.shape[-1], kpool.shape[2]
+    return (qh.shape[1] == 1 and kpool.ndim == 4
+            and kpool.dtype == jnp.bfloat16 and qh.dtype == kpool.dtype
+            and D % 128 == 0 and bs % 16 == 0
+            and (mesh is None or mesh.size == 1))
+
+
 def _int8_kv_attn_kernel(bs, sr, d, scale, tbl_ref, qpos_ref, q_ref, k_ref, v_ref,
             ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref):
     # tbl_ref (the scalar-prefetched block table) already did its job
@@ -675,4 +857,18 @@ registry.register(
         "the block table up to each row's length, each page DMA'd once "
         "and read as K over all its lanes and as V over the first "
         "value_width",
+)
+
+
+registry.register(
+    "paged_sparse_attention", paged_sparse_attention,
+    paged_sparse_attention_ref,
+    tolerance="atol 2e-5 / rtol 1e-4 vs xla_ref on f32 inputs (f32 "
+              "softmax re-association; p.v in bf16 hi+lo halves drops "
+              "under 2**-17 of p); bit-identical across physical block "
+              "ids and batch neighbours",
+    eligible=_sparse_eligible,
+    doc="block-sparse (MSA) decode attention: each (row, kv head) reads "
+        "only the (page, head) tiles of head-major pools its indexer "
+        "selected, double buffered, one f32 softmax over the selection",
 )

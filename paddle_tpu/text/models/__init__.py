@@ -8,6 +8,9 @@ from .kimi_linear import (  # noqa: F401
 from .lfm2_moe import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny,
 )
+from .minimax_m3 import (  # noqa: F401
+    MiniMaxM3Config, MiniMaxM3ForCausalLM, minimax_m3_tiny,
+)
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, RMSNorm,
     llama_tiny, llama_7b, llama_13b,
@@ -28,6 +31,7 @@ __all__ = [
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
     "LlamaConfig", "LlamaForCausalLM", "LlamaModel", "RMSNorm",
     "llama_tiny", "llama_7b", "llama_13b",
+    "MiniMaxM3Config", "MiniMaxM3ForCausalLM", "minimax_m3_tiny",
     "PanguUltraMoEConfig", "PanguUltraMoEForCausalLM",
     "pangu_ultra_moe_tiny",
     "CrossEntropyCriterion", "TransformerConfig", "TransformerModel",

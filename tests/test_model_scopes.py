@@ -26,7 +26,8 @@ TOY = os.path.join(ROOT, "tests", "perfbench_tests", "toy")
 CELLS = {"mistral7b-serve-decode": "manifest",
          "kimi-linear-serve-decode": "manifest_kimi",
          "lfm2-8b-a1b-serve-decode": "manifest_lfm2",
-         "openpangu-ultra-moe-serve-decode": "manifest_pangu"}
+         "openpangu-ultra-moe-serve-decode": "manifest_pangu",
+         "minimax-m3-serve-longctx": "manifest_minimax"}
 HEAVY = ("dot", "gather", "scatter", "custom-call")
 _COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
 _INST = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(")
@@ -116,12 +117,14 @@ def programs(request):
 
 
 # the blocks of each model beside the embedding, the head and the
-# sampler: a mixer in the two hybrid models, experts in the three sparse
-# ones behind a dense first layer
+# sampler: a mixer in the two hybrid models, experts in the four sparse
+# ones behind a dense first layer (MiniMax's indexer sits inside
+# ``attn`` under a name of its own that is not a block)
 BLOCKS = {"mistral7b-serve-decode": {"attn", "ffn"},
           "kimi-linear-serve-decode": {"attn", "mixer", "moe", "ffn"},
           "lfm2-8b-a1b-serve-decode": {"attn", "mixer", "moe", "ffn"},
-          "openpangu-ultra-moe-serve-decode": {"attn", "moe", "ffn"}}
+          "openpangu-ultra-moe-serve-decode": {"attn", "moe", "ffn"},
+          "minimax-m3-serve-longctx": {"attn", "moe", "ffn"}}
 
 
 def test_every_heavy_op_names_exactly_one_block(programs):

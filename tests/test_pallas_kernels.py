@@ -59,7 +59,8 @@ def test_registry_lists_every_kernel_with_tolerance():
     ks = kreg.kernels()
     for name in ("flash_attention", "opt_apply", "int8_matmul",
                  "int8_kv_attention", "paged_attention",
-                 "paged_latent_attention", "segment_sum",
+                 "paged_latent_attention", "paged_sparse_attention",
+                 "segment_sum",
                  "pull_dequant"):
         assert name in ks, sorted(ks)
         assert ks[name].tolerance, name
@@ -740,6 +741,101 @@ def test_paged_attention_ignores_block_ids_and_neighbours(dtype, D, R):
         jnp.asarray(vp2, vp.dtype), None, None, jnp.asarray(tbl2),
         jnp.asarray(pos2))
     assert np.array_equal(a[0], b[2])
+
+
+def _sparse_case(rng, lengths, KH=2, R=4, D=16, bs=8, ns=5, nb=60,
+                 dtype=np.float32):
+    """A block-sparse decode batch: rows at ``lengths`` (position =
+    length - 1), each kv head selecting page 0, its own partial page,
+    the one before it and up to ``ns - 3`` others at random, in
+    logical order, on scattered physical pages; the lists padded with
+    the trash page and a zero count."""
+    B = len(lengths)
+    kp = jnp.asarray(_rand(rng, nb, KH, bs, D), dtype)
+    vp = jnp.asarray(_rand(rng, nb, KH, bs, D), dtype)
+    q = jnp.asarray(_rand(rng, B, 1, KH * R, D), dtype)
+    pages = np.zeros((B, KH, ns), np.int32)
+    logical = np.zeros((B, KH, ns), np.int32)
+    counts = np.zeros((B, KH), np.int32)
+    phys = rng.permutation(np.arange(1, nb))
+    for b, n in enumerate(lengths):
+        bt = (n - 1) // bs
+        table = phys[b * 7:b * 7 + bt + 1] if bt < 7 else \
+            rng.choice(np.arange(1, nb), bt + 1, replace=False)
+        for g in range(KH):
+            far = np.arange(1, max(bt - 1, 1))
+            pick = rng.permutation(far)[:ns - 3] if len(far) else far
+            sel = sorted({0, max(bt - 1, 0), bt} | set(pick.tolist()))
+            counts[b, g] = len(sel)
+            logical[b, g, :len(sel)] = sel
+            pages[b, g, :len(sel)] = table[sel]
+    pos = np.asarray(lengths, np.int32)[:, None] - 1
+    return (q, kp, vp, jnp.asarray(pages), jnp.asarray(logical),
+            jnp.asarray(counts), jnp.asarray(pos))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_sparse_attention_interpret_parity(dtype):
+    """The block-sparse decode kernel against its reference: ragged
+    lengths (a row inside page 0, rows on and one past a page
+    boundary, long rows whose lists are full), lists padded with the
+    trash page, each row reading its own partial page.  atol 2e-5 /
+    rtol 1e-4 on f32 inputs (the f32 softmax sums in another order;
+    p . v in bf16 hi + lo halves drops under 2**-17 of p); a bf16 case
+    rounds both results to bf16 last, one bf16 ulp apart at most."""
+    from paddle_tpu.ops.pallas import kv_attention as kv
+    args = _sparse_case(np.random.default_rng(31), [3, 8, 9, 17, 120, 60],
+                        dtype=jnp.dtype(dtype))
+    assert int(args[5].min()) >= 1 and int(args[5].max()) == 5
+    ker = kv.paged_sparse_attention(*args, interpret=True)
+    ref = kv.paged_sparse_attention_ref(*args)
+    assert ker.shape == ref.shape == args[0].shape
+    assert ker.dtype == args[0].dtype
+    ker = np.asarray(ker.astype(jnp.float32))
+    ref = np.asarray(ref.astype(jnp.float32))
+    assert np.isfinite(ker).all()
+    if dtype == "bfloat16":
+        np.testing.assert_allclose(ker, ref, atol=2 ** -9, rtol=2 ** -7)
+    else:
+        np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=1e-4)
+
+
+def test_paged_sparse_attention_reads_only_the_listed_pages():
+    """What the list leaves out is never read: pages not listed, the
+    padding past the count and positions past the row's own may hold
+    anything finite, and the result is BIT-identical; the same pages
+    moved to other physical ids, or the row beside other rows, too."""
+    from paddle_tpu.ops.pallas import kv_attention as kv
+    rng = np.random.default_rng(32)
+    q, kp, vp, pages, logical, counts, pos = _sparse_case(
+        rng, [45, 77, 5])
+    run = lambda *a: np.asarray(kv.paged_sparse_attention(
+        *a, interpret=True))
+    base = run(q, kp, vp, pages, logical, counts, pos)
+    listed = np.zeros(kp.shape[:2], bool)
+    for b in range(3):
+        for g in range(2):
+            listed[np.asarray(pages[b, g, :counts[b, g]]), g] = True
+    noise = jnp.asarray(_rand(rng, *kp.shape)) * 100
+    kp2 = jnp.where(listed[..., None, None], kp, noise)
+    vp2 = jnp.where(listed[..., None, None], vp, -noise)
+    # the row's own partial page past its position, too
+    b, g = 0, 1
+    own = int(pages[b, g, counts[b, g] - 1])
+    off = int(pos[b, 0]) % 8
+    kp2 = kp2.at[own, g, off + 1:].set(1e4)
+    np.testing.assert_array_equal(
+        run(q, kp2, vp2, pages, logical, counts, pos), base)
+    # other physical ids for the same pages
+    perm = np.concatenate([[0], rng.permutation(np.arange(1, 60))])
+    inv = np.argsort(perm)
+    np.testing.assert_array_equal(
+        run(q, kp[perm], vp[perm], jnp.asarray(inv[np.asarray(pages)]),
+            logical, counts, pos), base)
+    # the first row alone
+    np.testing.assert_array_equal(
+        run(q[:1], kp, vp, pages[:1], logical[:1], counts[:1], pos[:1]),
+        base[:1])
 
 
 def _tiny_bf16_llama(kv_cache_dtype=None):

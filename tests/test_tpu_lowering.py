@@ -137,6 +137,18 @@ def _kernel_cases():
              np.zeros((128, 1, h, 640), jnp.bfloat16),
              np.zeros((128, m), i32), np.zeros((128, 1), i32)])
            for h, m in ((128, 256), (32, 192))},
+        # the block-sparse decode kernel at the MiniMax-M3 cell's
+        # shapes: 12 slots, 64 query heads on 4 kv heads of 128, pages
+        # of 128 in head-major pools, 19 pages a list (the pools cut
+        # to one row's 336 pages + the trash page: the kernel reads
+        # them from HBM by id, so their count is not its shape)
+        "paged_sparse_attention": (
+            lambda kp, vp, q, pages, lg, cnt, pos: kreg.dispatch(
+                "paged_sparse_attention", q, kp, vp, pages, lg, cnt, pos),
+            [np.zeros((337, 4, 128, 128), jnp.bfloat16)] * 2 + [
+                np.zeros((12, 1, 64, 128), jnp.bfloat16),
+                np.zeros((12, 4, 19), i32), np.zeros((12, 4, 19), i32),
+                np.ones((12, 4), i32), np.zeros((12, 1), i32)]),
         "segment_sum": (
             lambda gr, inv: kreg.dispatch("segment_sum", gr, inv,
                                           num_segments=256),
